@@ -21,8 +21,13 @@ import subprocess
 import sys
 import textwrap
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.speedup import cpu_host_env  # noqa: E402
+
 WORKER = textwrap.dedent("""
     import json, sys
+    import jax
     import numpy as np
     from repro.core import gcn, graph
     from repro.core.subproblems import ADMMConfig
@@ -53,6 +58,7 @@ WORKER = textwrap.dedent("""
     log = tr.train(epochs)
     print(json.dumps({
         "M": m,
+        "platform": jax.devices()[0].platform,
         "partitioner": tr.partitioner,
         "edge_cut_frac": round(tr.partition_stats["cut_frac"], 3),
         "partition_quality": quality,
@@ -68,9 +74,7 @@ def run(dataset: str = "amazon_photo_mini", epochs: int = 25,
         partitioner: str = "multilevel") -> list[dict]:
     rows = []
     for m in parts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={m}"
-        env.setdefault("PYTHONPATH", "src")
+        env = cpu_host_env(m)
         out = subprocess.run(
             [sys.executable, "-c", WORKER, dataset, str(m), str(epochs),
              str(hidden), partitioner],

@@ -29,6 +29,18 @@ import subprocess
 import sys
 import textwrap
 
+
+def cpu_host_env(n_devices: int) -> dict:
+    """Environment of a host-mesh worker: pinned to the CPU with
+    ``n_devices`` virtual devices, so it never contends for a chip the
+    parent may hold, and none of its timings reads as a device number."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    return env
+
+
 WORKER = textwrap.dedent("""
     import json, sys, time
     import jax
@@ -86,7 +98,8 @@ WORKER = textwrap.dedent("""
                 "edge_cut": int(part_q["edge_cut"]),
                 "part_balance": float(part_q["balance"]),
                 "part_max_deg": int(part_q["max_deg"])}
-    print(json.dumps({"mode": mode, "total_s": total,
+    print(json.dumps({"mode": mode, "platform": jax.devices()[0].platform,
+                      "total_s": total,
                       "per_epoch_s": total / epochs,
                       "per_device_flops": float(census.flops),
                       "collective_bytes": float(census.collective_bytes),
@@ -96,10 +109,7 @@ WORKER = textwrap.dedent("""
 
 
 def _run(mode: str, dataset: str, epochs: int, hidden: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=" + \
-        ("1" if mode == "serial" else "3")
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env = cpu_host_env(1 if mode == "serial" else 3)
     out = subprocess.run(
         [sys.executable, "-c", WORKER, mode, dataset, str(epochs),
          str(hidden)],
@@ -359,7 +369,7 @@ MB_WORKER = textwrap.dedent("""
     from repro.core import graph, gcn
     from repro.core.parallel import ParallelADMMTrainer, TrainerConfig, AXIS
     from repro.core.subproblems import ADMMConfig
-    from repro.util.compat import make_mesh
+    from jax.sharding import AxisType
     m, hidden, epochs = int(sys.argv[1]), int(sys.argv[2]), int(sys.argv[3])
     frac = float(sys.argv[4])
     g, part = graph.synthetic_powerlaw_communities(
@@ -368,7 +378,7 @@ MB_WORKER = textwrap.dedent("""
     cfg = gcn.GCNConfig(layer_dims=(hidden, hidden,
                                     int(np.asarray(g.labels).max()) + 1))
     admm = ADMMConfig(nu=1e-3, rho=1e-3)
-    mesh = make_mesh((4,), (AXIS,), devices=jax.devices()[:4])
+    mesh = jax.make_mesh((4,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:4])
     out = {}
     for name, cfg_t in (("full", TrainerConfig.packed()),
                         ("minibatch",
@@ -382,6 +392,7 @@ MB_WORKER = textwrap.dedent("""
                      "lagrangian": float(tr._lagrangian(tr.state)),
                      "minibatch": {k: v for k, v in
                                    tr.comm_stats["minibatch"].items()}}
+    out["platform"] = jax.devices()[0].platform
     print(json.dumps(out))
 """)
 
@@ -433,9 +444,7 @@ def minibatch_comparison(m: int = 32, hidden: int = 64,
             sub, [hidden])["wire_bytes"]))
         rows.append(int(shard_rows[list(b)].sum()))
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env = cpu_host_env(4)
     proc = subprocess.run(
         [sys.executable, "-c", MB_WORKER, str(m), "16", str(epochs),
          str(batch_fraction)],
@@ -483,7 +492,7 @@ FU_WORKER = textwrap.dedent("""
     from repro.core import graph, gcn
     from repro.core.parallel import ParallelADMMTrainer, TrainerConfig, AXIS
     from repro.core.subproblems import ADMMConfig
-    from repro.util.compat import make_mesh
+    from jax.sharding import AxisType
     from repro.analysis.rules.memory import fused_agg_handoffs
     m, hidden, epochs = int(sys.argv[1]), int(sys.argv[2]), int(sys.argv[3])
     g, part = graph.synthetic_powerlaw_communities(
@@ -492,7 +501,7 @@ FU_WORKER = textwrap.dedent("""
     cfg = gcn.GCNConfig(layer_dims=(hidden, hidden,
                                     int(np.asarray(g.labels).max()) + 1))
     admm = ADMMConfig(nu=1e-3, rho=1e-3)
-    mesh = make_mesh((4,), (AXIS,), devices=jax.devices()[:4])
+    mesh = jax.make_mesh((4,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:4])
     out = {"num_layers": cfg.num_layers}
     trs = {}
     for name, fused in (("unfused", False), ("fused", True)):
@@ -525,6 +534,7 @@ FU_WORKER = textwrap.dedent("""
     out["parity_max_delta"] = max(deltas)
     out["lagrangian_unfused"] = float(trs["unfused"]._lagrangian(state))
     out["lagrangian_fused"] = float(trs["fused"]._lagrangian(fused_next))
+    out["platform"] = jax.devices()[0].platform
     print(json.dumps(out))
 """)
 
@@ -569,9 +579,7 @@ def fused_comparison(m: int = 32, hidden: int = 64,
         + [(dims[L - 1], dims[L])] * 2
     traffic = fused_agg_traffic((m // n_shards) * layout.n_pad, sites)
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env = cpu_host_env(4)
     proc = subprocess.run(
         [sys.executable, "-c", FU_WORKER, str(m), "16", str(epochs)],
         capture_output=True, text=True, env=env, check=True)

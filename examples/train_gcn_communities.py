@@ -15,6 +15,7 @@ from repro import checkpoint as ckpt
 from repro.core import gcn, graph
 from repro.core.parallel import ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -32,7 +33,8 @@ def main():
                          "no dense (M,M,n_pad,n_pad) tensor on device")
     ap.add_argument("--use-kernel", action="store_true",
                     help="route aggregation through the Pallas kernels "
-                         "(TPU; set REPRO_PALLAS_INTERPRET=1 elsewhere)")
+                         "(native on TPU; interpret mode elsewhere only "
+                         "with REPRO_PALLAS_INTERPRET=1)")
     ap.add_argument("--transport", default=None,
                     choices=["p2p", "allgather"],
                     help="Z/U/q exchange: neighbour-only ppermute rounds "
@@ -80,6 +82,7 @@ def main():
                     help="seed of the community batch sampler")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = graph.synthetic_sbm(args.dataset, seed=0)
     hyper = 1e-3 if "computers" in args.dataset else 1e-4

@@ -13,7 +13,8 @@ Context expectation: ``kernels`` is a list of dicts —
 
     {"spec": KernelSpec,                  # required
      "scalars": {name: np.ndarray, ...},  # the scalar-prefetch operands
-     "vmem_budget": int}                  # optional, default 16 MiB
+     "vmem_budget": int}                  # optional, default: the kernels'
+                                          # own scoped VMEM limit
 """
 from __future__ import annotations
 
@@ -22,9 +23,12 @@ from typing import Any, Iterable, List, Mapping, Optional
 
 from repro.analysis.findings import Finding, Severity
 from repro.analysis.registry import AnalysisContext, rule
+from repro.kernels.community_spmm import LANE as _LANE
+from repro.kernels.community_spmm import SUBLANE as _SUBLANE
+from repro.kernels.community_spmm import VMEM_LIMIT_BYTES
 
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024    # per-core VMEM on current TPUs
-_SUBLANE, _LANE = 8, 128
+# the scoped VMEM every community kernel requests from Mosaic
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES
 
 
 def _grid_corners(grid: tuple) -> Iterable[tuple]:
@@ -75,13 +79,13 @@ def check_kernel_bounds(spec: Any,
         if op.gather_scalar and op.gather_scalar in scalars:
             arr = scalars[op.gather_scalar]
             lo, hi = int(arr.min()), int(arr.max())
-            limit = counts[0]
+            limit = op.gather_limit()
             if lo < 0 or hi >= limit:
                 findings.append(Finding(
                     "pallas/index-bounds", Severity.ERROR,
                     f"{spec.name}:{op.name} gathered via "
                     f"{op.gather_scalar} with values in [{lo}, {hi}] but "
-                    f"only {limit} leading blocks",
+                    f"only {limit} leading positions",
                     location=f"{spec.name}:{op.name}",
                     details={"scalar": op.gather_scalar, "min": lo,
                              "max": hi, "blocks": limit}))
@@ -107,7 +111,8 @@ def check_kernel_vmem(spec: Any,
 
 def check_tile_alignment(spec: Any) -> List[Finding]:
     """Trailing block dims are (8, 128)-aligned (or span the full array
-    dim) so blocks map onto whole VREG tiles."""
+    dim) — Mosaic refuses to lower any other block for the TPU, so a
+    finding here is a kernel that cannot run on the chip."""
     findings: list[Finding] = []
     for op in spec.operands:
         pairs = [(b, d) for b, d in zip(op.block_shape, op.array_shape)
@@ -122,7 +127,7 @@ def check_tile_alignment(spec: Any) -> List[Finding]:
             bad.append(f"sublane dim {sub_b} not a multiple of {_SUBLANE}")
         if bad:
             findings.append(Finding(
-                "pallas/tile-alignment", Severity.WARNING,
+                "pallas/tile-alignment", Severity.ERROR,
                 f"{spec.name}:{op.name} block "
                 f"{tuple(b for b in op.block_shape)}: " + "; ".join(bad),
                 location=f"{spec.name}:{op.name}",
@@ -150,8 +155,9 @@ def vmem_budget(ctx: AnalysisContext) -> Iterable[Finding]:
             k["spec"], k.get("vmem_budget", VMEM_BUDGET_BYTES))
 
 
-@rule("pallas/tile-alignment", severity=Severity.WARNING)
+@rule("pallas/tile-alignment")
 def tile_alignment(ctx: AnalysisContext) -> Iterable[Finding]:
-    """Block shapes land on (8, 128) VREG tile boundaries."""
+    """Block shapes land on (8, 128) VREG tile boundaries (or span the
+    array dim) — the TPU compiler's own lowering condition."""
     for k in _kernels(ctx):
         yield from check_tile_alignment(k["spec"])

@@ -108,16 +108,40 @@ if TYPE_CHECKING:
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import gcn, graph, messages
 from repro.core.subproblems import ADMMConfig, stale_weights
 from repro.sharding.partition import CommunityBatchSampler
-from repro.util import shard_map
-from repro.util.compat import make_mesh
 
 Array = jax.Array
 AXIS = "comm"
+
+
+class BoundProgram:
+    """A jitted trainer program with the device data bound as its leading
+    argument.  Closed-over arrays would be baked into the compiled program
+    as constants — at the paper's widths a quarter-gigabyte ELL literal
+    per program — so the data travels as a real, already-placed argument
+    while callers keep the ``program(state, ...)`` signature (``lower``
+    for the analysis passes)."""
+
+    def __init__(self, fn, data):
+        self.fn, self.data = fn, data
+
+    def __call__(self, *args):
+        return self.fn(self.data, *args)
+
+    def lower(self, *args):
+        return self.fn.lower(self.data, *args)
+
+
+def place_on_mesh(mesh: Mesh, tree, spec):
+    """``device_put`` every array leaf of ``tree`` with ``spec`` on the
+    mesh (a PartitionSpec, or a matching tree of them)."""
+    return jax.device_put(tree, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), spec,
+        is_leaf=lambda s: isinstance(s, P)))
 
 
 class ParallelState(NamedTuple):
@@ -977,8 +1001,8 @@ class ParallelADMMTrainer:
         if mesh is None:
             n_dev = len(jax.devices())
             n_shards = max(d for d in range(1, n_dev + 1) if m % d == 0)
-            mesh = make_mesh((n_shards,), (AXIS,),
-                             devices=jax.devices()[:n_shards])
+            mesh = jax.make_mesh((n_shards,), (AXIS,), (AxisType.Auto,),
+                                 devices=jax.devices()[:n_shards])
         self.mesh = mesh
         n_shards = mesh.shape[AXIS]
 
@@ -987,9 +1011,16 @@ class ParallelADMMTrainer:
         # true community size, not M·n_pad (docs/layout.md)
         self.packed_layout = self.layout.device_layout(n_shards) \
             if packed else None
-        self.data = community_data(g, self.layout, compressed=compressed,
-                                   adjacency_bf16=adjacency_bf16,
-                                   device_layout=self.packed_layout)
+        # every data array starts where the step reads it: lane-major rows
+        # split over the comm axis, the scalar denominator replicated
+        data = community_data(g, self.layout, compressed=compressed,
+                              adjacency_bf16=adjacency_bf16,
+                              device_layout=self.packed_layout)
+        self.data = dataclasses.replace(data, **{
+            f.name: place_on_mesh(mesh, getattr(data, f.name),
+                                  P() if f.name == "denom" else P(AXIS))
+            for f in dataclasses.fields(data)
+            if f.name != "packed_layout" and getattr(data, f.name) is not None})
 
         # init from the same forward pass as the serial trainer
         ws = gcn.init_weights(cfg, jax.random.key(seed))
@@ -998,15 +1029,23 @@ class ParallelADMMTrainer:
                               jnp.asarray(g.features), ws)
         if packed:
             dl = self.packed_layout
-            zs = tuple(jnp.asarray(dl.pack_state(
-                self.layout.pack(np.asarray(z)))) for z in zs_full)
-        else:
-            zs = tuple(jnp.asarray(self.layout.pack(np.asarray(z)))
+            zs = tuple(dl.pack_state(self.layout.pack(np.asarray(z)))
                        for z in zs_full)
-        u = jnp.zeros_like(zs[-1])
+        else:
+            zs = tuple(self.layout.pack(np.asarray(z)) for z in zs_full)
+        del zs_full
+        u = np.zeros_like(zs[-1])
         taus = tuple(jnp.asarray(admm.tau_init) for _ in ws)
         thetas = tuple(jnp.full((m,), admm.tau_init) for _ in zs)
-        self.state = ParallelState(tuple(ws), zs, u, taus, thetas)
+        sharded, rep = P(AXIS), P()
+        n_l = cfg.num_layers
+        self.state_spec = ParallelState((rep,) * n_l, (sharded,) * n_l,
+                                         sharded, (rep,) * n_l,
+                                         (sharded,) * n_l)
+        # the state starts where the step's shard_map reads it, spread
+        # over the comm axis — not all on the default device
+        self.state = place_on_mesh(mesh, ParallelState(
+            tuple(ws), zs, u, taus, thetas), self.state_spec)
 
         self._plan = None
         ell_idx_dev = self.data.ell_indices
@@ -1031,7 +1070,7 @@ class ParallelADMMTrainer:
                 # the body never sees an (M, ...) payload
                 body_plan = self._plan
                 csr = self.layout.compress()
-                ell_idx_dev = jnp.asarray(self._plan.localize_indices(
+                ell_idx_dev = np.asarray(self._plan.localize_indices(
                     csr.ell_indices, csr.ell_mask))
         else:
             body_plan = None
@@ -1068,8 +1107,6 @@ class ParallelADMMTrainer:
                     ov_msk = np.asarray(csr.ell_mask).reshape(
                         n_shards, dl.lanes_per_shard, -1)
 
-        sharded, rep = P(AXIS), P()
-        n_l = cfg.num_layers
         if compressed:
             # each shard carries only its lanes' ELL rows — no dense
             # (M, M, n_pad, n_pad) tensor exists on device — plus its
@@ -1083,6 +1120,10 @@ class ParallelADMMTrainer:
             adj_spec = sharded
         data = self.data
         k_lanes = m // n_shards
+        step_spec = (adj_spec, sharded, sharded, sharded, sharded, rep)
+        step_data = place_on_mesh(mesh, (
+            adj_data, data.neighbor_mask, data.z0, data.labels,
+            data.train_mask, data.denom), step_spec)
 
         def make_step(sampled=None):
             """Compile one ADMM step.  ``sampled`` (an iterable of shard
@@ -1123,37 +1164,25 @@ class ParallelADMMTrainer:
             body = partial(_iteration_body, cfg, admm, use_kernel,
                            comm_bf16, compressed, step_plan, overlap_on,
                            fused, step_aux, mb_aux)
-            in_specs = (adj_spec, sharded, sharded, sharded, sharded, rep,
-                        (rep,) * n_l, (sharded,) * n_l, sharded,
-                        (rep,) * n_l, (sharded,) * n_l)
-            out_specs = ((rep,) * n_l, (sharded,) * n_l, sharded,
-                         (rep,) * n_l, (sharded,) * n_l)
+            in_specs = step_spec + tuple(self.state_spec)
+            out_specs = tuple(self.state_spec)
             if mb_aux is not None:
                 in_specs = in_specs + (sharded,)
-            mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+            mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False)
 
             # the state rebinds every step: donating it lets XLA reuse the
             # Z/U/weight buffers in place instead of doubling peak HBM
             # (memory/donated-inputs proves this holds on the compiled step)
             if mb_aux is None:
-                @partial(jax.jit, donate_argnums=(0,))
-                def step(state: ParallelState):
-                    ws, zs, u, taus, thetas = mapped(
-                        adj_data, data.neighbor_mask, data.z0, data.labels,
-                        data.train_mask, data.denom, state.weights,
-                        state.zs, state.u, state.taus, state.thetas)
-                    return ParallelState(ws, zs, u, taus, thetas)
+                @partial(jax.jit, donate_argnums=(1,))
+                def step(dev, state: ParallelState):
+                    return ParallelState(*mapped(*dev, *state))
             else:
-                @partial(jax.jit, donate_argnums=(0,))
-                def step(state: ParallelState, nbr_decay):
-                    ws, zs, u, taus, thetas = mapped(
-                        adj_data, data.neighbor_mask, data.z0, data.labels,
-                        data.train_mask, data.denom, state.weights,
-                        state.zs, state.u, state.taus, state.thetas,
-                        nbr_decay)
-                    return ParallelState(ws, zs, u, taus, thetas)
-            return step, step_plan
+                @partial(jax.jit, donate_argnums=(1,))
+                def step(dev, state: ParallelState, nbr_decay):
+                    return ParallelState(*mapped(*dev, *state, nbr_decay))
+            return BoundProgram(step, step_data), step_plan
 
         self._make_step = make_step
         self._sampler = None
@@ -1316,24 +1345,32 @@ class ParallelADMMTrainer:
 
         # full-M packed aggregation for metrics/Lagrangian: ELL in compressed
         # mode (no dense adjacency is retained on device), masked dense
-        # einsum otherwise
+        # einsum otherwise.  The ELL aggregation runs per shard under the
+        # mesh (a Pallas kernel cannot be auto-partitioned across chips):
+        # each shard aggregates its own lanes against the replicated
+        # blocked Z.  ``use_kernel`` picks the kernel or the einsum, as in
+        # the step body.
+        data = self.data
         if compressed:
-            ell = (self.data.ell_blocks, self.data.ell_indices,
-                   self.data.ell_mask)
-            counts = (self.data.row_counts, self.data.nbr_counts)
-
-            def agg_full(z_pack):
-                from repro.kernels import ops as kops
-                return kops.community_spmm_ell(*ell, z_pack, *counts)
+            from repro.kernels import ops as kops
+            from repro.kernels import ref as kref
+            agg_lanes = kops.community_spmm_ell if use_kernel \
+                else kref.community_spmm_ell_einsum
+            agg_full = jax.shard_map(
+                lambda ell, z: agg_lanes(*ell[:3], z, *ell[3:]), mesh=mesh,
+                in_specs=((sharded,) * 5, rep), out_specs=sharded,
+                check_vma=False)
+            adj_full = (data.ell_blocks, data.ell_indices, data.ell_mask,
+                        data.row_counts, data.nbr_counts)
         else:
-            a_blocks = self.data.a_blocks
-            nbr_f = self.data.neighbor_mask.astype(jnp.float32)
+            adj_full = (data.a_blocks, data.neighbor_mask)
 
-            def agg_full(z_pack):
+            def agg_full(adj, z_pack):
+                a_blocks, nbr = adj
+                nbr_f = nbr.astype(jnp.float32)
                 return jnp.einsum("mrip,rpc->mic",
                                   a_blocks * nbr_f[:, :, None, None], z_pack)
 
-        data = self.data
         f_act = gcn.activation_fn(cfg.activation)
 
         # metrics/Lagrangian run on the blocked (M, n_pad, ...) view; in
@@ -1341,7 +1378,7 @@ class ParallelADMMTrainer:
         # layout's global row table (take-with-fill, bitwise lossless
         # under the zero-outside-counts contract)
         if packed:
-            gup = jnp.asarray(self.packed_layout.global_unpack_rows())
+            gup = np.asarray(self.packed_layout.global_unpack_rows())
             n_pad_loc = self.layout.n_pad
 
             def unfold(p):
@@ -1351,36 +1388,37 @@ class ParallelADMMTrainer:
             def unfold(p):
                 return p
 
-        z0_blk = unfold(data.z0)
-        labels_blk = unfold(data.labels)
-        train_blk = unfold(data.train_mask)
-        test_blk = unfold(data.test_mask)
+        # the device data the host-side metrics read, bound as arguments
+        # (never baked into the programs as constants)
+        eval_data = {"adj": adj_full, "z0": data.z0, "labels": data.labels,
+                     "train": data.train_mask, "test": data.test_mask,
+                     "row_mask": data.row_mask, "denom": data.denom}
 
-        def forward_packed(weights):
-            """Community-blocked forward pass — logits (M, n_pad, C_L)."""
-            z = z0_blk
-            for l, w in enumerate(weights):
-                z = agg_full(z) @ w
-                if l < cfg.num_layers - 1:
-                    z = f_act(z)
-            return z
-
-        row_mask = data.row_mask[..., None]       # (M, n_pad, 1) true rows
+        def blocked(dev):
+            return (unfold(dev["z0"]), unfold(dev["labels"]),
+                    unfold(dev["train"]), unfold(dev["test"]),
+                    dev["row_mask"][..., None])   # (M, n_pad, 1) true rows
 
         @jax.jit
-        def metrics(state: ParallelState):
-            logits = forward_packed(state.weights)
+        def metrics(dev, state: ParallelState):
+            z0_blk, labels_blk, train_blk, test_blk, row_mask = blocked(dev)
+            # community-blocked forward pass — logits (M, n_pad, C_L)
+            logits = z0_blk
+            for l, w in enumerate(state.weights):
+                logits = agg_full(dev["adj"], logits) @ w
+                if l < cfg.num_layers - 1:
+                    logits = f_act(logits)
             z_pen = unfold(state.zs[-2]) if cfg.num_layers >= 2 else z0_blk
-            res = (unfold(state.zs[-1]) - agg_full(z_pen)
+            res = (unfold(state.zs[-1]) - agg_full(dev["adj"], z_pen)
                    @ state.weights[-1]) * row_mask
             return (gcn.accuracy(logits, labels_blk, train_blk),
                     gcn.accuracy(logits, labels_blk, test_blk),
                     jnp.linalg.norm(res))
 
-        self._metrics = metrics
+        self._metrics = BoundProgram(metrics, eval_data)
 
         @jax.jit
-        def lagrangian(state: ParallelState):
+        def lagrangian(dev, state: ParallelState):
             """ℒ_ρ(W, Z, U) — eq. (1) on the packed iterates.  Every
             residual is masked down to the true community rows
             (``row_mask``): pad slots carry zero adjacency/labels so the
@@ -1388,24 +1426,26 @@ class ParallelADMMTrainer:
             global or bucketed — never leaks into the objective, and the
             result equals the global subproblems.lagrangian_value on the
             unpacked state."""
+            z0_blk, labels_blk, train_blk, _, row_mask = blocked(dev)
             ws = state.weights
             zs = tuple(unfold(z) for z in state.zs)
             u = unfold(state.u)
             logp = jax.nn.log_softmax(zs[-1], axis=-1)
             nll = -jnp.take_along_axis(logp, labels_blk[..., None],
                                        axis=-1)[..., 0]
-            val = jnp.sum(nll * train_blk) / data.denom
+            val = jnp.sum(nll * train_blk) / dev["denom"]
             z_prev = z0_blk
             for l in range(cfg.num_layers - 1):
-                r = (zs[l] - f_act(agg_full(z_prev) @ ws[l])) * row_mask
+                r = (zs[l] - f_act(agg_full(dev["adj"], z_prev) @ ws[l])) \
+                    * row_mask
                 val += 0.5 * admm.nu * jnp.vdot(r, r).real
                 z_prev = zs[l]
-            r = (zs[-1] - agg_full(z_prev) @ ws[-1]) * row_mask
+            r = (zs[-1] - agg_full(dev["adj"], z_prev) @ ws[-1]) * row_mask
             val += jnp.vdot(u * row_mask, r).real \
                 + 0.5 * admm.rho * jnp.vdot(r, r).real
             return val
 
-        self._lagrangian = lagrangian
+        self._lagrangian = BoundProgram(lagrangian, eval_data)
 
     def _nbr_decay(self):
         """Per-ELL-slot staleness weight d_r = stale_decay**age_r, looked

@@ -51,17 +51,20 @@ class SerialADMMTrainer:
         self._lagr = jax.jit(partial(
             subproblems.lagrangian_value, cfg, admm))
 
+        # graph arrays are arguments, not closed-over constants (a dense
+        # Ã baked into the program is N² floats of literal)
         @jax.jit
-        def _metrics(state: subproblems.ADMMState):
-            logits = gcn.forward(cfg, self.a_tilde, self.z0,
-                                 state.weights)[-1]
-            z_pen = state.zs[-2] if cfg.num_layers >= 2 else self.z0
-            res = state.zs[-1] - self.a_tilde @ z_pen @ state.weights[-1]
-            return (gcn.accuracy(logits, self.labels, self.train_mask),
-                    gcn.accuracy(logits, self.labels, self.test_mask),
+        def _metrics(a_tilde, z0, labels, train_mask, test_mask,
+                     state: subproblems.ADMMState):
+            logits = gcn.forward(cfg, a_tilde, z0, state.weights)[-1]
+            z_pen = state.zs[-2] if cfg.num_layers >= 2 else z0
+            res = state.zs[-1] - a_tilde @ z_pen @ state.weights[-1]
+            return (gcn.accuracy(logits, labels, train_mask),
+                    gcn.accuracy(logits, labels, test_mask),
                     jnp.linalg.norm(res))
 
-        self._metrics = _metrics
+        self._metrics = partial(_metrics, self.a_tilde, self.z0,
+                                self.labels, self.train_mask, self.test_mask)
 
     def step(self) -> None:
         self.state = self._step(self.a_tilde, self.z0, self.labels,

@@ -28,6 +28,15 @@ declarative ``KernelSpec`` (``spmm_spec`` / ``ell_spec``) which
 ``repro.analysis.rules.pallas`` abstract-interprets to bound every block
 DMA against the operand shapes and to estimate the VMEM footprint — the
 kernel and the linter read the *same* spec, so they cannot drift.
+
+Tiling contract (what Mosaic accepts on v5e): every block's last two dims
+are multiples of (8, 128) or span the whole array dim.  ``_shrink`` only
+ever returns such tiles, so the contraction tile over ``n_pad`` is a
+128-multiple divisor when one exists and the full ``n_pad`` otherwise,
+and feature tiles are 128-multiples or the whole C.  The packed kernels
+read neighbour rows at 8-aligned offsets of the receive plane through
+element-indexed (``pl.Element``) Z windows, so a contraction tile may
+start mid-plane without an 8-lane adjacency block.
 """
 from __future__ import annotations
 
@@ -39,8 +48,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_TILE_N = 256     # rows per tile (8-aligned; 256 divides n_pad)
-DEFAULT_TILE_C = 256     # feature cols per tile (128-aligned)
+DEFAULT_TILE_N = 256     # output rows per tile (a multiple of 8)
+DEFAULT_TILE_C = 256     # feature cols per tile (a multiple of 128)
+DEFAULT_TILE_P = 256     # contraction rows per tile (a multiple of 128)
+SUBLANE, LANE = 8, 128   # the (8, 128) f32 VMEM tile of TPU v5e
+# scoped VMEM each kernel may claim (v5e has 128 MiB per core; Mosaic's
+# default scope is 16 MiB, below the full-width contraction blocks)
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 
 
 # ---------------------------------------------------------------------------
@@ -58,6 +72,13 @@ class BlockOperand:
     names the scalar-prefetch array whose *values* select this operand's
     leading block (data-dependent DMA): the linter bounds that array's
     value range against the leading block count.
+
+    ``element=True`` makes every block dim an element window
+    (``pl.Element``): the index map returns element offsets, not block
+    indices, and the gathered scalar counts ``gather_unit`` elements.
+    ``tail_pad`` is the zero rows the kernel appends to the operand so a
+    window starting on any real row stays in bounds; a gathered offset
+    must address a real row (below ``array_shape[0] - tail_pad``).
     """
     name: str
     array_shape: tuple[int, ...]
@@ -65,6 +86,9 @@ class BlockOperand:
     index_map: Callable[..., tuple]
     dtype_bytes: int = 4
     gather_scalar: Optional[str] = None
+    element: bool = False
+    gather_unit: int = 1
+    tail_pad: int = 0
 
     def block_bytes(self) -> int:
         n = 1
@@ -74,9 +98,36 @@ class BlockOperand:
         return n * self.dtype_bytes
 
     def block_counts(self) -> tuple[int, ...]:
-        """Valid block-index range per dim (None dims index elements)."""
+        """Valid index range per dim (None dims index elements; element
+        windows count their in-bounds start offsets)."""
+        if self.element:
+            return tuple(dim - b + 1 for dim, b in
+                         zip(self.array_shape, self.block_shape))
         return tuple(dim if b is None else -(-dim // b)
                      for dim, b in zip(self.array_shape, self.block_shape))
+
+    def gather_limit(self) -> int:
+        """Exclusive bound on the gathered scalar's values."""
+        if self.element:
+            return -(-(self.array_shape[0] - self.tail_pad)
+                     // self.gather_unit)
+        return self.block_counts()[0]
+
+    def pallas_block_spec(self):
+        """The ``pl.BlockSpec`` this operand lowers to.  Element windows
+        tell Mosaic their row offset is sublane-aligned (every gathered
+        offset and contraction tile is a multiple of 8 rows)."""
+        if not self.element:
+            return pl.BlockSpec(self.block_shape, self.index_map)
+        index_map = self.index_map
+
+        def aligned(*args):
+            *lead, row, col = index_map(*args)
+            return (*lead, pl.multiple_of(row, SUBLANE),
+                    pl.multiple_of(col, LANE))
+
+        return pl.BlockSpec(tuple(pl.Element(b) for b in self.block_shape),
+                            aligned)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,35 +146,40 @@ class KernelSpec:
                 + self.scratch_bytes)
 
 
-def _shrink(total: int, tile: int) -> int:
-    tile = min(tile, total)
-    while total % tile:
-        tile //= 2
-    return max(tile, 1)
+def _shrink(total: int, tile: int, quantum: int) -> int:
+    """Largest divisor of ``total`` that is at most ``tile`` and a
+    multiple of ``quantum``; ``total`` itself when none is (a block equal
+    to the array dim is always legal).  Never an unaligned partial tile."""
+    if total <= tile:
+        return total
+    for t in range(tile - tile % quantum, 0, -quantum):
+        if total % t == 0:
+            return t
+    return total
 
 
 def spmm_spec(m: int, n_pad: int, c: int, *,
               tile_n: int = DEFAULT_TILE_N, tile_c: int = DEFAULT_TILE_C,
               a_bytes: int = 4, z_bytes: int = 4) -> KernelSpec:
-    """Spec for the dense-block kernel (grid: row-tiles, col-tiles, M)."""
-    tile_n = _shrink(n_pad, tile_n)
-    tile_c = _shrink(c, tile_c)
+    """Spec for the dense-block kernel (grid: row-tiles, col-tiles, M;
+    the scalar-prefetched block mask gates each community step)."""
+    tile_n = _shrink(n_pad, tile_n, SUBLANE)
+    tile_c = _shrink(c, tile_c, LANE)
     return KernelSpec(
         name="community_spmm",
         grid=(n_pad // tile_n, c // tile_c, m),
         operands=(
-            BlockOperand("mask", (m,), (m,),
-                         lambda i, j, r: (0,), 4),
             BlockOperand("a_row", (m, n_pad, n_pad),
                          (None, tile_n, n_pad),
-                         lambda i, j, r: (r, i, 0), a_bytes),
+                         lambda i, j, r, msk: (r, i, 0), a_bytes),
             BlockOperand("z_all", (m, n_pad, c),
                          (None, n_pad, tile_c),
-                         lambda i, j, r: (r, 0, j), z_bytes),
+                         lambda i, j, r, msk: (r, 0, j), z_bytes),
             BlockOperand("out", (n_pad, c), (tile_n, tile_c),
-                         lambda i, j, r: (i, j), z_bytes),
+                         lambda i, j, r, msk: (i, j), z_bytes),
         ),
-        scratch_bytes=tile_n * tile_c * 4)
+        scratch_bytes=tile_n * tile_c * 4,
+        scalar_prefetch=("mask",))
 
 
 def ell_spec(k: int, max_deg: int, n_pad: int, c: int, m_total: int, *,
@@ -132,9 +188,9 @@ def ell_spec(k: int, max_deg: int, n_pad: int, c: int, m_total: int, *,
              block_bytes: int = 4, z_bytes: int = 4) -> KernelSpec:
     """Spec for the ELL kernel (grid: k, row-tiles, col-tiles, max_deg,
     contraction-tiles; scalar-prefetched ``ell_indices`` steer the Z DMA)."""
-    tile_n = _shrink(n_pad, tile_n)
-    tile_c = _shrink(c, tile_c)
-    tile_p = _shrink(n_pad, tile_n if tile_p is None else tile_p)
+    tile_n = _shrink(n_pad, tile_n, SUBLANE)
+    tile_c = _shrink(c, tile_c, LANE)
+    tile_p = _shrink(n_pad, tile_p or DEFAULT_TILE_P, LANE)
     return KernelSpec(
         name="community_spmm_ell",
         grid=(k, n_pad // tile_n, c // tile_c, max_deg, n_pad // tile_p),
@@ -157,27 +213,37 @@ def ell_spec(k: int, max_deg: int, n_pad: int, c: int, m_total: int, *,
                          "row_counts", "nbr_counts"))
 
 
+def _plane_window(plane_rows: int, tile_p: int, c: int, tile_c: int,
+                  z_bytes: int, index_map) -> BlockOperand:
+    """The packed receive plane as an element-windowed Z operand: the
+    kernel appends ``tile_p`` zero rows so a contraction tile that starts
+    on a real row never reads past the plane, and the index map clamps
+    dead tiles' starts (past a neighbour's rows, skipped by the
+    ``nbr_counts`` guard) to the first padding row."""
+    return BlockOperand("z_plane", (plane_rows + tile_p, c), (tile_p, tile_c),
+                        index_map, z_bytes, gather_scalar="ell_offsets8",
+                        element=True, gather_unit=SUBLANE, tail_pad=tile_p)
+
+
 def ell_fused_spec(k: int, max_deg: int, n_pad: int, c_in: int, c_out: int,
                    plane_rows: int, *,
                    tile_n: int = DEFAULT_TILE_N,
                    block_bytes: int = 4, z_bytes: int = 4) -> KernelSpec:
     """Spec for the fused aggregation→GEMM kernel.
 
-    Same packed-plane machinery as ``ell_packed_spec`` — the Z DMA reads
-    the (plane_rows, C_in) receive plane at the scalar-prefetched 8-row
-    offsets — but the grid carries no feature-tile axis: the whole
+    Same packed-plane machinery as ``ell_packed_spec`` — the Z window
+    reads the (plane_rows, C_in) receive plane at the scalar-prefetched
+    8-row offsets — but the grid carries no feature-tile axis: the whole
     (tile_n, C_in) aggregated block accumulates in VMEM scratch across
     the (d, p) reduction steps, and at the last step the per-community
     Z-update GEMM against the VMEM-resident ``w`` block writes the
     (tile_n, C_out) output directly.  The aggregated stack exists only
-    as that scratch tile — it never round-trips HBM (GCN feature dims
-    are small, so the un-tiled C axes stay well inside the VMEM budget;
-    ``repro.analysis.rules.pallas.check_kernel_vmem`` proves it against
-    this spec).
+    as that scratch tile — it never round-trips HBM
+    (``repro.analysis.rules.pallas.check_kernel_vmem`` bounds the
+    footprint against this spec).
     """
-    tile_n = _shrink(n_pad, tile_n)
-    tile_p = 8
-    zb = plane_rows // tile_p
+    tile_n = _shrink(n_pad, tile_n, SUBLANE)
+    tile_p = _shrink(n_pad, DEFAULT_TILE_P, LANE)
     return KernelSpec(
         name="community_spmm_ell_fused",
         grid=(k, n_pad // tile_n, max_deg, n_pad // tile_p),
@@ -186,11 +252,10 @@ def ell_fused_spec(k: int, max_deg: int, n_pad: int, c_in: int, c_out: int,
                          (None, None, tile_n, tile_p),
                          lambda m, i, d, p, off8, msk, rows, nbr:
                          (m, d, i, p), block_bytes),
-            BlockOperand("z_plane", (plane_rows, c_in),
-                         (tile_p, c_in),
-                         lambda m, i, d, p, off8, msk, rows, nbr:
-                         (jnp.minimum(off8[m, d] + p, zb - 1), 0), z_bytes,
-                         gather_scalar="ell_offsets8"),
+            _plane_window(plane_rows, tile_p, c_in, c_in, z_bytes,
+                          lambda m, i, d, p, off8, msk, rows, nbr:
+                          (jnp.minimum(off8[m, d] * SUBLANE + p * tile_p,
+                                       plane_rows), 0)),
             BlockOperand("w", (c_in, c_out), (c_in, c_out),
                          lambda m, i, d, p, off8, msk, rows, nbr:
                          (0, 0), z_bytes),
@@ -212,17 +277,15 @@ def ell_packed_spec(k: int, max_deg: int, n_pad: int, c: int,
     Z is the packed Σ-bucket-rows receive plane ``(plane_rows, C)`` —
     no ``(M, n_pad, C)`` stride.  The scalar-prefetched ``ell_offsets8``
     plane carries each stored neighbour's starting row *in 8-row units*
-    (every bucket size and plane offset is a multiple of the (8, 128)
-    tile quantum), so the contraction tiles at ``tile_p = 8`` and the Z
-    DMA for contraction step p starts at block ``off8[m, d] + p``.  The
-    ``jnp.minimum`` clamp keeps the map in bounds at grid corners past a
-    neighbour's true rows — those tiles are dead (the ``nbr_counts``
-    guard skips them) but pallas still evaluates their index map.
+    (every bucket size and plane offset is a multiple of 8 rows), and the
+    Z window for contraction step p starts at element row
+    ``8 · off8[m, d] + p · tile_p``.  A window may run past the
+    neighbour's rows into the next slot's; those rows are zeroed in the
+    kernel (``nbr_counts``), so they add nothing to the contraction.
     """
-    tile_n = _shrink(n_pad, tile_n)
-    tile_c = _shrink(c, tile_c)
-    tile_p = 8
-    zb = plane_rows // tile_p
+    tile_n = _shrink(n_pad, tile_n, SUBLANE)
+    tile_c = _shrink(c, tile_c, LANE)
+    tile_p = _shrink(n_pad, DEFAULT_TILE_P, LANE)
     return KernelSpec(
         name="community_spmm_ell_packed",
         grid=(k, n_pad // tile_n, c // tile_c, max_deg, n_pad // tile_p),
@@ -231,11 +294,10 @@ def ell_packed_spec(k: int, max_deg: int, n_pad: int, c: int,
                          (None, None, tile_n, tile_p),
                          lambda m, i, j, d, p, off8, msk, rows, nbr:
                          (m, d, i, p), block_bytes),
-            BlockOperand("z_plane", (plane_rows, c),
-                         (tile_p, tile_c),
-                         lambda m, i, j, d, p, off8, msk, rows, nbr:
-                         (jnp.minimum(off8[m, d] + p, zb - 1), j), z_bytes,
-                         gather_scalar="ell_offsets8"),
+            _plane_window(plane_rows, tile_p, c, tile_c, z_bytes,
+                          lambda m, i, j, d, p, off8, msk, rows, nbr:
+                          (jnp.minimum(off8[m, d] * SUBLANE + p * tile_p,
+                                       plane_rows), j * tile_c)),
             BlockOperand("out", (k, n_pad, c), (None, tile_n, tile_c),
                          lambda m, i, j, d, p, off8, msk, rows, nbr:
                          (m, i, j), z_bytes),
@@ -250,6 +312,14 @@ def ell_packed_spec(k: int, max_deg: int, n_pad: int, c: int,
 # ---------------------------------------------------------------------------
 
 
+def _f32_dot(a, b):
+    """The kernels' one MXU product: float32 operands at full float32
+    precision (explicit — Mosaic's default contract precision may round
+    the operands to bfloat16), accumulated in float32."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _spmm_kernel(mask_ref, a_ref, z_ref, o_ref, acc_scr):
     r = pl.program_id(2)
     n_r = pl.num_programs(2)
@@ -262,7 +332,7 @@ def _spmm_kernel(mask_ref, a_ref, z_ref, o_ref, acc_scr):
     def _accum():
         a = a_ref[...]                       # (tile_n, n_pad)
         z = z_ref[...]                       # (n_pad, tile_c)
-        acc_scr[...] += jnp.dot(a, z, preferred_element_type=jnp.float32)
+        acc_scr[...] += _f32_dot(a, z)
 
     @pl.when(r == n_r - 1)
     def _write():
@@ -274,24 +344,26 @@ def community_spmm(a_row: jax.Array, z_all: jax.Array, mask: jax.Array,
                    *, tile_n: int = DEFAULT_TILE_N,
                    tile_c: int = DEFAULT_TILE_C,
                    interpret: bool = False) -> jax.Array:
+    from jax.experimental.pallas import tpu as pltpu
+
     m, n_pad, _ = a_row.shape
     c = z_all.shape[-1]
     spec = spmm_spec(m, n_pad, c, tile_n=tile_n, tile_c=tile_c,
                      a_bytes=a_row.dtype.itemsize,
                      z_bytes=z_all.dtype.itemsize)
-    mask_op, a_op, z_op, out_op = spec.operands
+    a_op, z_op, out_op = spec.operands
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,     # block mask (SMEM)
+        grid=spec.grid,
+        in_specs=[a_op.pallas_block_spec(), z_op.pallas_block_spec()],
+        out_specs=out_op.pallas_block_spec(),
+        scratch_shapes=[_vmem_scratch(out_op.block_shape)],
+    )
     return pl.pallas_call(
         _spmm_kernel,
-        grid=spec.grid,
-        in_specs=[
-            pl.BlockSpec(mask_op.block_shape, mask_op.index_map),
-            pl.BlockSpec(a_op.block_shape, a_op.index_map),
-            pl.BlockSpec(z_op.block_shape, z_op.index_map),
-        ],
-        out_specs=pl.BlockSpec(out_op.block_shape, out_op.index_map),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_all.dtype),
-        scratch_shapes=[_vmem_scratch(
-            (out_op.block_shape[0], out_op.block_shape[1]))],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(mask.astype(jnp.int32), a_row, z_all)
 
@@ -299,6 +371,11 @@ def community_spmm(a_row: jax.Array, z_all: jax.Array, mask: jax.Array,
 def _vmem_scratch(shape):
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.VMEM(shape, jnp.float32)
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 # ---------------------------------------------------------------------------
@@ -326,8 +403,19 @@ def _vmem_scratch(shape):
 # ---------------------------------------------------------------------------
 
 
+def _neighbour_rows(z, nbr_rows, p, tile_p: int):
+    """Zero the Z tile's rows past the neighbour's own (as the oracle
+    does).  Strided Z holds zeros there already; a packed window may run
+    into the next slot's rows of the plane."""
+    row = p * tile_p + jax.lax.broadcasted_iota(jnp.int32, z.shape, 0)
+    return jnp.where(row < nbr_rows, z, 0.0)
+
+
 def _spmm_ell_kernel(idx_ref, msk_ref, rows_ref, nbr_ref, a_ref, z_ref,
                      o_ref, acc_scr, *, tile_n: int, tile_p: int):
+    """ELL accumulation over (d, p); shared by the strided kernel (Z
+    blocks steered by ``ell_indices``) and the packed one (Z windows at
+    ``ell_offsets8``) — only their index maps differ."""
     m = pl.program_id(0)
     i = pl.program_id(1)
     d = pl.program_id(3)
@@ -346,8 +434,9 @@ def _spmm_ell_kernel(idx_ref, msk_ref, rows_ref, nbr_ref, a_ref, z_ref,
     @pl.when(live)
     def _accum():
         a = a_ref[...].astype(jnp.float32)       # (tile_n, tile_p)
-        z = z_ref[...].astype(jnp.float32)       # (tile_p, tile_c)
-        acc_scr[...] += jnp.dot(a, z, preferred_element_type=jnp.float32)
+        z = _neighbour_rows(z_ref[...].astype(jnp.float32), nbr_ref[m, d],
+                            p, tile_p)           # (tile_p, tile_c)
+        acc_scr[...] += _f32_dot(a, z)
 
     @pl.when((d == n_d - 1) & (p == n_p - 1))
     def _write():
@@ -398,11 +487,8 @@ def community_spmm_ell(ell_blocks: jax.Array, ell_indices: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,     # ell_indices, ell_mask, rows, nbrs (SMEM)
         grid=spec.grid,
-        in_specs=[
-            pl.BlockSpec(a_op.block_shape, a_op.index_map),
-            pl.BlockSpec(z_op.block_shape, z_op.index_map),
-        ],
-        out_specs=pl.BlockSpec(out_op.block_shape, out_op.index_map),
+        in_specs=[a_op.pallas_block_spec(), z_op.pallas_block_spec()],
+        out_specs=out_op.pallas_block_spec(),
         scratch_shapes=[_vmem_scratch(
             (out_op.block_shape[1], out_op.block_shape[2]))],
     )
@@ -411,10 +497,43 @@ def community_spmm_ell(ell_blocks: jax.Array, ell_indices: jax.Array,
                           tile_p=eff_tile_p),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_all.dtype),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(ell_indices.astype(jnp.int32), ell_mask.astype(jnp.int32),
       row_counts.astype(jnp.int32), nbr_counts.astype(jnp.int32),
       ell_blocks, z_all)
+
+
+def _packed_call(kernel, spec: KernelSpec, ell_blocks, ell_offsets,
+                 ell_mask, z_plane, row_counts, nbr_counts, *extra,
+                 interpret: bool):
+    """pallas_call shared by the packed and fused kernels: offsets in
+    8-row units (masked slots pinned at 0, so every prefetched value
+    addresses the plane — the linter bounds the value range) and the
+    plane padded with the window's ``tail_pad`` zero rows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    a_op, z_op, *_, out_op = spec.operands
+    off8 = jnp.where(ell_mask != 0, ell_offsets // SUBLANE, 0)
+    z_pad = jnp.pad(z_plane, ((0, z_op.tail_pad), (0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,   # offsets8, ell_mask, rows, nbrs (SMEM)
+        grid=spec.grid,
+        in_specs=[op.pallas_block_spec() for op in spec.operands[:-1]],
+        out_specs=out_op.pallas_block_spec(),
+        scratch_shapes=[_vmem_scratch(
+            (out_op.block_shape[1], z_op.block_shape[1]))],
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, tile_n=out_op.block_shape[1],
+                          tile_p=a_op.block_shape[3]),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_plane.dtype),
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(off8.astype(jnp.int32), ell_mask.astype(jnp.int32),
+      row_counts.astype(jnp.int32), nbr_counts.astype(jnp.int32),
+      ell_blocks, z_pad, *extra)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "tile_c", "interpret"))
@@ -442,40 +561,15 @@ def community_spmm_ell_packed(ell_blocks: jax.Array, ell_offsets: jax.Array,
     nbr_counts:  (k, max_deg) int32 — each stored neighbour's rows
     returns      (k, n_pad, C) blocked output, rows past row_counts zero
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     k, max_deg, n_pad, _ = ell_blocks.shape
     plane_rows, c = z_plane.shape
     spec = ell_packed_spec(k, max_deg, n_pad, c, plane_rows,
                            tile_n=tile_n, tile_c=tile_c,
                            block_bytes=ell_blocks.dtype.itemsize,
                            z_bytes=z_plane.dtype.itemsize)
-    a_op, z_op, out_op = spec.operands
-    eff_tile_n = out_op.block_shape[1]
-
-    # 8-row-unit offsets; masked slots pinned at 0 so every prefetched
-    # value indexes inside the plane (the linter bounds the value range)
-    off8 = jnp.where(ell_mask != 0, ell_offsets // 8, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,   # offsets8, ell_mask, rows, nbrs (SMEM)
-        grid=spec.grid,
-        in_specs=[
-            pl.BlockSpec(a_op.block_shape, a_op.index_map),
-            pl.BlockSpec(z_op.block_shape, z_op.index_map),
-        ],
-        out_specs=pl.BlockSpec(out_op.block_shape, out_op.index_map),
-        scratch_shapes=[_vmem_scratch(
-            (out_op.block_shape[1], out_op.block_shape[2]))],
-    )
-    return pl.pallas_call(
-        functools.partial(_spmm_ell_kernel, tile_n=eff_tile_n, tile_p=8),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_plane.dtype),
-        interpret=interpret,
-    )(off8.astype(jnp.int32), ell_mask.astype(jnp.int32),
-      row_counts.astype(jnp.int32), nbr_counts.astype(jnp.int32),
-      ell_blocks, z_plane)
+    return _packed_call(_spmm_ell_kernel, spec, ell_blocks,
+                        ell_offsets, ell_mask, z_plane, row_counts,
+                        nbr_counts, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -516,15 +610,14 @@ def _spmm_ell_fused_kernel(off_ref, msk_ref, rows_ref, nbr_ref, a_ref,
     @pl.when(live)
     def _accum():
         a = a_ref[...].astype(jnp.float32)       # (tile_n, tile_p)
-        z = z_ref[...].astype(jnp.float32)       # (tile_p, c_in)
-        agg_scr[...] += jnp.dot(a, z, preferred_element_type=jnp.float32)
+        z = _neighbour_rows(z_ref[...].astype(jnp.float32), nbr_ref[m, d],
+                            p, tile_p)           # (tile_p, c_in)
+        agg_scr[...] += _f32_dot(a, z)
 
     @pl.when((d == n_d - 1) & (p == n_p - 1))
     def _write():
         w = w_ref[...].astype(jnp.float32)       # (c_in, c_out)
-        o_ref[...] = jnp.dot(agg_scr[...], w,
-                             preferred_element_type=jnp.float32
-                             ).astype(o_ref.dtype)
+        o_ref[...] = _f32_dot(agg_scr[...], w).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
@@ -547,8 +640,6 @@ def community_spmm_ell_fused(ell_blocks: jax.Array, ell_offsets: jax.Array,
     ``agg @ w`` contraction differently.  Returns (k, n_pad, C_out) with
     rows past ``row_counts`` zero.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     k, max_deg, n_pad, _ = ell_blocks.shape
     plane_rows, c_in = z_plane.shape
     c_out = w.shape[-1]
@@ -556,30 +647,6 @@ def community_spmm_ell_fused(ell_blocks: jax.Array, ell_offsets: jax.Array,
                           tile_n=tile_n,
                           block_bytes=ell_blocks.dtype.itemsize,
                           z_bytes=z_plane.dtype.itemsize)
-    a_op, z_op, w_op, out_op = spec.operands
-    eff_tile_n = out_op.block_shape[1]
-
-    # 8-row-unit offsets; masked slots pinned at 0 so every prefetched
-    # value indexes inside the plane (the linter bounds the value range)
-    off8 = jnp.where(ell_mask != 0, ell_offsets // 8, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,   # offsets8, ell_mask, rows, nbrs (SMEM)
-        grid=spec.grid,
-        in_specs=[
-            pl.BlockSpec(a_op.block_shape, a_op.index_map),
-            pl.BlockSpec(z_op.block_shape, z_op.index_map),
-            pl.BlockSpec(w_op.block_shape, w_op.index_map),
-        ],
-        out_specs=pl.BlockSpec(out_op.block_shape, out_op.index_map),
-        scratch_shapes=[_vmem_scratch((eff_tile_n, c_in))],
-    )
-    return pl.pallas_call(
-        functools.partial(_spmm_ell_fused_kernel, tile_n=eff_tile_n,
-                          tile_p=8),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_plane.dtype),
-        interpret=interpret,
-    )(off8.astype(jnp.int32), ell_mask.astype(jnp.int32),
-      row_counts.astype(jnp.int32), nbr_counts.astype(jnp.int32),
-      ell_blocks, z_plane, w)
+    return _packed_call(_spmm_ell_fused_kernel, spec, ell_blocks,
+                        ell_offsets, ell_mask, z_plane, row_counts,
+                        nbr_counts, w, interpret=interpret)

@@ -82,15 +82,15 @@ def _build_trainer(spec: dict):
     from repro.core import gcn, graph
     from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
     from repro.core.subproblems import ADMMConfig
-    from repro.util.compat import make_mesh
+    from jax.sharding import AxisType
 
     g, part = graph.synthetic_powerlaw_communities(
         num_parts=8, nodes_per_part=12, attach=1, seed=0, feat_dim=8,
         size_skew=0.8)
     cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
     admm = ADMMConfig(nu=1e-3, rho=1e-3)
-    mesh = make_mesh((N_SHARDS,), (AXIS,),
-                     devices=jax.devices()[:N_SHARDS])
+    mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,),
+                         devices=jax.devices()[:N_SHARDS])
     # the spec dicts ARE TrainerConfig kwargs (single source of truth);
     # only the compressed default differs from the dataclass default
     kw = {k: v for k, v in spec.items() if k != "name"}
@@ -109,13 +109,7 @@ def run_configs(configs: list[dict]) -> list:
     waivers = (analysis.Waiver(
         "memory/no-dense-adjacency",
         "the dense baseline IS the dense layout",
-        when={"compressed": False}),
-               analysis.Waiver(
-        "pallas/tile-alignment",
-        "the packed ELL kernel contracts in 8-row steps by design — "
-        "bucket sizes and plane offsets are multiples of the 8-row "
-        "tile quantum, so the ell_blocks lane dim is 8, not 128",
-        when={"state_packed": True}))
+        when={"compressed": False}),)
     reports = []
     for spec in configs:
         tr = _build_trainer(spec)

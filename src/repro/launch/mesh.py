@@ -9,8 +9,7 @@ Target hardware (roofline constants): TPU v5e — 197 TFLOP/s bf16/chip,
 from __future__ import annotations
 
 import jax
-
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 PEAK_FLOPS = 197e12       # bf16 per chip
 HBM_BW = 819e9            # bytes/s per chip
@@ -21,15 +20,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = 512 if multi_pod else 256
-    return make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])
 
 
 def make_host_mesh(model_axis: int = 1):
     """Small mesh over whatever host devices exist (tests/examples)."""
     n = len(jax.devices())
     data = n // model_axis
-    return make_mesh((data, model_axis), ("data", "model"),
-                     devices=jax.devices()[:data * model_axis])
+    return jax.make_mesh((data, model_axis), ("data", "model"),
+                         (AxisType.Auto,) * 2,
+                         devices=jax.devices()[:data * model_axis])
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -66,6 +66,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.core import gcn, graph
     from repro.core.parallel import ParallelADMMTrainer, TrainerConfig
     from repro.core.subproblems import ADMMConfig

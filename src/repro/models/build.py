@@ -187,7 +187,6 @@ class Model:
         The 'model' axis stays auto, so tensor-parallel sharding inside the
         loss is unchanged.
         """
-        from repro.util import shard_map as _shard_map
         from jax.sharding import PartitionSpec as P
         dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         accum = max(self.cfg.grad_accum, 1)
@@ -222,11 +221,11 @@ class Model:
         for a in dp:
             n_dp *= mesh.shape[a]
         batch_spec = jax.tree.map(lambda _: P(dp), batch)
-        grads, loss_sum, mets = _shard_map(
+        grads, loss_sum, mets = jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params), batch_spec),
             out_specs=(jax.tree.map(lambda _: P(), params), P(), P()),
-            check_rep=False, axis_names=dp)(params, batch)
+            check_vma=False, axis_names=frozenset(dp))(params, batch)
         grads = jax.tree.map(lambda g: g / (accum * n_dp), grads)
         loss_val = loss_sum / (accum * n_dp)
         metrics = jax.tree.map(lambda m: m.mean() / n_dp, mets)

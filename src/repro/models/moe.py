@@ -178,7 +178,6 @@ def apply_moe_a2a(cfg: ModelConfig, p: Params, x: Array, mesh
     deferred-reduction train step is already manual over data at an outer
     level and keeps the portable path.
     """
-    from repro.util import shard_map as _shard_map
     from jax.sharding import PartitionSpec as P
 
     moe = cfg.moe
@@ -263,13 +262,13 @@ def apply_moe_a2a(cfg: ModelConfig, p: Params, x: Array, mesh
     n_split = _dp_size(mesh) * nm
     tok = P(t_axes if (b * s) % n_split == 0 else
             (dp if (b * s) % _dp_size(mesh) == 0 else None), None)
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok, rep2, P("model", None, None),
                   P("model", None, None), P("model", None, None),
                   jax.tree.map(lambda _: rep2, shared)),
         out_specs=(tok, P()),
-        check_rep=False, axis_names=dp + ("model",))(
+        check_vma=False, axis_names=frozenset(dp + ("model",)))(
         xf, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)
     return out.reshape(b, s, d), aux
 

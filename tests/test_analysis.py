@@ -287,7 +287,8 @@ def test_tile_alignment_warns_on_ragged_blocks():
     bad = _toy_spec(lambda i, j: (0, 0), blocks=(64, 100),
                     array=(256, 400), grid=(1, 1))
     findings = check_tile_alignment(bad)
-    assert findings and findings[0].severity == Severity.WARNING
+    # Mosaic refuses such a block outright: an error, not a warning
+    assert findings and findings[0].severity == Severity.ERROR
 
 
 # ---------------------------------------------------------------------------

@@ -221,15 +221,15 @@ from repro.core import gcn, graph
 from repro.core.parallel import AXIS, ParallelADMMTrainer
 from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 assert len(jax.devices()) >= 2, jax.devices()
 g, part = graph.synthetic_powerlaw_communities(
     num_parts=4, nodes_per_part=16, attach=1, seed=3, feat_dim=8)
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh2 = make_mesh((2,), (AXIS,), devices=jax.devices()[:2])
-mesh1 = make_mesh((1,), (AXIS,), devices=jax.devices()[:1])
+mesh2 = jax.make_mesh((2,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:2])
+mesh1 = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:1])
 
 # dense vs compressed on a 2-shard mesh (k=2 lanes per shard)
 dense2 = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,

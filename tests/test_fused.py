@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.analysis.registry import AnalysisContext
 from repro.analysis.rules.memory import (fused_agg_handoffs,
@@ -31,7 +32,6 @@ from repro.core.subproblems import ADMMConfig
 from repro.kernels import ops, ref
 from repro.kernels.community_spmm import (community_spmm_ell_fused,
                                           ell_fused_spec)
-from repro.util.compat import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +165,7 @@ def test_fused_one_shard_is_bitwise_inert():
     g, part = graph.synthetic_powerlaw_communities(
         num_parts=8, nodes_per_part=12, attach=1, seed=0, feat_dim=8,
         size_skew=0.8)
-    mesh = make_mesh((1,), (AXIS,))
+    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
     ref_tr = _trainer(g, part, mesh)
     fu_tr = _trainer(g, part, mesh, fused=True)
     for _ in range(3):
@@ -273,14 +273,14 @@ from repro.analysis.rules.memory import fused_agg_handoffs
 from repro.core import gcn, graph
 from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 g, part = graph.synthetic_powerlaw_communities(
     num_parts=8, nodes_per_part=12, attach=1, seed=0, feat_dim=8,
     size_skew=0.8)
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh = make_mesh((4,), (AXIS,), devices=jax.devices()[:4])
+mesh = jax.make_mesh((4,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:4])
 
 def build(**kw):
     return ParallelADMMTrainer(cfg, admm, g, num_parts=8, seed=0,
@@ -312,10 +312,7 @@ fu_h = len(fused_agg_handoffs(jax.make_jaxpr(fu._step)(fu.state), n_pad))
 un_h = len(fused_agg_handoffs(jax.make_jaxpr(un._step)(un.state), n_pad))
 assert fu_h == cfg.num_layers, (fu_h, cfg.num_layers)
 assert un_h > fu_h, (un_h, fu_h)
-waivers = (analysis.Waiver(
-    "pallas/tile-alignment", "packed ELL contracts in 8-row steps",
-    when={"state_packed": True}),)
-rep = analysis.analyze_trainer(fu, config="p2p_fused", waivers=waivers)
+rep = analysis.analyze_trainer(fu, config="p2p_fused")
 assert analysis.no_findings(rep, rule="memory/fused-no-intermediate")
 assert not rep.errors(), rep.summary()
 print("FU_ANALYSIS_OK")

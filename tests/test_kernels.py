@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.community_spmm import community_spmm, community_spmm_ell
+from repro.kernels.community_spmm import (community_spmm, community_spmm_ell,
+                                          community_spmm_ell_packed)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
@@ -107,6 +108,48 @@ def test_community_spmm_ell_skips_padding_lanes():
     full = ref.community_spmm_ell_einsum(blocks, idx,
                                          jnp.ones_like(mask), z)
     assert np.abs(np.asarray(out) - np.asarray(full)).max() > 1e-3
+
+
+# ---------------------------------------------------------------------------
+# community_spmm_ell_packed (packed receive plane, element-windowed Z)
+# ---------------------------------------------------------------------------
+
+def _plane_inputs(k, max_deg, n_pad, c, seed=0):
+    """Slots packed back to back at 8-aligned offsets, bucket counts in
+    multiples of 8, adjacency rows past a lane's count zero (the layout
+    contract).  Adjacency columns past a neighbour's count are left
+    NONZERO: a Z window that runs into the next slot's rows (or the
+    plane's tail) must be zeroed by the kernel, not by the adjacency."""
+    rng = np.random.default_rng(seed)
+    n_slots = k + 2
+    counts = 8 * rng.integers(1, n_pad // 8 + 1, size=n_slots)
+    offsets = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
+    slot = rng.integers(0, n_slots, size=(k, max_deg))
+    slot[0, 0] = n_slots - 1              # the window that ends the plane
+    mask = np.zeros((k, max_deg), np.float32)
+    for r in range(k):
+        mask[r, : 1 + r % max_deg] = 1.0
+    rows = (8 * rng.integers(1, n_pad // 8 + 1, size=k)).astype(np.int32)
+    blocks = rng.normal(size=(k, max_deg, n_pad, n_pad)).astype(np.float32)
+    blocks *= np.arange(n_pad)[None, None, :, None] < rows[:, None, None,
+                                                           None]
+    plane = rng.normal(size=(int(counts.sum()), c)).astype(np.float32)
+    return tuple(jnp.asarray(x) for x in
+                 (blocks, offsets[slot], mask, plane, rows,
+                  counts[slot].astype(np.int32)))
+
+
+@pytest.mark.parametrize("k,max_deg,n_pad,c", [
+    (2, 3, 32, 8),        # one contraction tile (full n_pad)
+    (3, 2, 512, 16),      # two 256-row tiles, windows cross slots
+    (2, 2, 72, 130),      # C not a lane multiple: full-width tile
+])
+def test_community_spmm_ell_packed_matches_oracle(k, max_deg, n_pad, c):
+    args = _plane_inputs(k, max_deg, n_pad, c)
+    out = community_spmm_ell_packed(*args, interpret=True)
+    expect = ref.community_spmm_ell_packed_einsum(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=2e-4, atol=2e-4)
 
 
 # ---------------------------------------------------------------------------

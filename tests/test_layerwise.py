@@ -66,6 +66,7 @@ def test_layerwise_admm_sharded_runs():
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 host devices")
     mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2,
                          devices=jax.devices()[:4])
     cfg = get_config("qwen2-7b", reduced=True)
     tr = LayerwiseADMMTrainer(cfg, ADMMConfig(nu=1e-2, rho=1e-2), mesh=mesh)

@@ -14,14 +14,15 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import gcn, graph, messages
 from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig, stale_weights
 from repro.sharding.partition import CommunityBatchSampler
-from repro.util.compat import make_mesh
 
 
 def _skewed(m=8, seed=0, skew=0.8):
@@ -254,7 +255,7 @@ def test_fraction_one_matches_packed_bitwise_one_shard():
     must equal the full-batch packed trainer BITWISE (identity masks and
     decay 1.0 multiply exactly, the full-set sub-plan IS the plan)."""
     g, part = _skewed()
-    mesh = make_mesh((1,), (AXIS,))
+    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
     ref = _trainer(g, part, mesh, TrainerConfig.packed())
     mb = _trainer(g, part, mesh,
                   TrainerConfig.minibatch(batch_fraction=1.0))
@@ -273,7 +274,7 @@ def test_fraction_one_matches_packed_bitwise_one_shard():
 
 def test_minibatch_comm_stats_and_age_tracking():
     g, part = _skewed()
-    mesh = make_mesh((1,), (AXIS,))
+    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
     mb = _trainer(g, part, mesh,
                   TrainerConfig.minibatch(batch_fraction=1.0,
                                           stale_decay=0.75,
@@ -306,14 +307,14 @@ from repro import analysis
 from repro.core import gcn, graph, messages
 from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 g, part = graph.synthetic_powerlaw_communities(
     num_parts=8, nodes_per_part=12, attach=1, seed=0, feat_dim=8,
     size_skew=0.8)
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh = make_mesh((4,), (AXIS,), devices=jax.devices()[:4])
+mesh = jax.make_mesh((4,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:4])
 
 def build(config):
     return ParallelADMMTrainer(cfg, admm, g, num_parts=8, seed=0,
@@ -364,11 +365,7 @@ sub_pairs = {p for r in mb._active_plan.rounds for p in r.pairs}
 full_pairs = {p for r in mb._plan.rounds for p in r.pairs}
 assert sub_pairs < full_pairs
 assert all(d in sampled for _, d in sub_pairs)
-waivers = (analysis.Waiver(
-    "pallas/tile-alignment", "packed ELL contracts in 8-row steps",
-    when={"state_packed": True}),)
-rep = analysis.analyze_trainer(mb, config="p2p_minibatch",
-                               waivers=waivers)
+rep = analysis.analyze_trainer(mb, config="p2p_minibatch")
 assert analysis.no_findings(rep, rule="collective/permute-schedule")
 assert analysis.no_findings(rep, rule="collective/no-allgather-under-p2p")
 assert not rep.errors(), rep.summary()
@@ -404,14 +401,14 @@ from repro.analysis.trainer import _gathered_cs
 from repro.core import gcn, graph, messages
 from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 g, part = graph.synthetic_powerlaw_communities(
     num_parts=8, nodes_per_part=12, attach=1, seed=0, feat_dim=8,
     size_skew=0.8)
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh = make_mesh((4,), (AXIS,), devices=jax.devices()[:4])
+mesh = jax.make_mesh((4,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:4])
 
 def build(config):
     return ParallelADMMTrainer(cfg, admm, g, num_parts=8, seed=0,

@@ -5,11 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.models import moe as moe_lib
 from repro.sharding.hints import sharding_hints
-from repro.util.compat import make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -21,7 +21,7 @@ def setup():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(2, 32, cfg.d_model))
                     .astype(np.float32) * 0.5)
-    mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+    mesh = jax.make_mesh((2, 2), ("data", "model"), (AxisType.Auto,) * 2, devices=jax.devices()[:4])
     return cfg, p, x, mesh
 
 
@@ -47,7 +47,6 @@ def test_a2a_gated_off_inside_manual_region(setup):
     """Inside an enclosing shard_map (deferred train step) the a2a path
     must defer to the portable dispatch instead of nesting shard_maps."""
     from jax.sharding import PartitionSpec as P
-    from repro.util import shard_map
     cfg, p, x, mesh = setup
 
     def body(xs):
@@ -55,9 +54,9 @@ def test_a2a_gated_off_inside_manual_region(setup):
         return out
 
     with mesh, sharding_hints(mesh, moe_a2a=True):
-        fn = shard_map(body, mesh=mesh, in_specs=P("data", None, None),
-                       out_specs=P("data", None, None), check_rep=False,
-                       axis_names=("data",))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P("data", None, None),
+                           out_specs=P("data", None, None), check_vma=False,
+                           axis_names=frozenset({"data"}))
         out = jax.jit(fn)(x)          # would raise on nested manual axes
     assert np.isfinite(np.asarray(out)).all()
 

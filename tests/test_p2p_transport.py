@@ -336,8 +336,7 @@ from repro.core import gcn, graph, messages
 from repro.core.parallel import AXIS, ParallelADMMTrainer
 from repro.core.subproblems import ADMMConfig
 from repro.launch import roofline
-from repro.util import shard_map
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 N_SHARDS = 4
@@ -346,7 +345,7 @@ g, part = graph.synthetic_powerlaw_communities(
     num_parts=12, nodes_per_part=12, attach=1, seed=0, feat_dim=8)
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh2 = make_mesh((N_SHARDS,), (AXIS,), devices=jax.devices()[:N_SHARDS])
+mesh2 = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:N_SHARDS])
 
 # --- raw exchange == the needed rows of an all-gather, on real devices ---
 layout = graph.build_community_layout(g.num_nodes, g.edges, part,
@@ -355,9 +354,9 @@ plan = messages.build_neighbor_exchange(layout.neighbor_mask, N_SHARDS,
                                         layout.n_pad)
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(12, layout.n_pad, 8)).astype(np.float32))
-ex = shard_map(lambda v: messages.exchange_neighbors(plan, v, AXIS),
+ex = jax.shard_map(lambda v: messages.exchange_neighbors(plan, v, AXIS),
                mesh=mesh2, in_specs=(P(AXIS),), out_specs=P(AXIS),
-               check_rep=False)
+               check_vma=False)
 bufs = np.asarray(jax.jit(ex)(x)).reshape(N_SHARDS, plan.r_pad,
                                           layout.n_pad, 8)
 for s in range(N_SHARDS):
@@ -438,7 +437,7 @@ from repro.core import gcn, graph
 from repro.core.parallel import AXIS, ParallelADMMTrainer
 from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 N_SHARDS = 4
 assert len(jax.devices()) >= N_SHARDS, jax.devices()
@@ -446,7 +445,7 @@ g, _ = graph.synthetic_powerlaw_communities(
     num_parts=12, nodes_per_part=12, attach=1, seed=0, feat_dim=8)
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh = make_mesh((N_SHARDS,), (AXIS,), devices=jax.devices()[:N_SHARDS])
+mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:N_SHARDS])
 
 serial = SerialADMMTrainer(cfg, admm, g, seed=0)
 ml = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, mesh=mesh,

@@ -15,14 +15,15 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro import analysis
 from repro.core import gcn, graph
 from repro.core.parallel import AXIS, ParallelADMMTrainer
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
 
 
 def _skewed(m=8, seed=0, skew=0.8):
@@ -91,7 +92,7 @@ def test_global_unpack_rows_is_the_scatter_inverse():
 
 def test_packed_flag_validation():
     g, part = _skewed()
-    mesh = make_mesh((1,), (AXIS,))
+    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
     with pytest.raises(ValueError, match="compressed"):
         _trainer(g, part, mesh, packed=True, compressed=False)
     with pytest.raises(ValueError, match="p2p"):
@@ -102,7 +103,7 @@ def test_packed_flag_validation():
 
 def test_comm_stats_state_accounting():
     g, part = _skewed()
-    mesh = make_mesh((1,), (AXIS,))
+    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
     tr = _trainer(g, part, mesh, packed=True)
     st = tr.comm_stats["state"]
     assert st["packed"] is True
@@ -127,7 +128,7 @@ def test_packed_trainer_bitwise_matches_strided_one_shard():
     runs the identical blocked math — every iterate, the Lagrangian and
     the metrics must match the strided trainer BITWISE."""
     g, part = _skewed()
-    mesh = make_mesh((1,), (AXIS,))
+    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
     ref = _trainer(g, part, mesh)
     pk = _trainer(g, part, mesh, packed=True)
     dl = pk.packed_layout
@@ -202,7 +203,7 @@ from repro.core import gcn, graph
 from repro.core.parallel import AXIS, ParallelADMMTrainer
 from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 N_SHARDS = 4
 assert len(jax.devices()) >= N_SHARDS, jax.devices()
@@ -211,7 +212,7 @@ g, part = graph.synthetic_powerlaw_communities(
     size_skew=0.8)
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh = make_mesh((N_SHARDS,), (AXIS,), devices=jax.devices()[:N_SHARDS])
+mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:N_SHARDS])
 
 def build(**kw):
     return ParallelADMMTrainer(cfg, admm, g, num_parts=8, seed=0,

@@ -365,7 +365,7 @@ from repro.core import gcn, graph
 from repro.core.parallel import AXIS, ParallelADMMTrainer
 from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
+from jax.sharding import AxisType
 
 N_SHARDS = 4
 assert len(jax.devices()) >= N_SHARDS, jax.devices()
@@ -376,7 +376,7 @@ sizes = np.bincount(part, minlength=12)
 assert sizes.max() >= 2 * sizes.min()          # genuinely skewed
 cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
 admm = ADMMConfig(nu=1e-3, rho=1e-3)
-mesh = make_mesh((N_SHARDS,), (AXIS,), devices=jax.devices()[:N_SHARDS])
+mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.devices()[:N_SHARDS])
 
 serial = SerialADMMTrainer(cfg, admm, g, seed=0)
 rag = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, part=part,

@@ -95,14 +95,14 @@ def test_pipeline_places_batches():
 # ---------------------------------------------------------------------------
 
 def test_param_specs_rules():
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.configs import get_config
     from repro.models.build import make_model
     from repro.sharding import partition
-    from repro.util.compat import make_mesh
 
     n = len(jax.devices())
-    mesh = make_mesh((1, n), ("data", "model"), devices=jax.devices())
+    mesh = jax.make_mesh((1, n), ("data", "model"), (AxisType.Auto,) * 2,
+                         devices=jax.devices())
     cfg = get_config("deepseek-moe-16b")      # full config, abstract only
     model = make_model(cfg)
     params_s = jax.eval_shape(model.init, jax.random.key(0))
@@ -146,18 +146,17 @@ def test_hlo_census_counts_scan_trips():
 
 def test_hlo_census_collectives():
     from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType
     from repro.launch.roofline import hlo_census
-    from repro.util import shard_map
-    from repro.util.compat import make_mesh
     n = len(jax.devices())
     if n < 2:
         pytest.skip("needs >1 device")
-    mesh = make_mesh((n,), ("d",), devices=jax.devices())
+    mesh = jax.make_mesh((n,), ("d",), (AxisType.Auto,), devices=jax.devices())
 
     def g(x):
-        return shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
-                         in_specs=P("d"), out_specs=P(),
-                         check_rep=False)(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
+                             in_specs=P("d"), out_specs=P(),
+                             check_vma=False)(x)
 
     x = jax.ShapeDtypeStruct((n, 64), jnp.float32)
     hlo = jax.jit(g).lower(x).compile().as_text()

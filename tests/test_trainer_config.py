@@ -11,12 +11,13 @@ DeprecationWarning exactly once.
 import argparse
 import warnings
 
+import jax
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import gcn, graph
 from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig
-from repro.util.compat import make_mesh
 
 
 def _graph():
@@ -29,7 +30,7 @@ def _trainer(config=None, **kw):
     g, part = _graph()
     cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
     admm = ADMMConfig(nu=1e-3, rho=1e-3)
-    mesh = make_mesh((1,), (AXIS,))
+    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
     return ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0,
                                part=part, mesh=mesh, config=config, **kw)
 
