@@ -1,0 +1,15 @@
+"""exchange_exposed_ms (ms): the part of ``exchange_ms`` during which the
+device runs no other operation, per round; the mean over the cell's
+devices."""
+from harness import names, trace
+
+MATCH = names.matcher(names.EXCHANGE_OP)
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    found = any(MATCH(e[0]) for d in tr["devices"].values() for e in d["ops"])
+    if ctx["rounds"] <= 0 or not found:
+        return None
+    s = trace.mean_over_devices(tr, lambda d: trace.exposed_s(tr, d, MATCH))
+    return 1e3 * s / ctx["rounds"]
