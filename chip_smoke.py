@@ -160,7 +160,8 @@ def transfer_state(src, dst, state):
     host = ParallelState(tuple(np.asarray(w) for w in state.weights),
                          tuple(plane(z) for z in state.zs), plane(state.u),
                          tuple(np.asarray(t) for t in state.taus),
-                         tuple(np.asarray(t) for t in state.thetas))
+                         tuple(np.asarray(t) for t in state.thetas),
+                         np.zeros((dst.mesh.size, 2), np.int32))
     return place_on_mesh(dst.mesh, host, dst.state_spec)
 
 

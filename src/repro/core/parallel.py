@@ -113,6 +113,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.core import gcn, graph, messages
 from repro.core.subproblems import ADMMConfig, stale_weights
 from repro.sharding.partition import CommunityBatchSampler
+from repro.util import spans
 
 Array = jax.Array
 AXIS = "comm"
@@ -154,6 +155,10 @@ class ParallelState(NamedTuple):
     u: Array                     # sharded
     taus: tuple[Array, ...]      # scalars, replicated
     thetas: tuple[Array, ...]    # (M,), sharded
+    # (n_shards, 2) int32, sharded: per shard, since construction, the
+    # line-search objective evaluations and the searches that ran all
+    # ``max_backtracks`` iterations (``probe_count``)
+    probes: Array
 
 
 @dataclasses.dataclass(frozen=True)
@@ -431,9 +436,19 @@ _LEGACY_FLAGS = ("use_kernel", "comm_bf16", "compressed", "transport",
 # backtracking primitives
 # ---------------------------------------------------------------------------
 
+def probe_count(iters, admm: ADMMConfig):
+    """``[evaluations, capped]`` (int32) of one line search whose ``while``
+    ran ``iters`` iterations: the first test plus one objective evaluation
+    per iteration, and 1 when it ran all ``max_backtracks`` of them."""
+    iters = jnp.asarray(iters, jnp.int32)
+    return jnp.stack([iters + 1,
+                      (iters >= admm.max_backtracks).astype(jnp.int32)])
+
+
 def backtracking_step_psum(local_obj, x, tau0, admm: ADMMConfig):
     """Majorize-minimize step on the *global* objective psum(local_obj):
-    every shard evaluates the same condition and accepts the same τ."""
+    every shard evaluates the same condition and accepts the same τ.
+    Returns the step, τ and the search's ``probe_count``."""
     val_loc, grad_loc = jax.value_and_grad(local_obj)(x)
     val = jax.lax.psum(val_loc, AXIS)
     grad = jax.lax.psum(grad_loc, AXIS)
@@ -455,8 +470,8 @@ def backtracking_step_psum(local_obj, x, tau0, admm: ADMMConfig):
         return tau * admm.backtrack_growth, it + 1
 
     tau0 = jnp.maximum(tau0 / admm.backtrack_growth, 1e-8)
-    tau, _ = jax.lax.while_loop(cond, body, (tau0, jnp.asarray(0)))
-    return x - grad / tau, tau
+    tau, iters = jax.lax.while_loop(cond, body, (tau0, jnp.asarray(0)))
+    return x - grad / tau, tau, probe_count(iters, admm)
 
 
 def backtracking_step_lanes(obj_lanes, x, theta0, admm: ADMMConfig):
@@ -464,7 +479,9 @@ def backtracking_step_lanes(obj_lanes, x, theta0, admm: ADMMConfig):
 
     obj_lanes: (k, n, C) -> (k,) per-community objective values.
     x: (k, n, C); theta0: (k,).  Lanes line-search independently: the loop
-    runs until every lane accepts, frozen lanes stop doubling.
+    runs until every lane accepts, frozen lanes stop doubling.  Returns the
+    step, θ and the search's ``probe_count`` (one evaluation covers every
+    lane of the shard).
     """
     vals = obj_lanes(x)                                  # (k,)
     grads = jax.grad(lambda z: obj_lanes(z).sum())(x)    # (k, n, C) separable
@@ -488,9 +505,9 @@ def backtracking_step_lanes(obj_lanes, x, theta0, admm: ADMMConfig):
 
     theta0 = jnp.maximum(theta0 / admm.backtrack_growth, 1e-8)
     done0 = accepted(theta0)
-    theta, _, _ = jax.lax.while_loop(cond, body,
-                                     (theta0, done0, jnp.asarray(0)))
-    return x - grads / theta[:, None, None], theta
+    theta, _, iters = jax.lax.while_loop(cond, body,
+                                         (theta0, done0, jnp.asarray(0)))
+    return x - grads / theta[:, None, None], theta, probe_count(iters, admm)
 
 
 def fista_lanes(admm: ADMMConfig, b, u, labels, mask, z_init, denom):
@@ -498,7 +515,8 @@ def fista_lanes(admm: ADMMConfig, b, u, labels, mask, z_init, denom):
 
     All arrays carry a leading lane dim k; each lane runs its own Lipschitz
     backtracking (lane-masked), so communities on the same device still
-    solve their subproblems exactly as independent agents would.
+    solve their subproblems exactly as independent agents would.  Returns
+    Z and the ``probe_count`` of its ``fista_iters`` searches, summed.
     """
 
     def obj_lanes(z):                                    # (k,) values
@@ -534,18 +552,19 @@ def fista_lanes(admm: ADMMConfig, b, u, labels, mask, z_init, denom):
             done = done | accepted(lip)
             return lip, done, it + 1
 
-        lip, _, _ = jax.lax.while_loop(
+        lip, _, iters = jax.lax.while_loop(
             cond, body, (lip, accepted(lip), jnp.asarray(0)))
         z_new = y - g / lip[:, None, None]
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         y_new = z_new + ((t - 1.0) / t_new) * (z_new - z)
-        return (z_new, y_new, t_new, lip * 0.9), None
+        return (z_new, y_new, t_new, lip * 0.9), probe_count(iters, admm)
 
     k = z_init.shape[0]
     init = (z_init, z_init, jnp.asarray(1.0),
             jnp.full((k,), admm.rho + 1.0))
-    (z, _, _, _), _ = jax.lax.scan(step, init, None, length=admm.fista_iters)
-    return z
+    (z, _, _, _), probes = jax.lax.scan(step, init, None,
+                                        length=admm.fista_iters)
+    return z, probes.sum(axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -559,8 +578,17 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
                     packed_aux: "dict | None",
                     mb_aux: "dict | None",
                     adj, nbr_row, z0_loc, labels_loc, mask_loc, denom,
-                    ws, zs_loc, u_loc, taus, thetas, nbr_decay=None):
-    """Shapes per shard: nbr_row (k,M); z*_loc (k,n,C); thetas[l] (k,).
+                    ws, zs_loc, u_loc, taus, thetas, probes,
+                    nbr_decay=None):
+    """Shapes per shard: nbr_row (k,M); z*_loc (k,n,C); thetas[l] (k,);
+    probes (1, 2), to which the round adds the ``probe_count`` of every
+    line search it ran.
+
+    The four sub-updates run under ``jax.named_scope`` ``admm_w`` (Line 3
+    with its line search), ``admm_z`` (eq. 5/6), ``admm_fista`` (eq. 7)
+    and ``admm_dual`` (eq. 3), so each HLO op they lower to carries the
+    scope in its ``op_name`` metadata, and device traces attribute op time
+    to the sub-update.
 
     ``adj`` is the shard's adjacency rows — dense mode: a_row (k,M,n,n);
     compressed mode: (ell_rows (k,max_deg,n,n), ell_idx (k,max_deg),
@@ -805,129 +833,142 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
     zh_in = [zh0] + zh[:-1]                     # layer inputs
 
     # ---- Line 3: W update (layer-parallel, Jacobi over Z^k) ----
-    new_ws, new_taus = [], []
-    for l in range(num_layers):
-        agg = rowagg(zh_in[l])                  # (k, n, C_{l-1})
+    new_ws, new_taus, counts = [], [], []
+    with jax.named_scope("admm_w"):
+        for l in range(num_layers):
+            agg = rowagg(zh_in[l])                  # (k, n, C_{l-1})
 
-        # minibatch: unsampled lanes' constraints leave the (psum-ed)
-        # W objective entirely — their residuals mask to exact zeros
-        if l < num_layers - 1:
-            def local_obj(w, agg=agg, z=zs_loc[l]):
-                r = z - f(agg @ w)
-                if sm is not None:
-                    r = r * sm
-                return 0.5 * admm.nu * jnp.vdot(r, r).real
-        else:
-            def local_obj(w, agg=agg, z=zs_loc[l]):
-                r = z - agg @ w
-                if sm is not None:
-                    r = r * sm
-                return jnp.vdot(u_loc, r).real + \
-                    0.5 * admm.rho * jnp.vdot(r, r).real
-        w_new, tau = backtracking_step_psum(local_obj, ws[l], taus[l], admm)
-        new_ws.append(w_new)
-        new_taus.append(tau)
+            # minibatch: unsampled lanes' constraints leave the (psum-ed)
+            # W objective entirely — their residuals mask to exact zeros
+            if l < num_layers - 1:
+                def local_obj(w, agg=agg, z=zs_loc[l]):
+                    r = z - f(agg @ w)
+                    if sm is not None:
+                        r = r * sm
+                    return 0.5 * admm.nu * jnp.vdot(r, r).real
+            else:
+                def local_obj(w, agg=agg, z=zs_loc[l]):
+                    r = z - agg @ w
+                    if sm is not None:
+                        r = r * sm
+                    return jnp.vdot(u_loc, r).real + \
+                        0.5 * admm.rho * jnp.vdot(r, r).real
+            w_new, tau, n = backtracking_step_psum(local_obj, ws[l], taus[l],
+                                                   admm)
+            new_ws.append(w_new)
+            new_taus.append(tau)
+            counts.append(n)
 
     # ---- Line 4: Z update (community-parallel, reads W^{k+1}, Z^k) ----
     new_zs, new_thetas = [], []
-    for l in range(1, num_layers):              # hidden layers (eq. 5/6)
-        w_l, w_next = new_ws[l - 1], new_ws[l]
-        target1 = f(rowagg_mm(zh_in[l - 1], w_l))            # (k, n, C_l)
-        # relay aggregates q_{l,r} (eq. 4 second-order payload), all r
-        q_loc = rowagg_mm(zh[l - 1], w_next)                 # (k, n, C_next)
-        q_all = gather(q_loc)[1]                             # blocked rows
-        z_ref = zs_loc[l - 1]
+    with jax.named_scope("admm_z"):
+        for l in range(1, num_layers):              # hidden layers (eq. 5/6)
+            w_l, w_next = new_ws[l - 1], new_ws[l]
+            target1 = f(rowagg_mm(zh_in[l - 1], w_l))        # (k, n, C_l)
+            # relay aggregates q_{l,r} (eq. 4 second-order payload), all r
+            q_loc = rowagg_mm(zh[l - 1], w_next)             # (k, n, C_next)
+            q_all = gather(q_loc)[1]                         # blocked rows
+            z_ref = zs_loc[l - 1]
 
-        # Coupling term of ψ (paper eq. 5/6): every neighbour community r's
-        # next-layer pre-activation as a function of my lanes,
-        #   pre[j, r] = q_r + Ã_{r,m_j} (z_j − z_ref_j) W.
-        # Lane m's ψ only sums r ∈ N_m ∪ {m} — the r ∉ N_m residuals are
-        # constants in z (zero gradient) and drop from the objective.
-        if compressed:
-            # neighbour-compressed form: enumerate the max_deg stored
-            # neighbours only.  Ã_{r,m} = Ã_{m,r}ᵀ (Ã symmetric), so the
-            # stored row blocks are consumed transposed ("kdnp,knc->kdpc")
-            # — the gather-transpose trick of second_order_from_relay.
-            # O(max_deg·n_pad²·C) per lane instead of the dense O(M·…).
-            def pre_nbr(z, q_all=q_all, z_ref=z_ref, w_next=w_next):
-                delta = (z - z_ref) @ w_next                 # (k, n, C)
-                own = jnp.einsum("kdnp,knc->kdpc",
-                                 ell_rows.astype(jnp.float32), delta)
-                return q_all[ell_idx] + own                  # (k, D, n, C)
+            # Coupling term of ψ (paper eq. 5/6): every neighbour community
+            # r's next-layer pre-activation as a function of my lanes,
+            #   pre[j, r] = q_r + Ã_{r,m_j} (z_j − z_ref_j) W.
+            # Lane m's ψ only sums r ∈ N_m ∪ {m} — the r ∉ N_m residuals
+            # are constants in z (zero gradient) and drop from the
+            # objective.
+            if compressed:
+                # neighbour-compressed form: enumerate the max_deg stored
+                # neighbours only.  Ã_{r,m} = Ã_{m,r}ᵀ (Ã symmetric), so
+                # the stored row blocks are consumed transposed
+                # ("kdnp,knc->kdpc") — the gather-transpose trick of
+                # second_order_from_relay.  O(max_deg·n_pad²·C) per lane
+                # instead of the dense O(M·…).
+                def pre_nbr(z, q_all=q_all, z_ref=z_ref, w_next=w_next):
+                    delta = (z - z_ref) @ w_next             # (k, n, C)
+                    own = jnp.einsum("kdnp,knc->kdpc",
+                                     ell_rows.astype(jnp.float32), delta)
+                    return q_all[ell_idx] + own              # (k, D, n, C)
 
-            # staleness damping: √d_r folded into the coupling weight, so
-            # every squared residual carries the full d_r (exact identity
-            # when all ages are 0: ell_f · 1.0 is bitwise ell_f)
-            wt = (ell_f * sdr if sdr is not None
-                  else ell_f)[..., None, None]               # (k, D, 1, 1)
+                # staleness damping: √d_r folded into the coupling weight,
+                # so every squared residual carries the full d_r (exact
+                # identity when all ages are 0: ell_f · 1.0 is bitwise
+                # ell_f)
+                wt = (ell_f * sdr if sdr is not None
+                      else ell_f)[..., None, None]           # (k, D, 1, 1)
 
-            def nbr_vals(x_all):
-                """(M, n, C) gathered payload -> this lane's (k, D, n, C)."""
-                return x_all[ell_idx]
-        else:
-            def pre_nbr(z, q_all=q_all, z_ref=z_ref, w_next=w_next):
-                delta = (z - z_ref) @ w_next                 # (k, n, C)
-                return q_all[None] + jnp.einsum("kmnp,knc->kmpc",
-                                                a_row, delta)
+                def nbr_vals(x_all):
+                    """(M, n, C) gathered payload -> this lane's
+                    (k, D, n, C)."""
+                    return x_all[ell_idx]
+            else:
+                def pre_nbr(z, q_all=q_all, z_ref=z_ref, w_next=w_next):
+                    delta = (z - z_ref) @ w_next             # (k, n, C)
+                    return q_all[None] + jnp.einsum("kmnp,knc->kmpc",
+                                                    a_row, delta)
 
-            wt = nbrf[:, :, None, None]                      # (k, M, 1, 1)
+                wt = nbrf[:, :, None, None]                  # (k, M, 1, 1)
 
-            def nbr_vals(x_all):
-                return x_all[None]                           # (1, M, n, C)
+                def nbr_vals(x_all):
+                    return x_all[None]                       # (1, M, n, C)
 
-        if l + 1 < num_layers:
-            zh_next = zh[l][1]
+            if l + 1 < num_layers:
+                zh_next = zh[l][1]
 
-            def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
-                          zh_next=zh_next):
-                r1 = z - target1
-                v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
-                r2 = (nbr_vals(zh_next) - f(pre_nbr(z))) * wt
-                v2 = 0.5 * admm.nu * jnp.sum(r2 * r2, axis=(1, 2, 3))
-                return v1 + v2
-        else:
-            zh_last, uh = zh[l][1], gather(u_loc)[1]
+                def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
+                              zh_next=zh_next):
+                    r1 = z - target1
+                    v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
+                    r2 = (nbr_vals(zh_next) - f(pre_nbr(z))) * wt
+                    v2 = 0.5 * admm.nu * jnp.sum(r2 * r2, axis=(1, 2, 3))
+                    return v1 + v2
+            else:
+                zh_last, uh = zh[l][1], gather(u_loc)[1]
 
-            def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
-                          zh_last=zh_last, uh=uh):
-                r1 = z - target1
-                v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
-                r2 = (nbr_vals(zh_last) - pre_nbr(z)) * wt
-                uv = nbr_vals(uh)
-                if sdr is not None:
-                    # second √d_r: r2 carries one, so the dual term
-                    # ⟨U_r, ·⟩ scales by the full staleness weight d_r
-                    uv = uv * sdr[..., None, None]
-                lin = jnp.sum(uv * r2, axis=(1, 2, 3))
-                quad = 0.5 * admm.rho * jnp.sum(r2 * r2, axis=(1, 2, 3))
-                return v1 + lin + quad
+                def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
+                              zh_last=zh_last, uh=uh):
+                    r1 = z - target1
+                    v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
+                    r2 = (nbr_vals(zh_last) - pre_nbr(z)) * wt
+                    uv = nbr_vals(uh)
+                    if sdr is not None:
+                        # second √d_r: r2 carries one, so the dual term
+                        # ⟨U_r, ·⟩ scales by the full staleness weight d_r
+                        uv = uv * sdr[..., None, None]
+                    lin = jnp.sum(uv * r2, axis=(1, 2, 3))
+                    quad = 0.5 * admm.rho * jnp.sum(r2 * r2, axis=(1, 2, 3))
+                    return v1 + lin + quad
 
-        z_new, theta = backtracking_step_lanes(
-            obj_lanes, zs_loc[l - 1], thetas[l - 1], admm)
-        if smask_b is not None:
-            # unsampled lanes keep their iterates bit-for-bit (exact
-            # block-coordinate step on the sampled blocks)
-            z_new = jnp.where(smask_b[:, None, None], z_new, zs_loc[l - 1])
-            theta = jnp.where(smask_b, theta, thetas[l - 1])
-        new_zs.append(z_new)
-        new_thetas.append(theta)
+            z_new, theta, n = backtracking_step_lanes(
+                obj_lanes, zs_loc[l - 1], thetas[l - 1], admm)
+            counts.append(n)
+            if smask_b is not None:
+                # unsampled lanes keep their iterates bit-for-bit (exact
+                # block-coordinate step on the sampled blocks)
+                z_new = jnp.where(smask_b[:, None, None], z_new,
+                                  zs_loc[l - 1])
+                theta = jnp.where(smask_b, theta, thetas[l - 1])
+            new_zs.append(z_new)
+            new_thetas.append(theta)
 
     # ---- Z_L: per-community FISTA prox (eq. 7) ----
-    b = rowagg_mm(zh_in[num_layers - 1], new_ws[-1])
-    z_last = fista_lanes(admm, b, u_loc, labels_loc, mask_loc,
-                         zs_loc[-1], denom)
-    if smask_b is not None:
-        z_last = jnp.where(smask_b[:, None, None], z_last, zs_loc[-1])
-    new_zs.append(z_last)
-    new_thetas.append(thetas[-1])
+    with jax.named_scope("admm_fista"):
+        b = rowagg_mm(zh_in[num_layers - 1], new_ws[-1])
+        z_last, n = fista_lanes(admm, b, u_loc, labels_loc, mask_loc,
+                                zs_loc[-1], denom)
+        counts.append(n)
+        if smask_b is not None:
+            z_last = jnp.where(smask_b[:, None, None], z_last, zs_loc[-1])
+        new_zs.append(z_last)
+        new_thetas.append(thetas[-1])
 
     # ---- Line 5: dual ascent (eq. 3) with updated iterates ----
-    zh_pen_new = gather(new_zs[num_layers - 2]) if num_layers >= 2 \
-        else zh0
-    b_new = rowagg_mm(zh_pen_new, new_ws[-1])
-    new_u = u_loc + admm.rho * (new_zs[-1] - b_new)
-    if smask_b is not None:
-        new_u = jnp.where(smask_b[:, None, None], new_u, u_loc)
+    with jax.named_scope("admm_dual"):
+        zh_pen_new = gather(new_zs[num_layers - 2]) if num_layers >= 2 \
+            else zh0
+        b_new = rowagg_mm(zh_pen_new, new_ws[-1])
+        new_u = u_loc + admm.rho * (new_zs[-1] - b_new)
+        if smask_b is not None:
+            new_u = jnp.where(smask_b[:, None, None], new_u, u_loc)
 
     if packed_aux is not None:
         # carry state between steps in the packed plane — the blocked
@@ -936,7 +977,7 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         new_u = to_plane(new_u)
 
     return (tuple(new_ws), tuple(new_zs), new_u,
-            tuple(new_taus), tuple(new_thetas))
+            tuple(new_taus), tuple(new_thetas), probes + sum(counts)[None])
 
 
 # ---------------------------------------------------------------------------
@@ -991,61 +1032,70 @@ class ParallelADMMTrainer:
             # honestly labelled (no re-partition just for the tag)
             partitioner = partitioner or "precomputed"
         self.partitioner = partitioner
-        self.partition_stats = graph.partition_quality(
-            g.num_nodes, g.edges, part, num_parts)
-        self.layout = graph.build_community_layout(g.num_nodes, g.edges, part,
-                                                   compressed=compressed,
-                                                   pad_mode=pad_mode)
-        m = int(np.asarray(self.layout.neighbor_mask).shape[0])
+        # the host spans ``construct.layout`` and ``construct.init_state``
+        # split set-up between the blocked layout with its device data and
+        # the initial state
+        with spans.span("construct.layout"):
+            self.partition_stats = graph.partition_quality(
+                g.num_nodes, g.edges, part, num_parts)
+            self.layout = graph.build_community_layout(
+                g.num_nodes, g.edges, part, compressed=compressed,
+                pad_mode=pad_mode)
+            m = int(np.asarray(self.layout.neighbor_mask).shape[0])
 
-        if mesh is None:
-            n_dev = len(jax.devices())
-            n_shards = max(d for d in range(1, n_dev + 1) if m % d == 0)
-            mesh = jax.make_mesh((n_shards,), (AXIS,), (AxisType.Auto,),
-                                 devices=jax.devices()[:n_shards])
-        self.mesh = mesh
-        n_shards = mesh.shape[AXIS]
+            if mesh is None:
+                n_dev = len(jax.devices())
+                n_shards = max(d for d in range(1, n_dev + 1) if m % d == 0)
+                mesh = jax.make_mesh((n_shards,), (AXIS,), (AxisType.Auto,),
+                                     devices=jax.devices()[:n_shards])
+            self.mesh = mesh
+            n_shards = mesh.shape[AXIS]
 
-        # packed state: each shard's Z/U/z0/label rows live back to back at
-        # their bucket row counts on a flat plane — resident bytes track
-        # true community size, not M·n_pad (docs/layout.md)
-        self.packed_layout = self.layout.device_layout(n_shards) \
-            if packed else None
-        # every data array starts where the step reads it: lane-major rows
-        # split over the comm axis, the scalar denominator replicated
-        data = community_data(g, self.layout, compressed=compressed,
-                              adjacency_bf16=adjacency_bf16,
-                              device_layout=self.packed_layout)
-        self.data = dataclasses.replace(data, **{
-            f.name: place_on_mesh(mesh, getattr(data, f.name),
-                                  P() if f.name == "denom" else P(AXIS))
-            for f in dataclasses.fields(data)
-            if f.name != "packed_layout" and getattr(data, f.name) is not None})
+            # packed state: each shard's Z/U/z0/label rows live back to
+            # back at their bucket row counts on a flat plane — resident
+            # bytes track true community size, not M·n_pad
+            # (docs/layout.md)
+            self.packed_layout = self.layout.device_layout(n_shards) \
+                if packed else None
+            # every data array starts where the step reads it: lane-major
+            # rows split over the comm axis, the scalar denominator
+            # replicated
+            data = community_data(g, self.layout, compressed=compressed,
+                                  adjacency_bf16=adjacency_bf16,
+                                  device_layout=self.packed_layout)
+            self.data = dataclasses.replace(data, **{
+                f.name: place_on_mesh(mesh, getattr(data, f.name),
+                                      P() if f.name == "denom" else P(AXIS))
+                for f in dataclasses.fields(data)
+                if f.name != "packed_layout"
+                and getattr(data, f.name) is not None})
 
-        # init from the same forward pass as the serial trainer
-        ws = gcn.init_weights(cfg, jax.random.key(seed))
-        a_full = graph.normalized_adjacency(g.num_nodes, g.edges)
-        zs_full = gcn.forward(cfg, jnp.asarray(a_full),
-                              jnp.asarray(g.features), ws)
-        if packed:
-            dl = self.packed_layout
-            zs = tuple(dl.pack_state(self.layout.pack(np.asarray(z)))
-                       for z in zs_full)
-        else:
-            zs = tuple(self.layout.pack(np.asarray(z)) for z in zs_full)
-        del zs_full
-        u = np.zeros_like(zs[-1])
-        taus = tuple(jnp.asarray(admm.tau_init) for _ in ws)
-        thetas = tuple(jnp.full((m,), admm.tau_init) for _ in zs)
-        sharded, rep = P(AXIS), P()
-        n_l = cfg.num_layers
-        self.state_spec = ParallelState((rep,) * n_l, (sharded,) * n_l,
-                                         sharded, (rep,) * n_l,
-                                         (sharded,) * n_l)
-        # the state starts where the step's shard_map reads it, spread
-        # over the comm axis — not all on the default device
-        self.state = place_on_mesh(mesh, ParallelState(
-            tuple(ws), zs, u, taus, thetas), self.state_spec)
+        with spans.span("construct.init_state"):
+            # init from the same forward pass as the serial trainer
+            ws = gcn.init_weights(cfg, jax.random.key(seed))
+            a_full = graph.normalized_adjacency(g.num_nodes, g.edges)
+            zs_full = gcn.forward(cfg, jnp.asarray(a_full),
+                                  jnp.asarray(g.features), ws)
+            if packed:
+                dl = self.packed_layout
+                zs = tuple(dl.pack_state(self.layout.pack(np.asarray(z)))
+                           for z in zs_full)
+            else:
+                zs = tuple(self.layout.pack(np.asarray(z)) for z in zs_full)
+            del zs_full
+            u = np.zeros_like(zs[-1])
+            taus = tuple(jnp.asarray(admm.tau_init) for _ in ws)
+            thetas = tuple(jnp.full((m,), admm.tau_init) for _ in zs)
+            probes = np.zeros((n_shards, 2), np.int32)
+            sharded, rep = P(AXIS), P()
+            n_l = cfg.num_layers
+            self.state_spec = ParallelState((rep,) * n_l, (sharded,) * n_l,
+                                             sharded, (rep,) * n_l,
+                                             (sharded,) * n_l, sharded)
+            # the state starts where the step's shard_map reads it, spread
+            # over the comm axis — not all on the default device
+            self.state = place_on_mesh(mesh, ParallelState(
+                tuple(ws), zs, u, taus, thetas, probes), self.state_spec)
 
         self._plan = None
         ell_idx_dev = self.data.ell_indices
@@ -1498,18 +1548,27 @@ class ParallelADMMTrainer:
     def train(self, epochs: int, verbose: bool = False) -> "TrainLog":
         from repro.core.serial import TrainLog
         log = TrainLog()
+        # host spans: ``train.step`` and ``train.eval`` dispatch the
+        # programs, ``train.wait`` waits for the step (``epoch_time_s``),
+        # ``train.sync`` reads the metrics back to the host
         for epoch in range(epochs):
             t0 = time.perf_counter()
-            self.step()
-            jax.block_until_ready(self.state.zs[-1])
+            with spans.span("train.step"):
+                self.step()
+            with spans.span("train.wait"):
+                jax.block_until_ready(self.state.zs[-1])
             dt = time.perf_counter() - t0
-            tr, te, res = self._metrics(self.state)
-            lag = self._lagrangian(self.state)
+            with spans.span("train.eval"):
+                tr, te, res = self._metrics(self.state)
+                lag = self._lagrangian(self.state)
+            with spans.span("train.sync"):
+                tr, te, lag, res = float(tr), float(te), float(lag), \
+                    float(res)
             log.epoch.append(epoch)
-            log.train_acc.append(float(tr))
-            log.test_acc.append(float(te))
-            log.lagrangian.append(float(lag))
-            log.residual.append(float(res))
+            log.train_acc.append(tr)
+            log.test_acc.append(te)
+            log.lagrangian.append(lag)
+            log.residual.append(res)
             log.epoch_time_s.append(dt)
             if verbose:
                 print(f"[parallel-admm] epoch {epoch:3d} train {tr:.3f} "

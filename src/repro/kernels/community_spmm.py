@@ -365,6 +365,7 @@ def community_spmm(a_row: jax.Array, z_all: jax.Array, mask: jax.Array,
         out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_all.dtype),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=spec.name,
     )(mask.astype(jnp.int32), a_row, z_all)
 
 
@@ -499,6 +500,7 @@ def community_spmm_ell(ell_blocks: jax.Array, ell_indices: jax.Array,
         out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_all.dtype),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=spec.name,
     )(ell_indices.astype(jnp.int32), ell_mask.astype(jnp.int32),
       row_counts.astype(jnp.int32), nbr_counts.astype(jnp.int32),
       ell_blocks, z_all)
@@ -531,6 +533,7 @@ def _packed_call(kernel, spec: KernelSpec, ell_blocks, ell_offsets,
         out_shape=jax.ShapeDtypeStruct(out_op.array_shape, z_plane.dtype),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=spec.name,
     )(off8.astype(jnp.int32), ell_mask.astype(jnp.int32),
       row_counts.astype(jnp.int32), nbr_counts.astype(jnp.int32),
       ell_blocks, z_pad, *extra)
