@@ -17,17 +17,6 @@ from repro.analysis.findings import Report, Waiver
 from repro.analysis.registry import AnalysisContext, run_rules
 
 
-def _gathered_cs(cfg: Any) -> list[int]:
-    """The per-iteration gather payload widths (the same convention as
-    the trainer's ``comm_stats``): Z_0 once, Z_1..Z_L, q per hidden
-    layer, then U and the penultimate-Z refresh for L >= 2."""
-    dims = list(cfg.layer_dims)
-    cs = [dims[0]] + dims[1:]
-    if cfg.num_layers >= 2:
-        cs += dims[2:] + [dims[-1], dims[-2]]
-    return cs
-
-
 def _kernel_entries(tr: Any, n_shards: int) -> list[dict]:
     """One ELL-kernel spec per shard, with that shard's scalar operands
     (localized indices under multi-shard p2p, global ids otherwise)."""
@@ -87,12 +76,12 @@ def _kernel_entries(tr: Any, n_shards: int) -> list[dict]:
 def trainer_expectations(tr: Any) -> dict[str, Any]:
     """Expectations dict for the built-in rules, from the trainer's
     host-side plan and layout (see ``AnalysisContext`` for the keys)."""
-    from repro.core.parallel import AXIS
+    from repro.core.parallel import AXIS, gathered_widths
 
     n_shards = tr.mesh.shape[AXIS]
     m = tr.data.num_parts
     n_pad = tr.layout.n_pad
-    cs = _gathered_cs(tr.cfg)
+    cs = gathered_widths(tr.cfg.layer_dims)
     max_c = max(tr.cfg.layer_dims)
     if tr.data.ell_mask is not None:
         max_deg = int(tr.data.ell_mask.shape[1])

@@ -276,6 +276,43 @@ def community_data(g: graph.Graph, layout: graph.CommunityLayout,
 
 
 # ---------------------------------------------------------------------------
+# aggregation order: Ã·Z·W at the narrow side
+# ---------------------------------------------------------------------------
+
+def gathered_widths(layer_dims) -> list[int]:
+    """Payload width of each gather in one ADMM iteration: Z_0 (once),
+    Z_1..Z_L, then for L >= 2 the relay q per hidden layer, U, and the
+    dual's refresh Z_{L-1}⁺.  A 1-layer net has no hidden Z loop (no q, no
+    U gather) and its dual refresh reuses the already-gathered Z_0."""
+    dims = list(layer_dims)
+    cs = [dims[0]] + dims[1:]
+    if len(dims) > 2:
+        cs += dims[2:] + [dims[-1], dims[-2]]
+    return cs
+
+
+def narrow_first_agg(agg, widths: list):
+    """``agg_mm(z, w)`` = Ã·z·w through ``agg(z)`` = Ã·z, aggregated at the
+    narrower side: Ã(z w) when ``w`` narrows (C_out < C_in), else (Ã z) w.
+    A repeated (z, w) pair reuses its first result.  ``widths`` is
+    cleared, then gets the width of each aggregation as it is traced."""
+    widths.clear()
+    done = []
+
+    def agg_mm(z, w):
+        for z_seen, w_seen, out in done:
+            if z_seen is z and w_seen is w:
+                return out
+        narrow = w.shape[1] < w.shape[0]
+        x = z @ w if narrow else z
+        widths.append(int(x.shape[-1]))
+        out = agg(x) if narrow else agg(x) @ w
+        done.append((z, w, out))
+        return out
+    return agg_mm
+
+
+# ---------------------------------------------------------------------------
 # trainer configuration
 # ---------------------------------------------------------------------------
 
@@ -577,12 +614,18 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
                     overlap: bool, fused: bool,
                     packed_aux: "dict | None",
                     mb_aux: "dict | None",
+                    agg_widths: list,
                     adj, nbr_row, z0_loc, labels_loc, mask_loc, denom,
                     ws, zs_loc, u_loc, taus, thetas, probes,
                     nbr_decay=None):
     """Shapes per shard: nbr_row (k,M); z*_loc (k,n,C); thetas[l] (k,);
     probes (1, 2), to which the round adds the ``probe_count`` of every
     line search it ran.
+
+    ``agg_widths`` is cleared, then gets the width of each distinct
+    gathered operand the round aggregates, as the body is traced: the W,
+    Z and FISTA sites aggregate each Z_l once between them (XLA merges
+    the repeats into one call), and the dual aggregates its refresh.
 
     The four sub-updates run under ``jax.named_scope`` ``admm_w`` (Line 3
     with its line search), ``admm_z`` (eq. 5/6), ``admm_fista`` (eq. 7)
@@ -781,6 +824,19 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         def rowagg_mm(x, w):
             return rowagg(x) @ w
 
+    agg_widths.clear()
+    agg_seen = []
+
+    def tallied(agg):
+        def run(x, *w):
+            if not any(x is s for s in agg_seen):
+                agg_seen.append(x)
+                agg_widths.append(int(x[1].shape[-1]))
+            return agg(x, *w)
+        return run
+
+    rowagg, rowagg_mm = tallied(rowagg), tallied(rowagg_mm)
+
     if packed_wire:
         ru_tbl = jnp.asarray(packed_aux["recv_unpack"])[sid0]  # (r_pad·n,)
 
@@ -963,6 +1019,10 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
 
     # ---- Line 5: dual ascent (eq. 3) with updated iterates ----
     with jax.named_scope("admm_dual"):
+        # wide-first, (Ã Z⁺) W⁺: the association the next round's W_L line
+        # search forms its residual with.  Ã(Z⁺ W⁺) differs from it at
+        # float32 rounding, and on a v5e at the paper's widths that
+        # stalled the search (τ_L doubled to its cap) on every seed tried
         zh_pen_new = gather(new_zs[num_layers - 2]) if num_layers >= 2 \
             else zh0
         b_new = rowagg_mm(zh_pen_new, new_ws[-1])
@@ -1213,7 +1273,7 @@ class ParallelADMMTrainer:
                 step_aux = dict(packed_aux, groups=grp)
             body = partial(_iteration_body, cfg, admm, use_kernel,
                            comm_bf16, compressed, step_plan, overlap_on,
-                           fused, step_aux, mb_aux)
+                           fused, step_aux, mb_aux, agg_widths["step"])
             in_specs = step_spec + tuple(self.state_spec)
             out_specs = tuple(self.state_spec)
             if mb_aux is not None:
@@ -1234,6 +1294,9 @@ class ParallelADMMTrainer:
                     return ParallelState(*mapped(*dev, *state, nbr_decay))
             return BoundProgram(step, step_data), step_plan
 
+        # the width of every distinct aggregation each program lowers,
+        # filled as the program is traced (``comm_stats["aggregations"]``)
+        agg_widths = {"step": [], "metrics": [], "lagrangian": []}
         self._make_step = make_step
         self._sampler = None
         self._round = 0
@@ -1259,21 +1322,15 @@ class ParallelADMMTrainer:
             self._step, plan0 = self._mb_steps[batch0]
             self._active_plan = plan0 if plan0 is not None else self._plan
 
-        # collective volume per iteration: the gathers the body issues are
-        # one (M, n_pad, C) payload each for Z_0 (gathered exactly once per
-        # step — it is static input), Z_1..Z_L, the relay aggregates q
-        # (hidden layers), U, and the refreshed penultimate Z.  A 1-layer
-        # net has no hidden Z loop (no q, no U gather) and its dual refresh
-        # reuses the already-gathered Z_0.
+        # collective volume per iteration: one (M, n_pad, C) payload per
+        # gather of the body (``gathered_widths``)
         dims = list(cfg.layer_dims)
-        gathered_cs = [dims[0]] + dims[1:]                # Z_0 (once), Z_1..Z_L
-        if cfg.num_layers >= 2:
-            gathered_cs += (dims[2:]                      # q per hidden layer
-                            + [dims[-1], dims[-2]])       # U, Z_{L-1} refresh
+        gathered_cs = gathered_widths(dims)
         self.comm_stats = messages.gather_bytes(
             self.layout.neighbor_mask, self.layout.n_pad, gathered_cs,
             itemsize=2 if comm_bf16 else 4)
         self.comm_stats["transport"] = self.transport
+        self.comm_stats["aggregations"] = agg_widths
         # residual-padding accounting: how many payload rows / aggregation
         # FLOPs this trainer spends beyond the true community sizes.  The
         # bucketed row_counts only shrink what a consumer actually
@@ -1399,7 +1456,8 @@ class ParallelADMMTrainer:
         # mesh (a Pallas kernel cannot be auto-partitioned across chips):
         # each shard aggregates its own lanes against the replicated
         # blocked Z.  ``use_kernel`` picks the kernel or the einsum, as in
-        # the step body.
+        # the step body.  Each Ã·Z·W of the two programs aggregates at the
+        # narrower side (``narrow_first_agg``).
         data = self.data
         if compressed:
             from repro.kernels import ops as kops
@@ -1452,15 +1510,17 @@ class ParallelADMMTrainer:
         @jax.jit
         def metrics(dev, state: ParallelState):
             z0_blk, labels_blk, train_blk, test_blk, row_mask = blocked(dev)
+            agg_mm = narrow_first_agg(partial(agg_full, dev["adj"]),
+                                      agg_widths["metrics"])
             # community-blocked forward pass — logits (M, n_pad, C_L)
             logits = z0_blk
             for l, w in enumerate(state.weights):
-                logits = agg_full(dev["adj"], logits) @ w
+                logits = agg_mm(logits, w)
                 if l < cfg.num_layers - 1:
                     logits = f_act(logits)
             z_pen = unfold(state.zs[-2]) if cfg.num_layers >= 2 else z0_blk
-            res = (unfold(state.zs[-1]) - agg_full(dev["adj"], z_pen)
-                   @ state.weights[-1]) * row_mask
+            res = (unfold(state.zs[-1]) - agg_mm(z_pen, state.weights[-1])) \
+                * row_mask
             return (gcn.accuracy(logits, labels_blk, train_blk),
                     gcn.accuracy(logits, labels_blk, test_blk),
                     jnp.linalg.norm(res))
@@ -1484,13 +1544,14 @@ class ParallelADMMTrainer:
             nll = -jnp.take_along_axis(logp, labels_blk[..., None],
                                        axis=-1)[..., 0]
             val = jnp.sum(nll * train_blk) / dev["denom"]
+            agg_mm = narrow_first_agg(partial(agg_full, dev["adj"]),
+                                      agg_widths["lagrangian"])
             z_prev = z0_blk
             for l in range(cfg.num_layers - 1):
-                r = (zs[l] - f_act(agg_full(dev["adj"], z_prev) @ ws[l])) \
-                    * row_mask
+                r = (zs[l] - f_act(agg_mm(z_prev, ws[l]))) * row_mask
                 val += 0.5 * admm.nu * jnp.vdot(r, r).real
                 z_prev = zs[l]
-            r = (zs[-1] - agg_full(dev["adj"], z_prev) @ ws[-1]) * row_mask
+            r = (zs[-1] - agg_mm(z_prev, ws[-1])) * row_mask
             val += jnp.vdot(u * row_mask, r).real \
                 + 0.5 * admm.rho * jnp.vdot(r, r).real
             return val

@@ -397,9 +397,9 @@ def test_minibatch_on_4_shards():
 _OV_WORKER = r"""
 import numpy as np, jax
 import jax.numpy as jnp
-from repro.analysis.trainer import _gathered_cs
 from repro.core import gcn, graph, messages
-from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
+from repro.core.parallel import (AXIS, ParallelADMMTrainer, TrainerConfig,
+                                 gathered_widths)
 from repro.core.subproblems import ADMMConfig
 from jax.sharding import AxisType
 
@@ -443,7 +443,7 @@ sub = ov._active_plan
 sub_pairs = {p for r in sub.rounds for p in r.pairs}
 full_pairs = {p for r in ov._plan.rounds for p in r.pairs}
 assert sub_pairs < full_pairs          # a strict sub-schedule is active
-eb = messages.exchange_bytes(sub, _gathered_cs(ov.cfg))
+eb = messages.exchange_bytes(sub, gathered_widths(ov.cfg.layer_dims))
 assert st["total_wire_bytes"] == eb["wire_bytes"], (st, eb)
 assert st["exposed_wire_bytes"] <= st["total_wire_bytes"]
 assert st["num_groups"] == sub.num_rounds + 1

@@ -8,8 +8,9 @@ nor the full array dim, and kernels whose VMEM footprint it cannot fit.
 
 Widths:
   * paper — Amazon Photo (7,650 nodes) in M=3 communities, n_pad 2552,
-    feature widths 745 and 1000, and the 745→1000 / 1000→8 GEMMs the
-    fused Z-update runs;
+    feature widths 745 and 1000, the 8 classes the metrics and
+    Lagrangian programs aggregate Ã(Z W) at, and the 745→1000 / 1000→8
+    GEMMs the fused Z-update runs;
   * m32 — the 32-community power-law test graphs, C 16–64.
 
 The topology is described inside a module fixture (never at import), so
@@ -70,7 +71,7 @@ def test_dense_block_kernel_compiles(one_chip, dims, c):
              ((m, n_pad, n_pad), f32), ((m, n_pad, c), f32), ((m,), i32))
 
 
-@pytest.mark.parametrize("dims,c", [(PAPER, 745), (PAPER, 1000),
+@pytest.mark.parametrize("dims,c", [(PAPER, 745), (PAPER, 1000), (PAPER, 8),
                                     (M32, 16), (M32, 32), (M32, 64)])
 @pytest.mark.parametrize("block_dtype", [f32, jnp.bfloat16])
 def test_ell_kernel_compiles(one_chip, dims, c, block_dtype):
@@ -81,7 +82,7 @@ def test_ell_kernel_compiles(one_chip, dims, c, block_dtype):
              ((k, d), i32))
 
 
-@pytest.mark.parametrize("dims,c", [(PAPER, 745), (PAPER, 1000),
+@pytest.mark.parametrize("dims,c", [(PAPER, 745), (PAPER, 1000), (PAPER, 8),
                                     (M32, 16), (M32, 32), (M32, 64)])
 def test_packed_kernel_compiles(one_chip, dims, c):
     k, d, n_pad, rows = dims
