@@ -161,7 +161,7 @@ def transfer_state(src, dst, state):
                          tuple(plane(z) for z in state.zs), plane(state.u),
                          tuple(np.asarray(t) for t in state.taus),
                          tuple(np.asarray(t) for t in state.thetas),
-                         np.zeros((dst.mesh.size, 2), np.int32))
+                         np.zeros(dst.state.probes.shape, np.int32))
     return place_on_mesh(dst.mesh, host, dst.state_spec)
 
 

@@ -168,6 +168,11 @@ def pad_stats(neighbor_mask: np.ndarray, sizes: np.ndarray,
 # neighbour-only point-to-point transport (ppermute round schedule)
 # ---------------------------------------------------------------------------
 
+# the ``jax.named_scope`` the exchanges' ppermute rounds run under, so a
+# device trace attributes their ops (the send gathers, the
+# collective-permutes and the receive scatters) to the exchange
+EXCHANGE_SCOPE = "admm_exchange"
+
 @dataclasses.dataclass(frozen=True)
 class ExchangeRound:
     """One ``lax.ppermute`` round of the neighbour exchange.
@@ -607,14 +612,15 @@ def exchange_neighbors(plan: NeighborExchange, x_loc: Array, axis: str,
     own = jnp.asarray(plan.own_slots)[sid]                    # (k,)
     own_flat = (own[:, None] * n + jnp.arange(n)[None, :]).reshape(-1)
     buf = buf.at[own_flat].set(x_flat)
-    for rnd in plan.rounds:
-        payload = x_flat[jnp.asarray(rnd.send_idx)[sid]]
-        permute = partial(jax.lax.ppermute, axis_name=axis,
-                          perm=list(rnd.pairs))
-        payload = bf16_wire(permute, payload) if comm_bf16 \
-            else permute(payload)
-        buf = buf.at[jnp.asarray(rnd.recv_slot)[sid]].set(payload,
-                                                          mode="drop")
+    with jax.named_scope(EXCHANGE_SCOPE):
+        for rnd in plan.rounds:
+            payload = x_flat[jnp.asarray(rnd.send_idx)[sid]]
+            permute = partial(jax.lax.ppermute, axis_name=axis,
+                              perm=list(rnd.pairs))
+            payload = bf16_wire(permute, payload) if comm_bf16 \
+                else permute(payload)
+            buf = buf.at[jnp.asarray(rnd.recv_slot)[sid]].set(payload,
+                                                              mode="drop")
     return buf.reshape((plan.r_pad, n) + feat)
 
 
@@ -647,15 +653,16 @@ def exchange_neighbors_packed(plan: NeighborExchange, x_plane: Array,
     own_tbl = jnp.asarray(plan.own_copy_rows)[sid]
     buf = jnp.take(x_plane, own_tbl, axis=0, mode="fill", fill_value=0)
     bufs = [buf]
-    for rnd in plan.rounds:
-        payload = x_plane[jnp.asarray(rnd.send_rows_packed)[sid]]
-        permute = partial(jax.lax.ppermute, axis_name=axis,
-                          perm=list(rnd.pairs))
-        payload = bf16_wire(permute, payload) if comm_bf16 \
-            else permute(payload)
-        buf = buf.at[jnp.asarray(rnd.recv_rows_packed)[sid]].set(
-            payload, mode="drop")
-        bufs.append(buf)
+    with jax.named_scope(EXCHANGE_SCOPE):
+        for rnd in plan.rounds:
+            payload = x_plane[jnp.asarray(rnd.send_rows_packed)[sid]]
+            permute = partial(jax.lax.ppermute, axis_name=axis,
+                              perm=list(rnd.pairs))
+            payload = bf16_wire(permute, payload) if comm_bf16 \
+                else permute(payload)
+            buf = buf.at[jnp.asarray(rnd.recv_rows_packed)[sid]].set(
+                payload, mode="drop")
+            bufs.append(buf)
     return bufs if staged else buf
 
 

@@ -155,9 +155,10 @@ class ParallelState(NamedTuple):
     u: Array                     # sharded
     taus: tuple[Array, ...]      # scalars, replicated
     thetas: tuple[Array, ...]    # (M,), sharded
-    # (n_shards, 2) int32, sharded: per shard, since construction, the
-    # line-search objective evaluations and the searches that ran all
-    # ``max_backtracks`` iterations (``probe_count``)
+    # (n_shards, len(probe_columns(L))) int32, sharded: per shard, since
+    # construction, the line-search objective evaluations and the searches
+    # that ran all ``max_backtracks`` iterations (``probe_count``), summed
+    # over every search and then for each search on its own
     probes: Array
 
 
@@ -473,6 +474,18 @@ _LEGACY_FLAGS = ("use_kernel", "comm_bf16", "compressed", "transport",
 # backtracking primitives
 # ---------------------------------------------------------------------------
 
+def probe_columns(num_layers: int) -> list[str]:
+    """Names of the columns of ``ParallelState.probes``: a round's
+    objective evaluations and capped searches summed over all its line
+    searches, then the same pair for each search in the order the round
+    runs them, W_1 … W_L, the hidden Z_1 … Z_{L-1}, and Z_L's FISTA (its
+    ``fista_iters`` searches summed)."""
+    searches = [f"w{l}" for l in range(1, num_layers + 1)] + \
+        [f"z{l}" for l in range(1, num_layers + 1)]
+    return ["evals", "capped"] + [f"{s}.{c}" for s in searches
+                                  for c in ("evals", "capped")]
+
+
 def probe_count(iters, admm: ADMMConfig):
     """``[evaluations, capped]`` (int32) of one line search whose ``while``
     ran ``iters`` iterations: the first test plus one objective evaluation
@@ -619,8 +632,8 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
                     ws, zs_loc, u_loc, taus, thetas, probes,
                     nbr_decay=None):
     """Shapes per shard: nbr_row (k,M); z*_loc (k,n,C); thetas[l] (k,);
-    probes (1, 2), to which the round adds the ``probe_count`` of every
-    line search it ran.
+    probes (1, len(probe_columns(L))), to which the round adds the
+    ``probe_count`` of every line search it ran, summed and per search.
 
     ``agg_widths`` is cleared, then gets the width of each distinct
     gathered operand the round aggregates, as the body is traced: the W,
@@ -631,7 +644,9 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
     with its line search), ``admm_z`` (eq. 5/6), ``admm_fista`` (eq. 7)
     and ``admm_dual`` (eq. 3), so each HLO op they lower to carries the
     scope in its ``op_name`` metadata, and device traces attribute op time
-    to the sub-update.
+    to the sub-update.  Inside ``admm_w`` each layer's update runs under
+    ``l1`` … ``lL``, inside ``admm_z`` each hidden layer's under ``l1`` …
+    ``l{L-1}`` (``admm_w/l2``, ``admm_z/l1`` in the op's path).
 
     ``adj`` is the shard's adjacency rows — dense mode: a_row (k,M,n,n);
     compressed mode: (ell_rows (k,max_deg,n,n), ell_idx (k,max_deg),
@@ -892,119 +907,131 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
     new_ws, new_taus, counts = [], [], []
     with jax.named_scope("admm_w"):
         for l in range(num_layers):
-            agg = rowagg(zh_in[l])                  # (k, n, C_{l-1})
+            with jax.named_scope(f"l{l + 1}"):
+                agg = rowagg(zh_in[l])              # (k, n, C_{l-1})
 
-            # minibatch: unsampled lanes' constraints leave the (psum-ed)
-            # W objective entirely — their residuals mask to exact zeros
-            if l < num_layers - 1:
-                def local_obj(w, agg=agg, z=zs_loc[l]):
-                    r = z - f(agg @ w)
-                    if sm is not None:
-                        r = r * sm
-                    return 0.5 * admm.nu * jnp.vdot(r, r).real
-            else:
-                def local_obj(w, agg=agg, z=zs_loc[l]):
-                    r = z - agg @ w
-                    if sm is not None:
-                        r = r * sm
-                    return jnp.vdot(u_loc, r).real + \
-                        0.5 * admm.rho * jnp.vdot(r, r).real
-            w_new, tau, n = backtracking_step_psum(local_obj, ws[l], taus[l],
-                                                   admm)
-            new_ws.append(w_new)
-            new_taus.append(tau)
-            counts.append(n)
+                # minibatch: unsampled lanes' constraints leave the
+                # (psum-ed) W objective entirely — their residuals mask to
+                # exact zeros
+                if l < num_layers - 1:
+                    def local_obj(w, agg=agg, z=zs_loc[l]):
+                        r = z - f(agg @ w)
+                        if sm is not None:
+                            r = r * sm
+                        return 0.5 * admm.nu * jnp.vdot(r, r).real
+                else:
+                    def local_obj(w, agg=agg, z=zs_loc[l]):
+                        r = z - agg @ w
+                        if sm is not None:
+                            r = r * sm
+                        return jnp.vdot(u_loc, r).real + \
+                            0.5 * admm.rho * jnp.vdot(r, r).real
+                w_new, tau, n = backtracking_step_psum(local_obj, ws[l],
+                                                       taus[l], admm)
+                new_ws.append(w_new)
+                new_taus.append(tau)
+                counts.append(n)
 
     # ---- Line 4: Z update (community-parallel, reads W^{k+1}, Z^k) ----
     new_zs, new_thetas = [], []
     with jax.named_scope("admm_z"):
         for l in range(1, num_layers):              # hidden layers (eq. 5/6)
-            w_l, w_next = new_ws[l - 1], new_ws[l]
-            target1 = f(rowagg_mm(zh_in[l - 1], w_l))        # (k, n, C_l)
-            # relay aggregates q_{l,r} (eq. 4 second-order payload), all r
-            q_loc = rowagg_mm(zh[l - 1], w_next)             # (k, n, C_next)
-            q_all = gather(q_loc)[1]                         # blocked rows
-            z_ref = zs_loc[l - 1]
+            with jax.named_scope(f"l{l}"):
+                w_l, w_next = new_ws[l - 1], new_ws[l]
+                target1 = f(rowagg_mm(zh_in[l - 1], w_l))    # (k, n, C_l)
+                # relay aggregates q_{l,r} (eq. 4 second-order payload),
+                # all r
+                q_loc = rowagg_mm(zh[l - 1], w_next)         # (k, n, C_next)
+                q_all = gather(q_loc)[1]                     # blocked rows
+                z_ref = zs_loc[l - 1]
 
-            # Coupling term of ψ (paper eq. 5/6): every neighbour community
-            # r's next-layer pre-activation as a function of my lanes,
-            #   pre[j, r] = q_r + Ã_{r,m_j} (z_j − z_ref_j) W.
-            # Lane m's ψ only sums r ∈ N_m ∪ {m} — the r ∉ N_m residuals
-            # are constants in z (zero gradient) and drop from the
-            # objective.
-            if compressed:
-                # neighbour-compressed form: enumerate the max_deg stored
-                # neighbours only.  Ã_{r,m} = Ã_{m,r}ᵀ (Ã symmetric), so
-                # the stored row blocks are consumed transposed
-                # ("kdnp,knc->kdpc") — the gather-transpose trick of
-                # second_order_from_relay.  O(max_deg·n_pad²·C) per lane
-                # instead of the dense O(M·…).
-                def pre_nbr(z, q_all=q_all, z_ref=z_ref, w_next=w_next):
-                    delta = (z - z_ref) @ w_next             # (k, n, C)
-                    own = jnp.einsum("kdnp,knc->kdpc",
-                                     ell_rows.astype(jnp.float32), delta)
-                    return q_all[ell_idx] + own              # (k, D, n, C)
+                # Coupling term of ψ (paper eq. 5/6): every neighbour
+                # community r's next-layer pre-activation as a function of
+                # my lanes,
+                #   pre[j, r] = q_r + Ã_{r,m_j} (z_j − z_ref_j) W.
+                # Lane m's ψ only sums r ∈ N_m ∪ {m} — the r ∉ N_m
+                # residuals are constants in z (zero gradient) and drop
+                # from the objective.
+                if compressed:
+                    # neighbour-compressed form: enumerate the max_deg
+                    # stored neighbours only.  Ã_{r,m} = Ã_{m,r}ᵀ (Ã
+                    # symmetric), so the stored row blocks are consumed
+                    # transposed ("kdnp,knc->kdpc") — the gather-transpose
+                    # trick of second_order_from_relay.
+                    # O(max_deg·n_pad²·C) per lane instead of the dense
+                    # O(M·…).
+                    def pre_nbr(z, q_all=q_all, z_ref=z_ref,
+                                w_next=w_next):
+                        delta = (z - z_ref) @ w_next         # (k, n, C)
+                        own = jnp.einsum("kdnp,knc->kdpc",
+                                         ell_rows.astype(jnp.float32),
+                                         delta)
+                        return q_all[ell_idx] + own          # (k, D, n, C)
 
-                # staleness damping: √d_r folded into the coupling weight,
-                # so every squared residual carries the full d_r (exact
-                # identity when all ages are 0: ell_f · 1.0 is bitwise
-                # ell_f)
-                wt = (ell_f * sdr if sdr is not None
-                      else ell_f)[..., None, None]           # (k, D, 1, 1)
+                    # staleness damping: √d_r folded into the coupling
+                    # weight, so every squared residual carries the full
+                    # d_r (exact identity when all ages are 0: ell_f · 1.0
+                    # is bitwise ell_f)
+                    wt = (ell_f * sdr if sdr is not None
+                          else ell_f)[..., None, None]       # (k, D, 1, 1)
 
-                def nbr_vals(x_all):
-                    """(M, n, C) gathered payload -> this lane's
-                    (k, D, n, C)."""
-                    return x_all[ell_idx]
-            else:
-                def pre_nbr(z, q_all=q_all, z_ref=z_ref, w_next=w_next):
-                    delta = (z - z_ref) @ w_next             # (k, n, C)
-                    return q_all[None] + jnp.einsum("kmnp,knc->kmpc",
-                                                    a_row, delta)
+                    def nbr_vals(x_all):
+                        """(M, n, C) gathered payload -> this lane's
+                        (k, D, n, C)."""
+                        return x_all[ell_idx]
+                else:
+                    def pre_nbr(z, q_all=q_all, z_ref=z_ref,
+                                w_next=w_next):
+                        delta = (z - z_ref) @ w_next         # (k, n, C)
+                        return q_all[None] + jnp.einsum("kmnp,knc->kmpc",
+                                                        a_row, delta)
 
-                wt = nbrf[:, :, None, None]                  # (k, M, 1, 1)
+                    wt = nbrf[:, :, None, None]              # (k, M, 1, 1)
 
-                def nbr_vals(x_all):
-                    return x_all[None]                       # (1, M, n, C)
+                    def nbr_vals(x_all):
+                        return x_all[None]                   # (1, M, n, C)
 
-            if l + 1 < num_layers:
-                zh_next = zh[l][1]
+                if l + 1 < num_layers:
+                    zh_next = zh[l][1]
 
-                def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
-                              zh_next=zh_next):
-                    r1 = z - target1
-                    v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
-                    r2 = (nbr_vals(zh_next) - f(pre_nbr(z))) * wt
-                    v2 = 0.5 * admm.nu * jnp.sum(r2 * r2, axis=(1, 2, 3))
-                    return v1 + v2
-            else:
-                zh_last, uh = zh[l][1], gather(u_loc)[1]
+                    def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
+                                  zh_next=zh_next):
+                        r1 = z - target1
+                        v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
+                        r2 = (nbr_vals(zh_next) - f(pre_nbr(z))) * wt
+                        v2 = 0.5 * admm.nu * jnp.sum(r2 * r2,
+                                                     axis=(1, 2, 3))
+                        return v1 + v2
+                else:
+                    zh_last, uh = zh[l][1], gather(u_loc)[1]
 
-                def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
-                              zh_last=zh_last, uh=uh):
-                    r1 = z - target1
-                    v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
-                    r2 = (nbr_vals(zh_last) - pre_nbr(z)) * wt
-                    uv = nbr_vals(uh)
-                    if sdr is not None:
-                        # second √d_r: r2 carries one, so the dual term
-                        # ⟨U_r, ·⟩ scales by the full staleness weight d_r
-                        uv = uv * sdr[..., None, None]
-                    lin = jnp.sum(uv * r2, axis=(1, 2, 3))
-                    quad = 0.5 * admm.rho * jnp.sum(r2 * r2, axis=(1, 2, 3))
-                    return v1 + lin + quad
+                    def obj_lanes(z, target1=target1, pre_nbr=pre_nbr,
+                                  zh_last=zh_last, uh=uh):
+                        r1 = z - target1
+                        v1 = 0.5 * admm.nu * jnp.sum(r1 * r1, axis=(1, 2))
+                        r2 = (nbr_vals(zh_last) - pre_nbr(z)) * wt
+                        uv = nbr_vals(uh)
+                        if sdr is not None:
+                            # second √d_r: r2 carries one, so the dual
+                            # term ⟨U_r, ·⟩ scales by the full staleness
+                            # weight d_r
+                            uv = uv * sdr[..., None, None]
+                        lin = jnp.sum(uv * r2, axis=(1, 2, 3))
+                        quad = 0.5 * admm.rho * jnp.sum(r2 * r2,
+                                                        axis=(1, 2, 3))
+                        return v1 + lin + quad
 
-            z_new, theta, n = backtracking_step_lanes(
-                obj_lanes, zs_loc[l - 1], thetas[l - 1], admm)
-            counts.append(n)
-            if smask_b is not None:
-                # unsampled lanes keep their iterates bit-for-bit (exact
-                # block-coordinate step on the sampled blocks)
-                z_new = jnp.where(smask_b[:, None, None], z_new,
-                                  zs_loc[l - 1])
-                theta = jnp.where(smask_b, theta, thetas[l - 1])
-            new_zs.append(z_new)
-            new_thetas.append(theta)
+                z_new, theta, n = backtracking_step_lanes(
+                    obj_lanes, zs_loc[l - 1], thetas[l - 1], admm)
+                counts.append(n)
+                if smask_b is not None:
+                    # unsampled lanes keep their iterates bit-for-bit
+                    # (exact block-coordinate step on the sampled blocks)
+                    z_new = jnp.where(smask_b[:, None, None], z_new,
+                                      zs_loc[l - 1])
+                    theta = jnp.where(smask_b, theta, thetas[l - 1])
+                new_zs.append(z_new)
+                new_thetas.append(theta)
 
     # ---- Z_L: per-community FISTA prox (eq. 7) ----
     with jax.named_scope("admm_fista"):
@@ -1036,8 +1063,11 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         new_zs = [to_plane(z) for z in new_zs]
         new_u = to_plane(new_u)
 
+    # the round's counts summed over its searches, then each search's own
+    # (``probe_columns``)
+    round_counts = jnp.concatenate([sum(counts)] + counts)
     return (tuple(new_ws), tuple(new_zs), new_u,
-            tuple(new_taus), tuple(new_thetas), probes + sum(counts)[None])
+            tuple(new_taus), tuple(new_thetas), probes + round_counts[None])
 
 
 # ---------------------------------------------------------------------------
@@ -1146,7 +1176,8 @@ class ParallelADMMTrainer:
             u = np.zeros_like(zs[-1])
             taus = tuple(jnp.asarray(admm.tau_init) for _ in ws)
             thetas = tuple(jnp.full((m,), admm.tau_init) for _ in zs)
-            probes = np.zeros((n_shards, 2), np.int32)
+            probes = np.zeros((n_shards, len(probe_columns(cfg.num_layers))),
+                              np.int32)
             sharded, rep = P(AXIS), P()
             n_l = cfg.num_layers
             self.state_spec = ParallelState((rep,) * n_l, (sharded,) * n_l,

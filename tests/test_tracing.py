@@ -8,6 +8,7 @@ Python loop over the same backtracking test, and the step sizes the
 searches leave behind (with ``backtrack_growth`` 2, τ_out / τ_start is
 2^iterations exactly).
 """
+import contextlib
 import glob
 import math
 import os
@@ -24,7 +25,8 @@ from jax.sharding import AxisType
 from repro.core import gcn, graph
 from repro.core.parallel import (AXIS, ParallelADMMTrainer, TrainerConfig,
                                  backtracking_step_lanes,
-                                 backtracking_step_psum, fista_lanes)
+                                 backtracking_step_psum, fista_lanes,
+                                 probe_columns)
 from repro.core.subproblems import ADMMConfig
 from repro.util import spans
 
@@ -38,12 +40,12 @@ def _graph(m=4):
         size_skew=0.8)
 
 
-def _trainer(g=None, part=None, mesh=None, **admm_kw):
+def _trainer(g=None, part=None, mesh=None, hidden=(8,), **admm_kw):
     if g is None:
         g, part = _graph()
     if mesh is None:
         mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
-    cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
+    cfg = gcn.GCNConfig(layer_dims=(8, *hidden, g.num_classes))
     admm = ADMMConfig(**{"nu": 1e-3, "rho": 1e-3, **admm_kw})
     return ParallelADMMTrainer(cfg, admm, g, num_parts=int(part.max()) + 1,
                                seed=0, part=part, mesh=mesh,
@@ -60,18 +62,25 @@ def _iters(start, end):
 
 
 def _counts_from_step_sizes(before, after, admm, n_shards):
-    """[evaluations, capped] per shard of the W and hidden-Z searches of
-    one round, read off τ and θ (FISTA keeps no step size in the state;
-    the tests that use this run with ``fista_iters=0``)."""
-    counts = np.zeros((n_shards, 2), int)
-    for t0, t1 in zip(before.taus, after.taus):
+    """The ``probe_columns`` per shard of the W and hidden-Z searches of
+    one round, read off τ and θ: [evaluations, capped] summed, then per
+    search (FISTA keeps no step size in the state; the tests that use
+    this run with ``fista_iters=0``, which leaves its columns at 0)."""
+    n_l = len(before.taus)
+    counts = np.zeros((n_shards, len(probe_columns(n_l))), int)
+    for l, (t0, t1) in enumerate(zip(before.taus, after.taus)):
         it = int(_iters(_start(t0, admm), t1))
-        counts += [it + 1, it >= admm.max_backtracks]
-    for th0, th1 in zip(before.thetas[:-1], after.thetas[:-1]):
+        col = 2 + 2 * l
+        counts[:, col:col + 2] += [it + 1, it >= admm.max_backtracks]
+    for l, (th0, th1) in enumerate(zip(before.thetas[:-1],
+                                       after.thetas[:-1])):
         lane_it = _iters(_start(th0, admm), th1).reshape(n_shards, -1)
         it = lane_it.max(axis=1)          # the loop runs until every lane
-        counts[:, 0] += it + 1            # of the shard has accepted
-        counts[:, 1] += it >= admm.max_backtracks
+        col = 2 + 2 * (n_l + l)           # of the shard has accepted
+        counts[:, col] += it + 1
+        counts[:, col + 1] += it >= admm.max_backtracks
+    counts[:, 0] = counts[:, 2::2].sum(axis=1)
+    counts[:, 1] = counts[:, 3::2].sum(axis=1)
     return counts
 
 
@@ -95,6 +104,49 @@ def test_compiled_step_carries_the_sub_update_scope(step_hlo, scope):
 def test_line_search_loops_sit_inside_their_scope(step_hlo, scope):
     paths = re.findall(r'op_name="([^"]*)"', step_hlo)
     assert any(re.search(rf"/{scope}/.*while", p) for p in paths), scope
+
+
+LAYER_SCOPES = ("admm_w/l1", "admm_w/l2", "admm_w/l3", "admm_z/l1",
+                "admm_z/l2")
+
+
+@pytest.fixture(scope="module")
+def step_hlo_three_layers():
+    t = _trainer(hidden=(8, 8))
+    return t._step.lower(*t._analysis_args).compile().as_text()
+
+
+@pytest.mark.parametrize("scope", LAYER_SCOPES)
+def test_three_layer_step_carries_a_scope_per_layer(step_hlo_three_layers,
+                                                    scope):
+    paths = re.findall(r'op_name="([^"]*)"', step_hlo_three_layers)
+    assert any(f"/{scope}/" in p for p in paths), scope
+    assert not any(f"/{s}/" in p for p in paths
+                   for s in ("admm_w/l4", "admm_z/l3", "admm_fista/l"))
+
+
+def test_layer_scopes_leave_the_iterates_bitwise(monkeypatch):
+    """A 2-layer round traced with the per-layer scopes is bitwise the
+    round traced with the sub-update scopes alone."""
+    with_layers, without = _trainer(), _trainer()
+    with_layers.step()
+    named_scope = jax.named_scope
+
+    def outer_only(name):
+        if re.fullmatch(r"l\d+", name):
+            return contextlib.nullcontext()
+        return named_scope(name)
+    monkeypatch.setattr(jax, "named_scope", outer_only)
+    hlo = without._step.lower(*without._analysis_args).compile().as_text()
+    without.step()
+    monkeypatch.undo()
+    assert "/admm_w/l1/" not in hlo and "/admm_w/" in hlo
+    for _ in range(2):
+        with_layers.step()
+        without.step()
+    a, b = jax.device_get(with_layers.state), jax.device_get(without.state)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ---------------------------------------------------------------------------
@@ -219,7 +271,7 @@ def test_fista_sums_its_lipschitz_tests(cap):
 @pytest.mark.parametrize("tau_init, cap", [(1.0, 30), (1e-4, 3)])
 def test_round_counters_match_the_step_sizes(tau_init, cap):
     t = _trainer(tau_init=tau_init, max_backtracks=cap, fista_iters=0)
-    total = np.zeros((1, 2), int)
+    total = np.zeros((1, len(probe_columns(2))), int)
     for _ in range(3):
         before = jax.device_get(t.state)
         t.step()
@@ -229,6 +281,36 @@ def test_round_counters_match_the_step_sizes(tau_init, cap):
     assert np.asarray(t.state.probes).dtype == np.int32
     if cap == 3:
         assert total[0, 1] >= 3       # the first W search of each round
+
+
+def test_three_layer_round_counters_match_the_step_sizes():
+    t = _trainer(hidden=(8, 8), tau_init=1e-3, max_backtracks=30,
+                 fista_iters=0)
+    total = np.zeros((1, len(probe_columns(3))), int)
+    for _ in range(3):
+        before = jax.device_get(t.state)
+        t.step()
+        after = jax.device_get(t.state)
+        total += _counts_from_step_sizes(before, after, t.admm, 1)
+    np.testing.assert_array_equal(np.asarray(t.state.probes), total)
+
+
+def test_per_search_columns_sum_to_the_totals():
+    t = _trainer(hidden=(8, 8), tau_init=1e-4, max_backtracks=3)
+    for _ in range(3):
+        t.step()
+    p = np.asarray(t.state.probes)
+    cols = probe_columns(3)
+    assert cols[:4] == ["evals", "capped", "w1.evals", "w1.capped"]
+    assert p.shape == (1, len(cols)) == (1, 14)
+    np.testing.assert_array_equal(p[:, 0], p[:, 2::2].sum(axis=1))
+    np.testing.assert_array_equal(p[:, 1], p[:, 3::2].sum(axis=1))
+    assert p[0, 1] > 0
+    # every search runs at least its first test each round; FISTA runs
+    # ``fista_iters`` of them
+    evals = dict(zip(cols[2::2], p[0, 2::2]))
+    assert all(v >= 3 for v in evals.values()), evals
+    assert evals["z3.evals"] >= 3 * t.admm.fista_iters
 
 
 def test_capped_searches_rise_round_by_round():
@@ -245,8 +327,10 @@ def test_capped_searches_rise_round_by_round():
 def test_counters_change_no_iterate():
     """The count rides along in the state and feeds nothing back."""
     a, b = _trainer(), _trainer()
+    offset = np.zeros(b.state.probes.shape, np.int32)
+    offset[0, :2] = [10 ** 6, 7]
     b.state = b.state._replace(probes=jax.device_put(
-        np.asarray([[10 ** 6, 7]], np.int32), b.state.probes.sharding))
+        offset, b.state.probes.sharding))
     for _ in range(2):
         a.step()
         b.step()
@@ -254,8 +338,7 @@ def test_counters_change_no_iterate():
                     jax.tree.leaves(b.state)[:-1]):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     np.testing.assert_array_equal(
-        np.asarray(b.state.probes) - np.asarray(a.state.probes),
-        [[10 ** 6, 7]])
+        np.asarray(b.state.probes) - np.asarray(a.state.probes), offset)
 
 
 _SHARDED_WORKER = r"""
@@ -267,8 +350,8 @@ g, part = tt._graph(m=8)
 mesh = jax.make_mesh((4,), (AXIS,), (AxisType.Auto,))
 t = tt._trainer(g, part, mesh, tau_init=1e-2, max_backtracks=30,
                 fista_iters=0)
-assert t.state.probes.shape == (4, 2)
-total = np.zeros((4, 2), int)
+assert t.state.probes.shape == (4, 10)
+total = np.zeros((4, 10), int)
 for _ in range(3):
     before = jax.device_get(t.state)
     t.step()
