@@ -154,8 +154,9 @@ def fused_agg_handoffs(closed_jaxpr: "object", n_pad: int) -> list[dict]:
 def fused_no_intermediate(ctx: AnalysisContext) -> Iterable[Finding]:
     """Under ``TrainerConfig(fused=True)`` the compiled step materialises
     no HBM-resident aggregated ``(rows, n_pad, C)`` stack feeding a GEMM
-    beyond the W-update allowance (one per layer — its line search
-    legitimately re-reads the aggregate under a varying W).  Checked on
+    beyond the W-update allowance (one per layer past the first, whose
+    Ã Z_0 comes from construction — its line search legitimately re-reads
+    the aggregate under a varying W).  Checked on
     the traced jaxpr, where the handoff survives on every dispatch
     target: the TPU program would show a pallas_call output into a dot,
     the CPU oracle an einsum output into a dot — the fused kernel keeps

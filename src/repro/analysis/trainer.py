@@ -81,7 +81,8 @@ def trainer_expectations(tr: Any) -> dict[str, Any]:
     n_shards = tr.mesh.shape[AXIS]
     m = tr.data.num_parts
     n_pad = tr.layout.n_pad
-    cs = gathered_widths(tr.cfg.layer_dims)
+    # fused only acts on the packed wire, which one shard does not have
+    cs = gathered_widths(tr.cfg.layer_dims, fused=tr.fused and n_shards > 1)
     max_c = max(tr.cfg.layer_dims)
     if tr.data.ell_mask is not None:
         max_deg = int(tr.data.ell_mask.shape[1])
@@ -138,14 +139,15 @@ def trainer_expectations(tr: Any) -> dict[str, Any]:
         exp["packed_rows_bound"] = int(tr._plan.r_pad)
     # fused aggregation→GEMM: only the W-update may hand an aggregated
     # block stack to a dot (its line search re-evaluates the GEMM under a
-    # varying W) — one aggregate per layer; every Z-update site must run
-    # the fused/reassociated form.  Like state_packed, only meaningful
-    # when the packed plane feeds the wire.
+    # varying W) — one aggregate per layer past the first, whose Ã Z_0 is
+    # device data from construction; every Z-update site must run the
+    # fused/reassociated form.  Like state_packed, only meaningful when the
+    # packed plane feeds the wire.
     exp["fused"] = bool(exp["state_packed"]
                         and getattr(getattr(tr, "config", None),
                                     "fused", False))
     if exp["fused"]:
-        exp["fused_max_agg_handoffs"] = int(tr.cfg.num_layers)
+        exp["fused_max_agg_handoffs"] = int(tr.cfg.num_layers) - 1
     # largest legitimate resident buffers: the adjacency store, the full
     # Z/U state stack, and one gathered payload; anything 4x past their
     # max is a blow-up

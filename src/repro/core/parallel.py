@@ -17,9 +17,11 @@ One ADMM iteration is a single ``shard_map``-ed program:
 
 Communication per iteration (the roofline 'collective' term) is one
 exchange of Z/U/q per consumer round; the paper's p/s messages are exactly
-the relayed aggregates, see messages.py.  Z_0 is static input — it is
-exchanged exactly once per iteration and reused by every consumer (layer-1
-input and the 1-layer dual refresh).  Two transports (``transport`` flag):
+the relayed aggregates, see messages.py.  Z_0 is static input — the
+trainer aggregates Ã·Z_0 once at construction and every consumer reads it
+(layer-1 input, the 1-layer dual refresh, the eval programs), so it is not
+exchanged at all, but on the fused packed wire, whose one-pass Ã(Z_0 W)
+Z sites gather it once per iteration.  Two transports (``transport`` flag):
 
   * allgather — ``lax.all_gather`` moves every shard's payload to every
     shard, then masks to the neighbour rows.  The only transport the dense
@@ -280,23 +282,28 @@ def community_data(g: graph.Graph, layout: graph.CommunityLayout,
 # aggregation order: Ã·Z·W at the narrow side
 # ---------------------------------------------------------------------------
 
-def gathered_widths(layer_dims) -> list[int]:
-    """Payload width of each gather in one ADMM iteration: Z_0 (once),
-    Z_1..Z_L, then for L >= 2 the relay q per hidden layer, U, and the
-    dual's refresh Z_{L-1}⁺.  A 1-layer net has no hidden Z loop (no q, no
-    U gather) and its dual refresh reuses the already-gathered Z_0."""
+def gathered_widths(layer_dims, fused: bool = False) -> list[int]:
+    """Payload width of each gather in one ADMM iteration: Z_1..Z_L, then
+    for L >= 2 the relay q per hidden layer, U, and the dual's refresh
+    Z_{L-1}⁺.  A 1-layer net has no hidden Z loop (no q, no U gather).
+    Z_0 crosses the wire only on the ``fused`` packed wire, once, for the
+    one-pass Ã(Z_0 W) of its Z sites; every other mode reads the
+    construction-time Ã·Z_0 (``ParallelADMMTrainer``'s ``ax``)."""
     dims = list(layer_dims)
-    cs = [dims[0]] + dims[1:]
+    cs = ([dims[0]] if fused else []) + dims[1:]
     if len(dims) > 2:
         cs += dims[2:] + [dims[-1], dims[-2]]
     return cs
 
 
-def narrow_first_agg(agg, widths: list):
-    """``agg_mm(z, w)`` = Ã·z·w through ``agg(z)`` = Ã·z, aggregated at the
-    narrower side: Ã(z w) when ``w`` narrows (C_out < C_in), else (Ã z) w.
-    A repeated (z, w) pair reuses its first result.  ``widths`` is
-    cleared, then gets the width of each aggregation as it is traced."""
+def narrow_first_agg(agg, widths: list, seeds=()):
+    """``agg_mm(z, w)`` = Ã·z·w through ``agg(z)`` = Ã·z.  A ``z`` whose
+    aggregate is at hand — ``seeds`` holds (z, Ã·z) pairs, as the eval
+    programs seed (Z_0, ``ax``) — is (Ã z) w, a GEMM with no aggregation;
+    any other is aggregated at the narrower side: Ã(z w) when ``w``
+    narrows (C_out < C_in), else (Ã z) w.  A repeated (z, w) pair reuses
+    its first result.  ``widths`` is cleared, then gets the width of each
+    aggregation as it is traced."""
     widths.clear()
     done = []
 
@@ -304,10 +311,14 @@ def narrow_first_agg(agg, widths: list):
         for z_seen, w_seen, out in done:
             if z_seen is z and w_seen is w:
                 return out
-        narrow = w.shape[1] < w.shape[0]
-        x = z @ w if narrow else z
-        widths.append(int(x.shape[-1]))
-        out = agg(x) if narrow else agg(x) @ w
+        az = next((a for z_seed, a in seeds if z_seed is z), None)
+        if az is not None:
+            out = az @ w
+        else:
+            narrow = w.shape[1] < w.shape[0]
+            x = z @ w if narrow else z
+            widths.append(int(x.shape[-1]))
+            out = agg(x) if narrow else agg(x) @ w
         done.append((z, w, out))
         return out
     return agg_mm
@@ -628,17 +639,27 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
                     packed_aux: "dict | None",
                     mb_aux: "dict | None",
                     agg_widths: list,
-                    adj, nbr_row, z0_loc, labels_loc, mask_loc, denom,
-                    ws, zs_loc, u_loc, taus, thetas, probes,
+                    adj, nbr_row, z0_loc, ax_loc, labels_loc, mask_loc,
+                    denom, ws, zs_loc, u_loc, taus, thetas, probes,
                     nbr_decay=None):
     """Shapes per shard: nbr_row (k,M); z*_loc (k,n,C); thetas[l] (k,);
     probes (1, len(probe_columns(L))), to which the round adds the
     ``probe_count`` of every line search it ran, summed and per search.
 
+    ``ax_loc`` (k, n, C_0) is this shard's lanes of Ã·Z_0, which the
+    trainer aggregates once at construction: Z_0 = X never changes, so
+    every site that aggregates the layer-1 input (the W_1 search, the
+    layer-1 Z target, and for L = 1 FISTA's B and the dual) reads it, and
+    no round aggregates or gathers Z_0.  The one exception is the fused
+    packed wire (``fused`` with a plan), whose Z sites keep their one-pass
+    Ã(Z_0 W) and so gather Z_0 once a round; its W_1 search reads
+    ``ax_loc`` too.
+
     ``agg_widths`` is cleared, then gets the width of each distinct
     gathered operand the round aggregates, as the body is traced: the W,
-    Z and FISTA sites aggregate each Z_l once between them (XLA merges
-    the repeats into one call), and the dual aggregates its refresh.
+    Z and FISTA sites aggregate each Z_l (l >= 1) once between them (XLA
+    merges the repeats into one call), and the dual aggregates its
+    refresh.
 
     The four sub-updates run under ``jax.named_scope`` ``admm_w`` (Line 3
     with its line search), ``admm_z`` (eq. 5/6), ``admm_fista`` (eq. 7)
@@ -706,6 +727,7 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         smask_b = sm = sdr = None
 
     packed_wire = packed_aux is not None and plan is not None
+    fused_wire = packed_wire and fused
     if packed_aux is not None:
         sid0 = jax.lax.axis_index(AXIS)
         kk, npd = packed_aux["k"], packed_aux["n"]
@@ -720,7 +742,8 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
             flat = blk.reshape((kk * npd,) + blk.shape[2:])
             return jnp.take(flat, pk_tbl, axis=0, mode="fill", fill_value=0)
 
-        z0_loc = from_plane(z0_loc)
+        if fused_wire:
+            z0_loc = from_plane(z0_loc)
         labels_loc = from_plane(labels_loc)
         mask_loc = from_plane(mask_loc)
         zs_loc = tuple(from_plane(z) for z in zs_loc)
@@ -811,7 +834,7 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
     # (k, n, C_in) stack never exists outside VMEM scratch.  Overlap
     # composes by linearity: (Σ_g agg_g) @ W == Σ_g (agg_g @ W), each
     # arrival group's fused call depending only on its own stage buffer.
-    if packed_wire and fused:
+    if fused_wire:
         if use_kernel:
             from repro.kernels import ops as kops
 
@@ -897,18 +920,26 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
             return (g, g)
 
     # gathered k-th iterates — one communication round per ADMM iteration.
-    # Z_0 is static input: gather it exactly once per step and reuse it for
-    # the layer-1 input and (1-layer nets) the dual refresh.
-    zh0 = gather(z0_loc)                        # Z_0, gathered once
+    # Z_0 is static input, aggregated once at construction (``ax_loc``):
+    # only the fused wire's one-pass Z sites gather it, once per step.
+    zh0 = gather(z0_loc) if fused_wire else None
     zh = [gather(z) for z in zs_loc]            # Z_1..Z_L
     zh_in = [zh0] + zh[:-1]                     # layer inputs
+
+    def agg_in_mm(l, w):
+        """Ã·(layer l+1's input)·w at a Z-update site: (Ã Z_0) w from
+        ``ax_loc`` for the first layer, off the fused wire."""
+        if l == 0 and not fused_wire:
+            return ax_loc @ w
+        return rowagg_mm(zh_in[l], w)
 
     # ---- Line 3: W update (layer-parallel, Jacobi over Z^k) ----
     new_ws, new_taus, counts = [], [], []
     with jax.named_scope("admm_w"):
         for l in range(num_layers):
             with jax.named_scope(f"l{l + 1}"):
-                agg = rowagg(zh_in[l])              # (k, n, C_{l-1})
+                # (k, n, C_{l-1})
+                agg = ax_loc if l == 0 else rowagg(zh_in[l])
 
                 # minibatch: unsampled lanes' constraints leave the
                 # (psum-ed) W objective entirely — their residuals mask to
@@ -938,7 +969,7 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         for l in range(1, num_layers):              # hidden layers (eq. 5/6)
             with jax.named_scope(f"l{l}"):
                 w_l, w_next = new_ws[l - 1], new_ws[l]
-                target1 = f(rowagg_mm(zh_in[l - 1], w_l))    # (k, n, C_l)
+                target1 = f(agg_in_mm(l - 1, w_l))           # (k, n, C_l)
                 # relay aggregates q_{l,r} (eq. 4 second-order payload),
                 # all r
                 q_loc = rowagg_mm(zh[l - 1], w_next)         # (k, n, C_next)
@@ -1035,7 +1066,7 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
 
     # ---- Z_L: per-community FISTA prox (eq. 7) ----
     with jax.named_scope("admm_fista"):
-        b = rowagg_mm(zh_in[num_layers - 1], new_ws[-1])
+        b = agg_in_mm(num_layers - 1, new_ws[-1])
         z_last, n = fista_lanes(admm, b, u_loc, labels_loc, mask_loc,
                                 zs_loc[-1], denom)
         counts.append(n)
@@ -1050,9 +1081,10 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         # search forms its residual with.  Ã(Z⁺ W⁺) differs from it at
         # float32 rounding, and on a v5e at the paper's widths that
         # stalled the search (τ_L doubled to its cap) on every seed tried
-        zh_pen_new = gather(new_zs[num_layers - 2]) if num_layers >= 2 \
-            else zh0
-        b_new = rowagg_mm(zh_pen_new, new_ws[-1])
+        if num_layers >= 2:
+            b_new = rowagg_mm(gather(new_zs[num_layers - 2]), new_ws[-1])
+        else:
+            b_new = agg_in_mm(0, new_ws[-1])
         new_u = u_loc + admm.rho * (new_zs[-1] - b_new)
         if smask_b is not None:
             new_u = jnp.where(smask_b[:, None, None], new_u, u_loc)
@@ -1220,6 +1252,7 @@ class ParallelADMMTrainer:
         # and indexed in-body by axis_index, so the shard_map specs never
         # see them (same pattern as the plan's send/recv tables)
         overlap_on = bool(overlap and body_plan is not None)
+        fused_on = bool(fused and body_plan is not None)
         packed_aux = None
         if packed:
             dl = self.packed_layout
@@ -1259,11 +1292,82 @@ class ParallelADMMTrainer:
         else:
             adj_data = self.data.a_blocks
             adj_spec = sharded
+        # full-M packed aggregation: ELL for Ã·Z_0 in every mode and for the
+        # metrics/Lagrangian programs in compressed mode (no dense
+        # adjacency is retained on device), the masked dense einsum for
+        # dense mode's.  The ELL aggregation runs per shard under the mesh
+        # (a Pallas kernel cannot be auto-partitioned across chips): each
+        # shard aggregates its own lanes against the replicated blocked Z.
+        # ``use_kernel`` picks the kernel or the einsum, as in the step
+        # body.
+        from repro.kernels import ops as kops
+        from repro.kernels import ref as kref
+        agg_lanes = kops.community_spmm_ell if use_kernel \
+            else kref.community_spmm_ell_einsum
+        agg_ell = jax.shard_map(
+            lambda ell, z: agg_lanes(*ell[:3], z, *ell[3:]), mesh=mesh,
+            in_specs=((sharded,) * 5, rep), out_specs=sharded,
+            check_vma=False)
         data = self.data
+        if compressed:
+            agg_full = agg_ell
+            adj_full = (data.ell_blocks, data.ell_indices, data.ell_mask,
+                        data.row_counts, data.nbr_counts)
+            ell_full = adj_full
+        else:
+            adj_full = (data.a_blocks, data.neighbor_mask)
+
+            def agg_full(adj, z_pack):
+                a_blocks, nbr = adj
+                nbr_f = nbr.astype(jnp.float32)
+                return jnp.einsum("mrip,rpc->mic",
+                                  a_blocks * nbr_f[:, :, None, None], z_pack)
+
+            # the ELL view of the dense blocks, for Ã·Z_0 alone
+            csr = self.layout.compress()
+            ell_full = place_on_mesh(mesh, (
+                csr.ell_blocks, csr.ell_indices, csr.ell_mask,
+                *csr.ell_row_counts()), sharded)
+
+        # Ã·Z_0 and the eval programs run on the blocked (M, n_pad, ...)
+        # view; in packed mode the planes are rebuilt through the device
+        # layout's global row table (take-with-fill, bitwise lossless
+        # under the zero-outside-counts contract)
+        if packed:
+            gup = np.asarray(self.packed_layout.global_unpack_rows())
+            n_pad_loc = self.layout.n_pad
+
+            def unfold(p):
+                flat = jnp.take(p, gup, axis=0, mode="fill", fill_value=0)
+                return flat.reshape((m, n_pad_loc) + p.shape[1:])
+        else:
+            def unfold(p):
+                return p
+
+        # Z_0 = X never changes, so Ã·Z_0 is aggregated once, here, and
+        # carried as device data: blocked (M, n_pad, C_0), split over the
+        # comm axis like the step's lanes.  The step's first-layer sites
+        # and both eval programs read it.  Every mode aggregates it through
+        # the ELL view, so dense and compressed trainers start from the
+        # same bits: the first W search's objective is float32 rounding
+        # at construction (Z_1 is the forward pass's), and which way it
+        # goes follows those bits.  The width of every distinct
+        # aggregation each program lowers, filled as the program is traced
+        # (``comm_stats["aggregations"]``), so C_0 is under "construct"
+        agg_widths = {"construct": [int(data.z0.shape[-1])], "step": [],
+                      "metrics": [], "lagrangian": []}
+        with spans.span("construct.input_agg"):
+            @partial(jax.jit, out_shardings=NamedSharding(mesh, sharded))
+            def input_agg(ell, z0):
+                return agg_ell(ell, unfold(z0))
+
+            self.ax = jax.block_until_ready(input_agg(ell_full, data.z0))
+
         k_lanes = m // n_shards
-        step_spec = (adj_spec, sharded, sharded, sharded, sharded, rep)
+        step_spec = (adj_spec, sharded, sharded, sharded, sharded, sharded,
+                     rep)
         step_data = place_on_mesh(mesh, (
-            adj_data, data.neighbor_mask, data.z0, data.labels,
+            adj_data, data.neighbor_mask, data.z0, self.ax, data.labels,
             data.train_mask, data.denom), step_spec)
 
         def make_step(sampled=None):
@@ -1325,9 +1429,6 @@ class ParallelADMMTrainer:
                     return ParallelState(*mapped(*dev, *state, nbr_decay))
             return BoundProgram(step, step_data), step_plan
 
-        # the width of every distinct aggregation each program lowers,
-        # filled as the program is traced (``comm_stats["aggregations"]``)
-        agg_widths = {"step": [], "metrics": [], "lagrangian": []}
         self._make_step = make_step
         self._sampler = None
         self._round = 0
@@ -1354,9 +1455,10 @@ class ParallelADMMTrainer:
             self._active_plan = plan0 if plan0 is not None else self._plan
 
         # collective volume per iteration: one (M, n_pad, C) payload per
-        # gather of the body (``gathered_widths``)
+        # gather of the body (``gathered_widths``; Z_0 on the fused wire
+        # only)
         dims = list(cfg.layer_dims)
-        gathered_cs = gathered_widths(dims)
+        gathered_cs = gathered_widths(dims, fused=fused_on)
         self.comm_stats = messages.gather_bytes(
             self.layout.neighbor_mask, self.layout.n_pad, gathered_cs,
             itemsize=2 if comm_bf16 else 4)
@@ -1481,55 +1583,15 @@ class ParallelADMMTrainer:
                 "full_state_rows": int(rc_sh.sum()),
             }
 
-        # full-M packed aggregation for metrics/Lagrangian: ELL in compressed
-        # mode (no dense adjacency is retained on device), masked dense
-        # einsum otherwise.  The ELL aggregation runs per shard under the
-        # mesh (a Pallas kernel cannot be auto-partitioned across chips):
-        # each shard aggregates its own lanes against the replicated
-        # blocked Z.  ``use_kernel`` picks the kernel or the einsum, as in
-        # the step body.  Each Ã·Z·W of the two programs aggregates at the
-        # narrower side (``narrow_first_agg``).
-        data = self.data
-        if compressed:
-            from repro.kernels import ops as kops
-            from repro.kernels import ref as kref
-            agg_lanes = kops.community_spmm_ell if use_kernel \
-                else kref.community_spmm_ell_einsum
-            agg_full = jax.shard_map(
-                lambda ell, z: agg_lanes(*ell[:3], z, *ell[3:]), mesh=mesh,
-                in_specs=((sharded,) * 5, rep), out_specs=sharded,
-                check_vma=False)
-            adj_full = (data.ell_blocks, data.ell_indices, data.ell_mask,
-                        data.row_counts, data.nbr_counts)
-        else:
-            adj_full = (data.a_blocks, data.neighbor_mask)
-
-            def agg_full(adj, z_pack):
-                a_blocks, nbr = adj
-                nbr_f = nbr.astype(jnp.float32)
-                return jnp.einsum("mrip,rpc->mic",
-                                  a_blocks * nbr_f[:, :, None, None], z_pack)
-
+        # Each Ã·Z·W of the two programs aggregates at the narrower side
+        # (``narrow_first_agg``), seeded with (Z_0, Ã·Z_0) so the first
+        # layer is a GEMM on ``ax``
         f_act = gcn.activation_fn(cfg.activation)
-
-        # metrics/Lagrangian run on the blocked (M, n_pad, ...) view; in
-        # packed mode the state planes are rebuilt through the device
-        # layout's global row table (take-with-fill, bitwise lossless
-        # under the zero-outside-counts contract)
-        if packed:
-            gup = np.asarray(self.packed_layout.global_unpack_rows())
-            n_pad_loc = self.layout.n_pad
-
-            def unfold(p):
-                flat = jnp.take(p, gup, axis=0, mode="fill", fill_value=0)
-                return flat.reshape((m, n_pad_loc) + p.shape[1:])
-        else:
-            def unfold(p):
-                return p
 
         # the device data the host-side metrics read, bound as arguments
         # (never baked into the programs as constants)
-        eval_data = {"adj": adj_full, "z0": data.z0, "labels": data.labels,
+        eval_data = {"adj": adj_full, "z0": data.z0, "ax": self.ax,
+                     "labels": data.labels,
                      "train": data.train_mask, "test": data.test_mask,
                      "row_mask": data.row_mask, "denom": data.denom}
 
@@ -1542,7 +1604,8 @@ class ParallelADMMTrainer:
         def metrics(dev, state: ParallelState):
             z0_blk, labels_blk, train_blk, test_blk, row_mask = blocked(dev)
             agg_mm = narrow_first_agg(partial(agg_full, dev["adj"]),
-                                      agg_widths["metrics"])
+                                      agg_widths["metrics"],
+                                      seeds=[(z0_blk, dev["ax"])])
             # community-blocked forward pass — logits (M, n_pad, C_L)
             logits = z0_blk
             for l, w in enumerate(state.weights):
@@ -1576,7 +1639,8 @@ class ParallelADMMTrainer:
                                        axis=-1)[..., 0]
             val = jnp.sum(nll * train_blk) / dev["denom"]
             agg_mm = narrow_first_agg(partial(agg_full, dev["adj"]),
-                                      agg_widths["lagrangian"])
+                                      agg_widths["lagrangian"],
+                                      seeds=[(z0_blk, dev["ax"])])
             z_prev = z0_blk
             for l in range(cfg.num_layers - 1):
                 r = (zs[l] - f_act(agg_mm(z_prev, ws[l]))) * row_mask

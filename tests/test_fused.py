@@ -306,11 +306,12 @@ for _ in range(3):
 print("FU_PARITY_OK")
 
 # --- the compiled fused step passes the analysis registry, the rule
-#     counts exactly the W-update floor ---
+#     counts exactly the W-update floor: one aggregate per layer past the
+#     first, whose Ã Z_0 is the constructor's ---
 n_pad = fu.layout.n_pad
 fu_h = len(fused_agg_handoffs(jax.make_jaxpr(fu._step)(fu.state), n_pad))
 un_h = len(fused_agg_handoffs(jax.make_jaxpr(un._step)(un.state), n_pad))
-assert fu_h == cfg.num_layers, (fu_h, cfg.num_layers)
+assert fu_h == cfg.num_layers - 1, (fu_h, cfg.num_layers)
 assert un_h > fu_h, (un_h, fu_h)
 rep = analysis.analyze_trainer(fu, config="p2p_fused")
 assert analysis.no_findings(rep, rule="memory/fused-no-intermediate")
@@ -324,7 +325,7 @@ from repro.analysis.rules.memory import fused_no_intermediate
 ctx = AnalysisContext(
     hlo_text=None, jaxpr=jax.make_jaxpr(un._step)(un.state),
     expectations={"n_pad": n_pad, "fused": True,
-                  "fused_max_agg_handoffs": cfg.num_layers},
+                  "fused_max_agg_handoffs": cfg.num_layers - 1},
     config="unfused-held-to-fused")
 hits = list(fused_no_intermediate(ctx))
 assert hits and hits[0].details["count"] == un_h, hits
@@ -334,7 +335,7 @@ print("FU_RULE_FIRES_OK")
 #     tolerance parity against the fused non-overlap trainer ---
 ov = build(fused=True, overlap=True)
 ov_h = len(fused_agg_handoffs(jax.make_jaxpr(ov._step)(ov.state), n_pad))
-assert ov_h == cfg.num_layers, ov_h
+assert ov_h == cfg.num_layers - 1, ov_h
 fu2 = build(fused=True)
 for _ in range(3):
     ov.step(); fu2.step()

@@ -386,7 +386,8 @@ def test_constructor_records_layout_then_initial_state():
     with spans.recording() as rec:
         _trainer(g, part)
     assert [s.name for s in rec] == ["construct.layout",
-                                     "construct.init_state"]
+                                     "construct.init_state",
+                                     "construct.input_agg"]
     assert all(s.parent is None and s.end_ns >= s.start_ns for s in rec)
     assert rec[0].end_ns <= rec[1].start_ns
 
