@@ -31,7 +31,7 @@ WORKER = textwrap.dedent("""
     import numpy as np
     from repro.core import gcn, graph
     from repro.core.subproblems import ADMMConfig
-    from repro.core.parallel import ParallelADMMTrainer
+    from repro.core.parallel import ParallelADMMTrainer, TrainerConfig
     from repro.launch import roofline
     dataset, m, epochs, hidden, partitioner = (
         sys.argv[1], int(sys.argv[2]), int(sys.argv[3]), int(sys.argv[4]),
@@ -41,7 +41,8 @@ WORKER = textwrap.dedent("""
     cfg = gcn.GCNConfig(layer_dims=(g.features.shape[1], hidden,
                                     g.num_classes))
     tr = ParallelADMMTrainer(cfg, ADMMConfig(nu=hyper, rho=hyper), g,
-                             num_parts=m, seed=0, partitioner=partitioner)
+                             num_parts=m, seed=0,
+                             config=TrainerConfig(partitioner=partitioner))
     # partition-quality head-to-head at this M: the cut sets the message
     # volume, max_deg the ELL fan-in, balance the padding waste
     quality = {

@@ -10,12 +10,7 @@ reports, per M:
     masked/compressed path (2·nnz·n_pad²·C);
   * per-iteration collective bytes of the parallel ADMM trainer's gathers:
     full all-gather vs the neighbour-only volume (messages.gather_bytes) —
-    the roofline's collective term, see benchmarks/roofline.py;
-  * an end-to-end trainer sweep: ParallelADMMTrainer in dense vs compressed
-    mode per M — device-resident adjacency bytes (the dense block tensor vs
-    the sharded ELL rows) and per-step wall time.  Compressed bytes must
-    scale with nnz blocks (~linear in M on the power-law generator), dense
-    with M².
+    the roofline's collective term, see benchmarks/roofline.py.
 
 Run: PYTHONPATH=src python benchmarks/block_sparsity.py [--quick]
                                                         [--out FILE.json]
@@ -121,45 +116,6 @@ def sweep(ms=(4, 8, 16, 32), nodes_per_part: int = 32, c: int = 64,
     return rows
 
 
-def trainer_sweep(ms=(4, 8, 16, 32), nodes_per_part: int = 32,
-                  hidden: int = 32, steps: int = 3, seed: int = 0
-                  ) -> list[dict]:
-    """End-to-end ParallelADMMTrainer per M: dense vs compressed
-    device-resident adjacency bytes and per-step wall time."""
-    from repro.core import gcn
-    from repro.core.parallel import ParallelADMMTrainer
-    from repro.core.subproblems import ADMMConfig
-
-    recs = []
-    for m in ms:
-        g, part = graph.synthetic_powerlaw_communities(
-            m, nodes_per_part=nodes_per_part, attach=2, seed=seed,
-            feat_dim=16)
-        cfg = gcn.GCNConfig(layer_dims=(16, hidden, g.num_classes))
-        admm = ADMMConfig(nu=1e-3, rho=1e-3)
-        for mode, compressed in (("dense", False), ("compressed", True)):
-            tr = ParallelADMMTrainer(cfg, admm, g, num_parts=m, seed=seed,
-                                     part=part, compressed=compressed)
-            assert (tr.data.a_blocks is None) == compressed
-            tr.step()                                    # compile
-            jax.block_until_ready(tr.state.zs[-1])
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                tr.step()
-            jax.block_until_ready(tr.state.zs[-1])
-            per_step = (time.perf_counter() - t0) / steps
-            recs.append({
-                "mode": mode, "M": m, "n_pad": tr.layout.n_pad,
-                "nnz_blocks": tr.layout.nnz_blocks,
-                "adjacency_bytes": int(tr.data.adjacency_nbytes),
-                "per_epoch_s": per_step,
-            })
-            print(f"[trainer] M={m:3d} {mode:10s} "
-                  f"adj {recs[-1]['adjacency_bytes']/1e6:8.3f} MB  "
-                  f"step {per_step*1e3:8.1f} ms")
-    return recs
-
-
 def main(quick: bool = False, out: "str | None" = None):
     ms = (4, 8) if quick else (4, 8, 16, 32)
     rows = sweep(ms=ms)
@@ -194,17 +150,7 @@ def main(quick: bool = False, out: "str | None" = None):
     print(f"FLOP growth {m0['M']}→{m1['M']} communities: dense "
           f"{dense_growth:.1f}×, nnz-proportional {sparse_growth:.1f}×")
 
-    trainer = trainer_sweep(ms=ms, steps=1 if quick else 3)
-    # device-resident adjacency must scale with nnz blocks, not M²
-    comp = [r for r in trainer if r["mode"] == "compressed"]
-    dense = [r for r in trainer if r["mode"] == "dense"]
-    comp_growth = comp[-1]["adjacency_bytes"] / comp[0]["adjacency_bytes"]
-    dense_growth = dense[-1]["adjacency_bytes"] / dense[0]["adjacency_bytes"]
-    assert comp_growth < dense_growth, (comp_growth, dense_growth)
-    print(f"Adjacency byte growth M={comp[0]['M']}→{comp[-1]['M']}: dense "
-          f"{dense_growth:.1f}×, compressed {comp_growth:.1f}×")
-
-    payload = {"quick": quick, "agg_sweep": rows, "trainer_sweep": trainer}
+    payload = {"quick": quick, "agg_sweep": rows}
     out_path = pathlib.Path(out) if out else \
         pathlib.Path(__file__).resolve().parent.parent / \
         "BENCH_block_sparsity.json"

@@ -3,17 +3,13 @@
 CI runs ``benchmarks/run.py --quick`` (which emits the quick payloads and
 calls this) so every push proves:
 
-  * both artifacts parse and carry the fields the perf-trajectory tracking
-    consumes (mode, M, byte counters, per-epoch seconds);
-  * the compressed adjacency does not regress above the dense curve (small
-    M may pay the tiny ELL index/mask overhead; the largest swept M must be
-    strictly smaller);
-  * the p2p transport's scheduled wire bytes stay below the all-gather
+  * the artifacts parse and carry the fields the perf-trajectory tracking
+    consumes (M, byte counters, per-epoch seconds);
+  * the p2p exchange's scheduled wire bytes stay below the all-gather
     volume — the wire-byte win the neighbour-only exchange exists for;
   * the multilevel partitioner strictly beats the BFS+KL stand-in on edge
-    cut at M=32 (no worse max_deg / wire bytes, strict balance) and never
-    cuts more than it on the trainer datasets — partition quality is the
-    lever behind every wire-byte number;
+    cut at M=32 (no worse max_deg / wire bytes, strict balance) —
+    partition quality is the lever behind every wire-byte number;
   * size-aware (bucketed) padding beats the global n_pad on the seed-0
     size-skewed power-law graph at M=32: lower pad bytes, lower pad FLOPs,
     and a row-exact p2p wire that undercuts the whole-block schedule and
@@ -49,8 +45,7 @@ def _fields(row: dict, spec: dict, where: str) -> None:
 
 def check_block_sparsity(payload: dict) -> None:
     where = "BENCH_block_sparsity"
-    _fields(payload, {"quick": bool, "agg_sweep": list,
-                      "trainer_sweep": list}, where)
+    _fields(payload, {"quick": bool, "agg_sweep": list}, where)
     _require(len(payload["agg_sweep"]) >= 2, where, "agg_sweep too short")
     for i, r in enumerate(payload["agg_sweep"]):
         w = f"{where}.agg_sweep[{i}]"
@@ -64,28 +59,6 @@ def check_block_sparsity(payload: dict) -> None:
         _require(r["coll_needed_kb"] <= r["coll_full_kb"] + 1e-9, w,
                  "needed volume above the all-gather volume")
 
-    sweep = payload["trainer_sweep"]
-    _require({r["mode"] for r in sweep} == {"dense", "compressed"}, where,
-             "trainer_sweep must cover dense and compressed modes")
-    by_m: dict[int, dict[str, int]] = {}
-    for i, r in enumerate(sweep):
-        w = f"{where}.trainer_sweep[{i}]"
-        _fields(r, {"mode": str, "M": int, "adjacency_bytes": int,
-                    "per_epoch_s": numbers.Real}, w)
-        _require(r["adjacency_bytes"] > 0 and r["per_epoch_s"] > 0, w,
-                 "non-positive measurement")
-        by_m.setdefault(r["M"], {})[r["mode"]] = r["adjacency_bytes"]
-    for m, d in sorted(by_m.items()):
-        # regression guard: compressed adjacency must never sit above the
-        # dense curve (beyond the ELL index/mask overhead at tiny M)
-        _require(d["compressed"] <= d["dense"] * 1.01 + 4096,
-                 f"{where}.M={m}",
-                 f"compressed adjacency {d['compressed']} regressed above "
-                 f"dense {d['dense']}")
-    top = by_m[max(by_m)]
-    _require(top["compressed"] < top["dense"], f"{where}.M={max(by_m)}",
-             "compressed adjacency not below dense at the largest M")
-
 
 def check_speedup(payload: dict) -> None:
     where = "BENCH_speedup"
@@ -94,9 +67,8 @@ def check_speedup(payload: dict) -> None:
                       "m32_packed": dict, "m32_minibatch": dict,
                       "m32_fused": dict}, where)
     modes = {r["mode"] for r in payload["rows"]}
-    _require(modes == {"parallel", "compressed", "p2p", "p2p_ml"}, where,
-             f"rows must cover parallel/compressed/p2p/p2p_ml, "
-             f"got {sorted(modes)}")
+    _require(modes == {"parallel"}, where,
+             f"rows must cover the parallel trainer, got {sorted(modes)}")
     for i, r in enumerate(payload["rows"]):
         w = f"{where}.rows[{i}]"
         _fields(r, {"mode": str, "dataset": str,
@@ -105,33 +77,12 @@ def check_speedup(payload: dict) -> None:
                     "parallel_collective_bytes": numbers.Real,
                     "adjacency_bytes": int}, w)
         _require(r["parallel_per_epoch_s"] > 0, w, "non-positive epoch time")
-    by_key: dict[tuple, dict[str, dict]] = {}
-    for r in payload["rows"]:
-        by_key.setdefault(r["dataset"], {})[r["mode"]] = r
-    for ds, d in by_key.items():
-        w = f"{where}.{ds}"
-        # the p2p step may never compile to MORE collective bytes than the
-        # allgather oracle (equality is legitimate on block-dense M=3
-        # graphs where every community neighbours every other; the strict
-        # win is asserted on the sparse M=32 topology below)
-        _require(d["p2p"]["parallel_collective_bytes"]
-                 <= d["compressed"]["parallel_collective_bytes"], w,
-                 "p2p collective bytes above the allgather transport")
-        _require(d["p2p"]["scheduled_wire_bytes"]
-                 <= d["p2p"]["comm_full_bytes"], w,
+        _require(r["scheduled_wire_bytes"] <= r["comm_full_bytes"], w,
                  "scheduled wire bytes above the all-gather volume")
-        # the multilevel partitioner may never cut more edges than the
-        # BFS+KL stand-in it supersedes (p2p_ml row == p2p row but
-        # partitioned by sharding.multilevel)
-        _require(d["p2p_ml"]["partitioner"] == "multilevel"
-                 and d["p2p"]["partitioner"] == "bfs_kl", w,
-                 "p2p/p2p_ml rows carry the wrong partitioner tag")
-        _require(d["p2p_ml"]["edge_cut"] <= d["p2p"]["edge_cut"], w,
-                 f"multilevel cut {d['p2p_ml']['edge_cut']} above bfs_kl "
-                 f"{d['p2p']['edge_cut']}")
-        for mode in ("p2p", "p2p_ml"):
-            _require(d[mode]["part_balance"] <= 1.0 + 1e-9, w,
-                     f"{mode} partition exceeds the strict balance cap")
+        _require(r["partitioner"] == "multilevel", w,
+                 "row carries the wrong partitioner tag")
+        _require(r["part_balance"] <= 1.0 + 1e-9, w,
+                 "partition exceeds the strict balance cap")
 
     m32 = payload["m32_wire"]
     w = f"{where}.m32_wire"

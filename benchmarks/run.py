@@ -13,8 +13,8 @@ Subset:         ``... -m benchmarks.run --only table3_speedup,roofline``
 CI smoke:       ``... benchmarks/run.py --quick`` — emits the repo-root
 ``BENCH_block_sparsity.json`` / ``BENCH_speedup.json`` / ``BENCH_serving.json``
 quick payloads and validates them with benchmarks/check_bench.py (schema +
-the compressed-vs-dense adjacency, p2p-vs-allgather wire-byte, and serving
-hit-rate/latency regression guards).
+the p2p-vs-allgather wire-byte and serving hit-rate/latency regression
+guards).
 """
 from __future__ import annotations
 

@@ -110,8 +110,7 @@ def run(quick: bool = False) -> dict:
     cfg = gcn.GCNConfig(layer_dims=(16, 32, g.num_classes))
     tr = ParallelADMMTrainer(
         cfg, ADMMConfig(nu=1e-3, rho=1e-3), g, num_parts=M, seed=0,
-        part=part, config=TrainerConfig(transport="p2p", compressed=True,
-                                        pad_mode="bucketed", packed=True))
+        part=part, config=TrainerConfig.packed())
     tr.train(epochs)
     train_acc, test_acc, _ = (float(x) for x in tr._metrics(tr.state))
 

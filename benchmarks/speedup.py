@@ -1,20 +1,17 @@
 """Paper Table 3: Serial ADMM vs Parallel ADMM wall-time / speedup.
 
-Serial = one community, one device.  Parallel = M=3 communities on 3 host
-devices (the paper used 3 agents on one Xeon; host CPU devices are real
-threads, so the speedup mechanism matches), in both the dense-replicated
-and the block-compressed (sharded ELL) adjacency representations; the
-``p2p``/``p2p_ml`` modes run the compressed trainer under the neighbour
-p2p transport with the bfs_kl vs multilevel partitioner respectively
-(rows carry each partition's edge_cut / balance / max_deg).  Each
-configuration runs in a subprocess so the device count can differ (XLA
-locks it at first init).
+Serial = one community, one device.  Parallel = M=3 multilevel
+communities on 3 host devices (the paper used 3 agents on one Xeon; host
+CPU devices are real threads, so the speedup mechanism matches), on the
+trainer's one path (rows carry the partition's edge_cut / balance /
+max_deg).  Each configuration runs in a subprocess so the device count
+can differ (XLA locks it at first init).
 
 The paper reports training/communication time separately; a fused XLA
 program has no such boundary, so alongside wall-time we report the
 *collective byte volume* of the parallel step (the communication the paper
 timed) parsed from the compiled HLO, plus the device-resident adjacency
-bytes each representation holds.
+bytes each trainer holds.
 
 Run: PYTHONPATH=src python benchmarks/speedup.py [--quick] [--out FILE.json]
 Emits machine-readable BENCH_speedup.json next to the repo root.
@@ -61,17 +58,9 @@ WORKER = textwrap.dedent("""
         adjacency_bytes = int(tr.a_tilde.nbytes)
     else:
         from repro.core.parallel import ParallelADMMTrainer, TrainerConfig
-        partitioner = "multilevel" if mode == "p2p_ml" else "bfs_kl"
-        MODES = {
-            "parallel": TrainerConfig.dense(partitioner=partitioner),
-            "compressed": TrainerConfig(compressed=True,
-                                        transport="allgather",
-                                        partitioner=partitioner),
-            "p2p": TrainerConfig.p2p(partitioner=partitioner),
-            "p2p_ml": TrainerConfig.p2p(partitioner=partitioner),
-        }
-        tr = ParallelADMMTrainer(cfg, admm, g, num_parts=3, seed=0,
-                                 config=MODES[mode])
+        tr = ParallelADMMTrainer(
+            cfg, admm, g, num_parts=3, seed=0,
+            config=TrainerConfig(partitioner="multilevel"))
         step = tr.step
         adjacency_bytes = int(tr.data.adjacency_nbytes)
     step(); jax.block_until_ready(tr.state.zs[-1])   # compile
@@ -122,41 +111,40 @@ def run(epochs: int = 20, hidden: int = 256,
     rows = []
     for ds in datasets:
         serial = _run("serial", ds, epochs, hidden)
-        for mode in ("parallel", "compressed", "p2p", "p2p_ml"):
-            parallel = _run(mode, ds, epochs, hidden)
-            speedup = serial["total_s"] / parallel["total_s"]
-            # analytic speedup: per-agent compute ratio from the HLO census —
-            # what the wall clock would show on hardware with ≥M real cores
-            # (this container has ONE core, so threads serialize; the paper's
-            # Xeon had many)
-            flops_ratio = (serial["per_device_flops"]
-                           / max(parallel["per_device_flops"], 1.0))
-            rows.append({
-                "mode": mode,
-                "dataset": ds,
-                "serial_total_s": round(serial["total_s"], 3),
-                "parallel_total_s": round(parallel["total_s"], 3),
-                "serial_per_epoch_s": round(serial["per_epoch_s"], 4),
-                "parallel_per_epoch_s": round(parallel["per_epoch_s"], 4),
-                "speedup": round(speedup, 2),
-                "analytic_compute_speedup": round(flops_ratio, 2),
-                "parallel_collective_bytes": parallel["collective_bytes"],
-                "scheduled_wire_bytes": parallel.get("scheduled_wire_bytes"),
-                "comm_full_bytes": parallel.get("full_bytes"),
-                "partitioner": parallel.get("partitioner"),
-                "edge_cut": parallel.get("edge_cut"),
-                "part_balance": parallel.get("part_balance"),
-                "part_max_deg": parallel.get("part_max_deg"),
-                "adjacency_bytes": parallel["adjacency_bytes"],
-                "serial_adjacency_bytes": serial["adjacency_bytes"],
-                "serial_test_acc": round(serial["test_acc"], 3),
-                "parallel_test_acc": round(parallel["test_acc"], 3),
-            })
-            print(f"[speedup] {ds} ({mode}): serial {serial['total_s']:.2f}s "
-                  f"parallel {parallel['total_s']:.2f}s -> {speedup:.2f}x "
-                  f"wall-clock (1 CPU core), {flops_ratio:.2f}x per-agent "
-                  f"compute, adjacency {parallel['adjacency_bytes']/1e6:.2f} "
-                  f"MB (paper: 3.30x/2.98x on 3 agents)")
+        parallel = _run("parallel", ds, epochs, hidden)
+        speedup = serial["total_s"] / parallel["total_s"]
+        # analytic speedup: per-agent compute ratio from the HLO census —
+        # what the wall clock would show on hardware with ≥M real cores
+        # (this container has ONE core, so threads serialize; the paper's
+        # Xeon had many)
+        flops_ratio = (serial["per_device_flops"]
+                       / max(parallel["per_device_flops"], 1.0))
+        rows.append({
+            "mode": "parallel",
+            "dataset": ds,
+            "serial_total_s": round(serial["total_s"], 3),
+            "parallel_total_s": round(parallel["total_s"], 3),
+            "serial_per_epoch_s": round(serial["per_epoch_s"], 4),
+            "parallel_per_epoch_s": round(parallel["per_epoch_s"], 4),
+            "speedup": round(speedup, 2),
+            "analytic_compute_speedup": round(flops_ratio, 2),
+            "parallel_collective_bytes": parallel["collective_bytes"],
+            "scheduled_wire_bytes": parallel.get("scheduled_wire_bytes"),
+            "comm_full_bytes": parallel.get("full_bytes"),
+            "partitioner": parallel.get("partitioner"),
+            "edge_cut": parallel.get("edge_cut"),
+            "part_balance": parallel.get("part_balance"),
+            "part_max_deg": parallel.get("part_max_deg"),
+            "adjacency_bytes": parallel["adjacency_bytes"],
+            "serial_adjacency_bytes": serial["adjacency_bytes"],
+            "serial_test_acc": round(serial["test_acc"], 3),
+            "parallel_test_acc": round(parallel["test_acc"], 3),
+        })
+        print(f"[speedup] {ds}: serial {serial['total_s']:.2f}s "
+              f"parallel {parallel['total_s']:.2f}s -> {speedup:.2f}x "
+              f"wall-clock (1 CPU core), {flops_ratio:.2f}x per-agent "
+              f"compute, adjacency {parallel['adjacency_bytes']/1e6:.2f} "
+              f"MB (paper: 3.30x/2.98x on 3 agents)")
     return rows
 
 
@@ -507,9 +495,7 @@ FU_WORKER = textwrap.dedent("""
     for name, fused in (("unfused", False), ("fused", True)):
         tr = ParallelADMMTrainer(
             cfg, admm, g, num_parts=m, seed=0, part=part, mesh=mesh,
-            config=TrainerConfig(compressed=True, transport="p2p",
-                                 pad_mode="bucketed", packed=True,
-                                 fused=fused))
+            config=TrainerConfig.packed(fused=fused))
         jx = jax.make_jaxpr(tr._step)(tr.state)
         out[name + "_handoffs"] = len(fused_agg_handoffs(jx,
                                                          tr.layout.n_pad))

@@ -52,8 +52,7 @@ def main():
     cfg = gcn.GCNConfig(layer_dims=(16, 32, g.num_classes))
     tr = ParallelADMMTrainer(
         cfg, ADMMConfig(nu=1e-3, rho=1e-3), g, num_parts=M, seed=0,
-        part=part, config=TrainerConfig(transport="p2p", compressed=True,
-                                        pad_mode="bucketed", packed=True))
+        part=part, config=TrainerConfig.packed())
     print(f"training M={M} community GCN on N={g.num_nodes}...")
     tr.train(3)
     _, test_acc, _ = tr._metrics(tr.state)

@@ -26,14 +26,16 @@ class AnalysisContext:
     expectations — facts about the config under analysis that rules
     check the program against.  Keys used by the built-in rules:
 
-      transport                "p2p" | "allgather"
+      n_shards                 shards of a trainer step: arms the
+                               no-all-gather rule; > 1 arms the
+                               packed-resident-state rule
+      packed_rows_bound        tallest blocked row stack (the r_pad view)
       round_pairs              list of per-round frozensets of (src, dst)
       num_gathers              host-side gathers per trainer step
       collective_budget_bytes  bound on transport payload bytes (census)
       allreduce_max_bytes      bound on any single all-reduce operand
       m_total, lanes, n_pad, max_deg   layout facts for the dense-adjacency
                                pattern matcher
-      dense_adjacency_allowed  True on the dense baseline config
       hbm_intermediate_budget  bound on any single intermediate's bytes
       args_donated             {arg_path: bool} from lowered.args_info
       expect_donated           substrings of arg paths that must be donated

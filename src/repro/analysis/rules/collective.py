@@ -27,14 +27,16 @@ def _collective_instrs(ctx: AnalysisContext,
 
 @rule("collective/no-allgather-under-p2p")
 def no_allgather_under_p2p(ctx: AnalysisContext) -> Iterable[Finding]:
-    """Under ``transport="p2p"`` the compiled step contains no all-gather."""
-    if ctx.hlo_text is None or ctx.expectations.get("transport") != "p2p":
+    """A trainer step (expectations carrying ``n_shards``) contains no
+    all-gather: many shards exchange rows by p2p ppermute, and one shard
+    exchanges nothing."""
+    if ctx.hlo_text is None or "n_shards" not in ctx.expectations:
         return
     hits = list(_collective_instrs(ctx, "all-gather"))
     if hits:
         yield Finding(
             "collective/no-allgather-under-p2p", Severity.ERROR,
-            f"{len(hits)} all-gather op(s) compiled under p2p transport "
+            f"{len(hits)} all-gather op(s) compiled in a trainer step "
             f"(first: %{hits[0][1].name} in {hits[0][0].name})",
             location=hits[0][1].name,
             details={"count": len(hits),

@@ -23,8 +23,6 @@ def no_dense_adjacency(ctx: AnalysisContext) -> Iterable[Finding]:
     n_pad = exp.get("n_pad")
     if ctx.hlo_text is None or not n_pad:
         return
-    if exp.get("dense_adjacency_allowed"):
-        return
     lanes = exp.get("lanes", 1)
     m_total = exp.get("m_total", 1)
     max_deg = exp.get("max_deg", m_total)
@@ -56,8 +54,8 @@ def no_dense_adjacency(ctx: AnalysisContext) -> Iterable[Finding]:
 
 @rule("memory/packed-resident-state")
 def packed_resident_state(ctx: AnalysisContext) -> Iterable[Finding]:
-    """Under packed state (``ParallelADMMTrainer(packed=True)`` on a
-    multi-shard p2p mesh) the per-shard program never materialises a
+    """On a multi-shard mesh (``n_shards`` > 1: the packed plane feeds
+    the p2p wire) the per-shard program never materialises a
     blocked row stack taller than the receive buffer: any computed
     (rows, n_pad, C) intermediate with ``rows > r_pad`` is a strided
     (M, n_pad, C)-shaped payload sneaking back in — exactly what the
@@ -66,7 +64,7 @@ def packed_resident_state(ctx: AnalysisContext) -> Iterable[Finding]:
     n_pad = exp.get("n_pad")
     if ctx.hlo_text is None or not n_pad:
         return
-    if not exp.get("state_packed"):
+    if exp.get("n_shards", 1) <= 1:
         return
     bound = int(exp.get("packed_rows_bound", 0))
     if bound <= 0:

@@ -568,9 +568,8 @@ def bf16_wire(collective: Callable[[Array], Array],
     """Run ``collective`` on a bf16-compressed payload (half the wire
     bytes) and restore the operand dtype.  The bf16 value travels bitcast
     as uint16 — a plain convert would be hoisted back to f32 by XLA's
-    convert-mover, silently undoing the compression (§Perf log).  Both
-    transports (all-gather and the p2p rounds) share this wrapper so the
-    compression trick can only evolve in one place.
+    convert-mover, silently undoing the compression (§Perf log).  Each p2p
+    round's payload goes through this wrapper.
     """
     dt = payload.dtype
     if dt != jnp.float32:
@@ -579,49 +578,6 @@ def bf16_wire(collective: Callable[[Array], Array],
         payload.astype(jnp.bfloat16), jnp.uint16)
     wire = collective(wire)
     return jax.lax.bitcast_convert_type(wire, jnp.bfloat16).astype(dt)
-
-
-def exchange_neighbors(plan: NeighborExchange, x_loc: Array, axis: str,
-                       comm_bf16: bool = False) -> Array:
-    """Run the plan inside ``shard_map``: (k, n, C) local -> (r_pad, n, C).
-
-    The returned buffer holds exactly the payload rows this shard's
-    subproblems read (own lanes placed locally, neighbour rows arriving via
-    the scheduled ``ppermute`` rounds) — the consumers index it through the
-    ``localize_indices`` slot mapping.  With ``comm_bf16`` each round's
-    payload travels bf16 (``bf16_wire``).  Note: only rows that actually
-    cross the wire are compressed — a shard's own resident rows stay at
-    full precision (strictly better numerics than the all-gather
-    transport, which roundtrips every row; the transports are therefore
-    bit-comparable oracles only at f32).
-    """
-    if plan.n_shards == 1:
-        # the single shard hosts every community: slots are the identity
-        # permutation and nothing hits the wire — returning the local
-        # payload keeps the program bit-identical to the all-gather path
-        return x_loc
-    sid = jax.lax.axis_index(axis)
-    dt = x_loc.dtype
-    k, n = x_loc.shape[0], x_loc.shape[1]
-    feat = x_loc.shape[2:]
-    # node-row-flat views: send rows are gathered (and receive rows
-    # scattered) at single-node granularity so row-exact plans wire only
-    # the true rows of each community
-    x_flat = x_loc.reshape((k * n,) + feat)
-    buf = jnp.zeros((plan.r_pad * n,) + feat, dt)
-    own = jnp.asarray(plan.own_slots)[sid]                    # (k,)
-    own_flat = (own[:, None] * n + jnp.arange(n)[None, :]).reshape(-1)
-    buf = buf.at[own_flat].set(x_flat)
-    with jax.named_scope(EXCHANGE_SCOPE):
-        for rnd in plan.rounds:
-            payload = x_flat[jnp.asarray(rnd.send_idx)[sid]]
-            permute = partial(jax.lax.ppermute, axis_name=axis,
-                              perm=list(rnd.pairs))
-            payload = bf16_wire(permute, payload) if comm_bf16 \
-                else permute(payload)
-            buf = buf.at[jnp.asarray(rnd.recv_slot)[sid]].set(payload,
-                                                              mode="drop")
-    return buf.reshape((plan.r_pad, n) + feat)
 
 
 def exchange_neighbors_packed(plan: NeighborExchange, x_plane: Array,
@@ -633,8 +589,8 @@ def exchange_neighbors_packed(plan: NeighborExchange, x_plane: Array,
     state (``PackedDeviceLayout``).  Returns the packed receive plane
     ``(recv_plane_rows, C)``: slot j's bucket rows live at
     ``recv_offsets[s, j]``, own lanes copied locally, neighbour rows
-    arriving through the same ppermute rounds (same pairs, same payload
-    rows — byte-identical wire) as the strided ``exchange_neighbors``.
+    arriving through the plan's ppermute rounds, each round moving the
+    true rows of its messages.
 
     With ``staged=True`` the *incremental* buffer states are returned as
     a list — ``[after own-copy, after round 0, ..., final]`` — so a

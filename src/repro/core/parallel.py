@@ -21,86 +21,54 @@ the relayed aggregates, see messages.py.  Z_0 is static input — the
 trainer aggregates Ã·Z_0 once at construction and every consumer reads it
 (layer-1 input, the 1-layer dual refresh, the eval programs), so it is not
 exchanged at all, but on the fused packed wire, whose one-pass Ã(Z_0 W)
-Z sites gather it once per iteration.  Two transports (``transport`` flag):
+Z sites gather it once per iteration.
 
-  * allgather — ``lax.all_gather`` moves every shard's payload to every
-    shard, then masks to the neighbour rows.  The only transport the dense
-    adjacency supports (its Z-coupling reads all M rows), and the parity
-    oracle for p2p.
-  * p2p (default for ``compressed=True``) — neighbour-only exchange over a
-    static round schedule (messages.NeighborExchange): the community
-    topology is lifted to shard-to-shard edges (per-shard union of the ELL
-    neighbour indices, graph.shard_neighbor_graph), messages are coloured
-    into ``lax.ppermute`` rounds by ring offset (sharding.partition.
-    ring_round_coloring — each round is a partial permutation, inactive
-    offsets are skipped), and each round moves a padded
-    ``(rows_pad, n_pad, C)`` send buffer.  Every shard receives only the
-    lane-major ``(r_pad, n_pad, C)`` buffer of rows its subproblems
-    actually read — no ``(M, n_pad, C)`` gathered tensor is materialised —
-    and the ELL indices are remapped host-side to receive-buffer slots.
-    ``comm_stats`` records the scheduled ``wire_bytes`` ==
-    true rows + round padding ≤ the all-gather ``full_bytes``, with the
-    true rows bounded by the mask-derived ``needed_bytes`` (verified at
-    construction by messages.verify_transport_bytes; with one community
-    per shard the bound holds padding-included and the CI benchmark
-    guards assert it strictly).
+There is one design, and ``SerialADMMTrainer`` (serial.py) is its
+oracle:
 
-Adjacency representations (``compressed`` flag):
-
-  * dense — every shard holds its k rows of the (M, M, n_pad, n_pad) block
-    tensor: O(k·M·n_pad²) bytes per shard, and the Z-update coupling term
-    sums over all M communities (masked): O(M·n_pad²·C) FLOPs per lane.
-  * compressed — each shard holds only its lanes' ELL rows,
+  * Adjacency — each shard holds only its lanes' ELL rows,
     (k, max_deg, n_pad, n_pad) blocks + (k, max_deg) indices/mask
     (graph.BlockCSR): O(k·max_deg·n_pad²) bytes per shard, no dense block
-    tensor anywhere on device.  Aggregations run through the lane-aware ELL
-    kernel (kernels.community_spmm_ell) and the coupling term is its
-    transposed-block form over the max_deg neighbours only:
-    O(max_deg·n_pad²·C) FLOPs per lane.  On power-law community graphs
-    max_deg is ~constant in M, so per-shard memory and Z-coupling FLOPs
-    stop scaling with the community count — the regime where M can grow
-    past what a dense replicated layout fits on device.
-
-Padding (``pad_mode`` flag, default "bucketed"): packed tensors keep the
-fixed (M, n_pad, ...) stride, but under the bucketed scheme every
-community is *logically* padded only to its power-of-two-ish size bucket
-(graph.bucket_pad_sizes) — the ELL kernel's scalar-prefetched row counts
-guard the pad rows out of the DMA+accumulate, the p2p transport wires
-row-exact payloads (a wired community contributes its true rows, not an
-n_pad block), and ``comm_stats`` reports the residual padding as
-``pad_rows``/``pad_bytes``/``pad_flops``/``pad_flop_frac``
-(messages.pad_stats).  "global" restores the historic
-everything-pads-to-the-max behaviour; the iterates are identical either
-way (pad rows are zero throughout), only processed/wired volume changes.
-``adjacency_bf16=True`` (compressed only) additionally stores the ELL
-block plane bf16 — half the resident adjacency bytes, f32 accumulation.
-
-Packed device state (``packed`` flag, requires compressed + p2p): the
-resident trainer state drops the (M, n_pad, …) stride entirely.  Z/U and
-the static z0/labels/masks live as Σ-bucket-rows planes — each shard
-holds a ``(plane_rows, C)`` plane of its lanes' bucket rows back to back
-(graph.PackedDeviceLayout), so resident state bytes track the bucketed
-community sizes, not M × the largest community.  The exchange runs on
-the packed plane (messages.exchange_neighbors_packed — same ppermute
-rounds, byte-identical wire) into a packed receive plane, and the ELL
-aggregation reads it through scalar-prefetched row offsets
-(kernels community_spmm_ell_packed / NeighborExchange.localized_offsets)
-instead of an n_pad stride.  Subproblem math runs on blocked per-lane
-views rebuilt with static take-with-fill tables — pad rows are zero
-throughout (the zero-outside-counts contract), so packed iterates are
-*bitwise* equal to the strided path's.  ``overlap=True`` (packed only)
-additionally splits each exchange into its round-indexed buffer stages
-and aggregates each arrival group as soon as its rounds are in
-(double-buffering wire behind compute; the sum association changes, so
-overlap parity is tolerance- rather than bit-level), and ``comm_stats``
-gains an analytic overlap-efficiency metric (messages.overlap_stats)
-the roofline prices exposed wire with.
+    tensor anywhere on device.  Aggregations run through the lane-aware
+    ELL kernel (kernels.community_spmm_ell) and the Z-coupling term is its
+    transposed-block form over the max_deg neighbours only.
+    ``adjacency_bf16=True`` stores the ELL block plane bf16 — half the
+    resident adjacency bytes, f32 accumulation.
+  * Padding — every community is padded only to its power-of-two-ish
+    size bucket (graph.bucket_pad_sizes): the ELL kernel's
+    scalar-prefetched row counts guard the pad rows out of the
+    DMA+accumulate, the exchange wires row-exact payloads, and
+    ``comm_stats`` reports the residual padding as
+    ``pad_rows``/``pad_bytes``/``pad_flops``/``pad_flop_frac``
+    (messages.pad_stats).
+  * State — Z/U and the static z0/labels/masks live as Σ-bucket-rows
+    planes: each shard holds a ``(plane_rows, C)`` plane of its lanes'
+    bucket rows back to back (graph.PackedDeviceLayout), so resident
+    state bytes track the bucketed community sizes, not M × the largest
+    community.  Subproblem math runs on blocked per-lane views rebuilt
+    with static take-with-fill tables — pad rows are zero throughout (the
+    zero-outside-counts contract), so the relocation changes no value.
+  * Exchange — on many shards, neighbour-only ``lax.ppermute`` rounds
+    over a static schedule (messages.NeighborExchange): the community
+    topology is lifted to shard-to-shard edges (graph.shard_neighbor_graph),
+    edge-coloured into rounds (sharding.partition.ring_round_coloring),
+    and run on the packed plane (messages.exchange_neighbors_packed) into
+    a packed receive plane that the ELL aggregation reads through
+    scalar-prefetched row offsets (kernels community_spmm_ell_packed /
+    NeighborExchange.localized_offsets).  ``comm_stats`` records the
+    scheduled ``wire_bytes`` == true rows + round padding, verified at
+    construction by messages.verify_transport_bytes.  On one shard every
+    community is local: nothing crosses a wire and the step reads the
+    shard's own blocked rows.  ``overlap=True`` splits each exchange into
+    its round-indexed buffer stages and aggregates each arrival group as
+    soon as its rounds are in (the sum association changes, so overlap
+    parity is tolerance- rather than bit-level), and ``comm_stats`` gains
+    an analytic overlap-efficiency metric (messages.overlap_stats).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-import warnings
 from functools import partial
 from typing import TYPE_CHECKING, NamedTuple
 
@@ -148,10 +116,9 @@ def place_on_mesh(mesh: Mesh, tree, spec):
 
 
 class ParallelState(NamedTuple):
-    """Trainer iterates.  Strided layout: zs[l] is (M, n_pad, C_l) and u
-    (M, n_pad, C_L), sharded over comm.  Packed layout: zs[l] is the
-    (n_shards · plane_rows, C_l) Σ-bucket-rows plane (u likewise) —
-    shard s's slice holds its lanes' bucket rows back to back."""
+    """Trainer iterates.  zs[l] is the (n_shards · plane_rows, C_l)
+    Σ-bucket-rows plane (u likewise), sharded over comm — shard s's slice
+    holds its lanes' bucket rows back to back."""
     weights: tuple[Array, ...]   # replicated
     zs: tuple[Array, ...]        # sharded over comm
     u: Array                     # sharded
@@ -168,113 +135,76 @@ class ParallelState(NamedTuple):
 class CommunityData:
     """Device-ready community-blocked graph tensors.
 
-    Exactly one adjacency representation is resident: dense mode holds
-    ``a_blocks`` (M, M, n_pad, n_pad); compressed mode holds only the ELL
-    view ``ell_blocks``/``ell_indices``/``ell_mask`` (graph.BlockCSR,
-    O(nnz·n_pad²) bytes) and ``a_blocks`` is None — the shard_map trainer
-    aggregates straight from the sharded ELL rows.  With
-    ``adjacency_bf16=True`` (compressed only) the ELL block store is kept
-    bf16 on device — half the resident adjacency bytes — and every
-    aggregation accumulates in f32 (the kernel's scratch / the oracle's
-    explicit upcast).
+    The adjacency is the ELL view ``ell_blocks``/``ell_indices``/
+    ``ell_mask`` (graph.BlockCSR, O(nnz·n_pad²) bytes); the shard_map
+    trainer aggregates straight from the sharded ELL rows.  With
+    ``adjacency_bf16=True`` the ELL block store is kept bf16 on device —
+    half the resident adjacency bytes — and every aggregation accumulates
+    in f32 (the kernel's scratch / the oracle's explicit upcast).
 
     ``row_counts``/``nbr_counts`` carry the ragged (bucketed) per-lane and
     per-neighbour padded row counts the ELL kernel's pad-row guards key
-    off; ``row_mask`` masks packed (M, n_pad) tensors down to true rows
-    (metrics / Lagrangian).  Under the global pad scheme the counts are
-    simply n_pad everywhere.
+    off; ``row_mask`` masks blocked (M, n_pad) tensors down to true rows
+    (metrics / Lagrangian).
 
-    With ``packed_layout`` set (graph.PackedDeviceLayout), z0 / labels /
-    train_mask / test_mask are stored as Σ-bucket-rows planes —
-    (n_shards · plane_rows, …) instead of (M, n_pad, …) — matching the
-    packed trainer state; ``row_mask`` stays blocked (it only feeds the
-    host-jit metrics, which unpack the planes anyway).
+    z0 / labels / train_mask / test_mask are stored as Σ-bucket-rows
+    planes of ``packed_layout`` (graph.PackedDeviceLayout) —
+    (n_shards · plane_rows, …) — matching the trainer state; ``row_mask``
+    stays blocked (it only feeds the host-jit metrics, which unpack the
+    planes anyway).
     """
-    a_blocks: "Array | None"   # (M, M, n_pad, n_pad) — dense mode only
-    z0: Array            # (M, n_pad, C0) | packed (total_rows, C0)
-    labels: Array        # (M, n_pad) int32 | packed (total_rows,)
-    train_mask: Array    # (M, n_pad) f32 | packed (total_rows,)
-    test_mask: Array     # (M, n_pad) f32 | packed (total_rows,)
-    neighbor_mask: Array  # (M, M) bool
+    z0: Array            # (total_rows, C0)
+    labels: Array        # (total_rows,) int32
+    train_mask: Array    # (total_rows,) f32
+    test_mask: Array     # (total_rows,) f32
     denom: Array         # scalar — global labeled-node count
     row_mask: Array       # (M, n_pad) float32 — 1 = true node row
-    # block-compressed Ã (ELL view) — compressed mode only
-    ell_blocks: "Array | None" = None    # (M, max_deg, n_pad, n_pad)
-    ell_indices: "Array | None" = None   # (M, max_deg) int32
-    ell_mask: "Array | None" = None      # (M, max_deg) float32
-    row_counts: "Array | None" = None    # (M,) int32
-    nbr_counts: "Array | None" = None    # (M, max_deg) int32
-    packed_layout: "graph.PackedDeviceLayout | None" = None
-
-    @property
-    def compressed(self) -> bool:
-        return self.a_blocks is None
-
-    @property
-    def packed(self) -> bool:
-        return self.packed_layout is not None
+    ell_blocks: Array     # (M, max_deg, n_pad, n_pad)
+    ell_indices: Array    # (M, max_deg) int32
+    ell_mask: Array       # (M, max_deg) float32
+    row_counts: Array     # (M,) int32
+    nbr_counts: Array     # (M, max_deg) int32
+    packed_layout: graph.PackedDeviceLayout
 
     @property
     def adjacency_bf16(self) -> bool:
-        return (self.ell_blocks is not None
-                and self.ell_blocks.dtype == jnp.bfloat16)
+        return self.ell_blocks.dtype == jnp.bfloat16
 
     @property
     def num_parts(self) -> int:
-        if self.packed_layout is not None:
-            return self.packed_layout.num_parts
-        return int(self.z0.shape[0])
+        return self.packed_layout.num_parts
 
     @property
     def adjacency_nbytes(self) -> int:
-        """Device-resident adjacency bytes of this representation."""
-        if self.compressed:
-            return (self.ell_blocks.nbytes + self.ell_indices.nbytes
-                    + self.ell_mask.nbytes)
-        return self.a_blocks.nbytes
+        """Device-resident bytes of the ELL adjacency store."""
+        return (self.ell_blocks.nbytes + self.ell_indices.nbytes
+                + self.ell_mask.nbytes)
 
 
 def community_data(g: graph.Graph, layout: graph.CommunityLayout,
-                   compressed: bool = False,
-                   adjacency_bf16: bool = False,
-                   device_layout: "graph.PackedDeviceLayout | None" = None
-                   ) -> CommunityData:
-    if adjacency_bf16 and not compressed:
-        raise ValueError("adjacency_bf16=True requires compressed=True — "
-                         "only the ELL block store has a bf16 path")
-    if device_layout is not None and not compressed:
-        raise ValueError("packed device state requires compressed=True — "
-                         "the dense block tensor keeps the n_pad stride")
-    if compressed:
-        csr = layout.compress()
-        rows, nbrs = csr.ell_row_counts()
-        block_dt = jnp.bfloat16 if adjacency_bf16 else jnp.float32
-        adj = {"a_blocks": None,
-               "ell_blocks": jnp.asarray(csr.ell_blocks, dtype=block_dt),
-               "ell_indices": jnp.asarray(csr.ell_indices),
-               "ell_mask": jnp.asarray(csr.ell_mask),
-               "row_counts": jnp.asarray(rows),
-               "nbr_counts": jnp.asarray(nbrs)}
-    else:
-        adj = {"a_blocks": jnp.asarray(layout.a_blocks)}
-    if device_layout is not None:
-        # Σ-bucket-rows planes: pad rows outside the bucket counts are
-        # zero by the layout contract, so pack is lossless
-        def dev(x):
-            return np.asarray(device_layout.pack_state(layout.pack(x)))
-    else:
-        def dev(x):
-            return layout.pack(x)
+                   device_layout: graph.PackedDeviceLayout,
+                   adjacency_bf16: bool = False) -> CommunityData:
+    csr = layout.compress()
+    rows, nbrs = csr.ell_row_counts()
+    block_dt = jnp.bfloat16 if adjacency_bf16 else jnp.float32
+
+    # Σ-bucket-rows planes: pad rows outside the bucket counts are zero by
+    # the layout contract, so pack is lossless
+    def dev(x):
+        return jnp.asarray(device_layout.pack_state(layout.pack(x)))
     return CommunityData(
-        z0=jnp.asarray(dev(g.features)),
-        labels=jnp.asarray(dev(g.labels.astype(np.int32))),
-        train_mask=jnp.asarray(dev(g.train_mask.astype(np.float32))),
-        test_mask=jnp.asarray(dev(g.test_mask.astype(np.float32))),
-        neighbor_mask=jnp.asarray(layout.neighbor_mask),
+        z0=dev(g.features),
+        labels=dev(g.labels.astype(np.int32)),
+        train_mask=dev(g.train_mask.astype(np.float32)),
+        test_mask=dev(g.test_mask.astype(np.float32)),
         denom=jnp.asarray(float(g.train_mask.sum())),
         row_mask=jnp.asarray(layout.node_mask.astype(np.float32)),
+        ell_blocks=jnp.asarray(csr.ell_blocks, dtype=block_dt),
+        ell_indices=jnp.asarray(csr.ell_indices),
+        ell_mask=jnp.asarray(csr.ell_mask),
+        row_counts=jnp.asarray(rows),
+        nbr_counts=jnp.asarray(nbrs),
         packed_layout=device_layout,
-        **adj,
     )
 
 
@@ -287,7 +217,7 @@ def gathered_widths(layer_dims, fused: bool = False) -> list[int]:
     for L >= 2 the relay q per hidden layer, U, and the dual's refresh
     Z_{L-1}⁺.  A 1-layer net has no hidden Z loop (no q, no U gather).
     Z_0 crosses the wire only on the ``fused`` packed wire, once, for the
-    one-pass Ã(Z_0 W) of its Z sites; every other mode reads the
+    one-pass Ã(Z_0 W) of its Z sites; every other step reads the
     construction-time Ã·Z_0 (``ParallelADMMTrainer``'s ``ax``)."""
     dims = list(layer_dims)
     cs = ([dims[0]] if fused else []) + dims[1:]
@@ -330,19 +260,10 @@ def narrow_first_agg(agg, widths: list, seeds=()):
 
 @dataclasses.dataclass(frozen=True)
 class TrainerConfig:
-    """Every mode flag of ``ParallelADMMTrainer``, validated in one place.
-
-    The flags form a dependency ladder the trainer's subsystems rely on —
-    packed planes only route through ELL offsets, the row-exact exchange
-    only feeds packed planes, sampling only restricts a p2p round
-    schedule — and ``__post_init__`` enforces the whole ladder with the
-    same messages the trainer's historic inline checks raised, so every
-    construction path (presets, CLI, benchmarks, the deprecation shim)
-    fails identically.  ``transport=None`` resolves here exactly as the
-    trainer historically did: p2p when compressed, the all-gather oracle
-    otherwise.  ``partitioner=None`` stays None — its resolution depends
-    on whether a precomputed partition is supplied, which only the
-    trainer knows.
+    """The options of ``ParallelADMMTrainer``'s one path, validated in one
+    place.  ``partitioner=None`` stays None — its resolution depends on
+    whether a precomputed partition is supplied, which only the trainer
+    knows.
 
     Minibatching (``batch_fraction`` not None) engages stochastic
     community sampling: each ADMM round runs the W/Z/U sweep on a seeded
@@ -350,27 +271,26 @@ class TrainerConfig:
     unsampled communities' consensus terms carried at their stale
     iterates under a ``stale_decay``-damped penalty
     (subproblems.stale_weights).  ``batch_fraction=1.0`` samples every
-    shard every round and is bitwise-identical to the full-batch packed
+    shard every round and is bitwise-identical to the full-batch
     trainer; ``None`` (the default) builds no sampling machinery at all.
     Sampling composes with ``overlap=True``: each compiled batch derives
     its arrival-group schedule from its own restricted sub-plan.
 
-    ``fused=True`` (requires ``packed``) routes the Z-update sites —
-    target/relay/dual aggregation followed by a GEMM — through the fused
-    aggregation→GEMM path (kernels.ops.community_spmm_ell_fused): the
-    aggregated (k, n_pad, C) stack stays in VMEM scratch (TPU) or is
-    reassociated away (oracle), never materialised in HBM.  The W-update
-    keeps the raw aggregate (its line search re-evaluates the GEMM under
-    a varying W — fusing there would repeat the whole aggregation per
-    backtracking probe).  Inert on 1-shard meshes (no packed wire), where
-    the program is bitwise the unfused one; multi-shard fused-vs-unfused
-    parity is dot-reassociation tolerance.
+    ``fused=True`` routes the Z-update sites — target/relay/dual
+    aggregation followed by a GEMM — through the fused aggregation→GEMM
+    path (kernels.ops.community_spmm_ell_fused): the aggregated
+    (k, n_pad, C) stack stays in VMEM scratch (TPU) or is reassociated
+    away (oracle), never materialised in HBM.  The W-update keeps the raw
+    aggregate (its line search re-evaluates the GEMM under a varying W —
+    fusing there would repeat the whole aggregation per backtracking
+    probe).  ``fused``, ``overlap`` and ``comm_bf16`` act on the
+    exchange's wire and receive plane, so all three do nothing on a
+    1-shard mesh, where nothing crosses a wire and the program is bitwise
+    the plain one (``comm_bf16`` there no longer rounds the shard's own
+    rows to bf16); multi-shard fused-vs-unfused parity is
+    dot-reassociation tolerance.
     """
-    compressed: bool = False
-    transport: "str | None" = None
     partitioner: "str | None" = None
-    pad_mode: str = "bucketed"
-    packed: bool = False
     overlap: bool = False
     fused: bool = False
     comm_bf16: bool = False
@@ -381,45 +301,10 @@ class TrainerConfig:
     sample_seed: int = 0
 
     def __post_init__(self):
-        transport = self.transport
-        if transport is None:
-            transport = "p2p" if self.compressed else "allgather"
-            object.__setattr__(self, "transport", transport)
-        if transport not in ("p2p", "allgather"):
-            raise ValueError(f"unknown transport {transport!r}; "
-                             f"expected 'p2p' or 'allgather'")
-        if transport == "p2p" and not self.compressed:
-            raise ValueError("transport='p2p' requires compressed=True — "
-                             "the dense Z-coupling reads all M payload rows")
-        if self.packed and not self.compressed:
-            raise ValueError("packed=True requires compressed=True — the "
-                             "packed plane is only routed through ELL "
-                             "offsets, never a dense Z-coupling")
-        if self.packed and transport != "p2p":
-            raise ValueError("packed=True requires transport='p2p' — the "
-                             "plane layout exists to feed the row-exact "
-                             "exchange; an all-gather would re-materialise "
-                             "the strided (M, n_pad, C) payload")
-        if self.overlap and not self.packed:
-            raise ValueError("overlap=True requires packed=True — the "
-                             "staged exchange snapshots are packed planes")
-        if self.fused and not self.packed:
-            raise ValueError("fused=True requires packed=True — the fused "
-                             "aggregation→GEMM kernel reads the packed "
-                             "receive plane through ELL offsets")
-        if self.pad_mode not in ("global", "bucketed"):
-            raise ValueError(f"unknown pad_mode {self.pad_mode!r}; "
-                             f"expected 'global' or 'bucketed'")
-        if self.adjacency_bf16 and not self.compressed:
-            raise ValueError("adjacency_bf16=True requires compressed=True")
-        if self.batch_fraction is not None:
-            if not 0.0 < self.batch_fraction <= 1.0:
-                raise ValueError(f"batch_fraction must be in (0, 1], got "
-                                 f"{self.batch_fraction!r}")
-            if not self.packed:
-                raise ValueError("batch_fraction requires packed=True — "
-                                 "the sampled sweep runs on the sampled "
-                                 "shards' packed planes")
+        if self.batch_fraction is not None \
+                and not 0.0 < self.batch_fraction <= 1.0:
+            raise ValueError(f"batch_fraction must be in (0, 1], got "
+                             f"{self.batch_fraction!r}")
         if not 0.0 < self.stale_decay <= 1.0:
             raise ValueError(f"stale_decay must be in (0, 1], got "
                              f"{self.stale_decay!r}")
@@ -435,50 +320,18 @@ class TrainerConfig:
                 kw[f.name] = getattr(args, f.name)
         return cls(**kw)
 
+    @classmethod
+    def packed(cls, **kw) -> "TrainerConfig":
+        """The one path — packed Σ-bucket-rows state, ELL adjacency,
+        bucketed padding, row-exact p2p exchange — with ``kw`` set; the
+        same as ``TrainerConfig(**kw)``."""
+        return cls(**kw)
 
-# named presets — attached after the class body because ``packed`` is
-# both a field and a constructor name (a def inside the class body would
-# shadow the dataclass field's default)
-def _preset_dense(cls, **kw) -> TrainerConfig:
-    """The dense-adjacency all-gather baseline."""
-    kw.setdefault("compressed", False)
-    return cls(**kw)
-
-
-def _preset_p2p(cls, **kw) -> TrainerConfig:
-    """Block-compressed adjacency over the neighbour-only p2p transport."""
-    kw.setdefault("compressed", True)
-    kw.setdefault("transport", "p2p")
-    return cls(**kw)
-
-
-def _preset_packed(cls, **kw) -> TrainerConfig:
-    """Packed Σ-bucket-rows resident state over row-exact p2p."""
-    kw.setdefault("compressed", True)
-    kw.setdefault("transport", "p2p")
-    kw.setdefault("packed", True)
-    return cls(**kw)
-
-
-def _preset_minibatch(cls, batch_fraction: float = 0.25,
-                      **kw) -> TrainerConfig:
-    """Stochastic community minibatching on the packed trainer."""
-    kw.setdefault("compressed", True)
-    kw.setdefault("transport", "p2p")
-    kw.setdefault("packed", True)
-    kw.setdefault("batch_fraction", batch_fraction)
-    return cls(**kw)
-
-
-TrainerConfig.dense = classmethod(_preset_dense)
-TrainerConfig.p2p = classmethod(_preset_p2p)
-TrainerConfig.packed = classmethod(_preset_packed)
-TrainerConfig.minibatch = classmethod(_preset_minibatch)
-
-# the historic flag kwargs the deprecation shim still accepts
-_LEGACY_FLAGS = ("use_kernel", "comm_bf16", "compressed", "transport",
-                 "partitioner", "pad_mode", "adjacency_bf16", "packed",
-                 "overlap")
+    @classmethod
+    def minibatch(cls, batch_fraction: float = 0.25,
+                  **kw) -> "TrainerConfig":
+        """Stochastic community minibatching."""
+        return cls(batch_fraction=batch_fraction, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -633,16 +486,15 @@ def fista_lanes(admm: ADMMConfig, b, u, labels, mask, z_init, denom):
 # ---------------------------------------------------------------------------
 
 def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
-                    comm_bf16: bool, compressed: bool,
+                    comm_bf16: bool,
                     plan: "messages.NeighborExchange | None",
-                    overlap: bool, fused: bool,
-                    packed_aux: "dict | None",
+                    overlap: bool, fused: bool, packed_aux: dict,
                     mb_aux: "dict | None",
                     agg_widths: list,
-                    adj, nbr_row, z0_loc, ax_loc, labels_loc, mask_loc,
+                    adj, z0_loc, ax_loc, labels_loc, mask_loc,
                     denom, ws, zs_loc, u_loc, taus, thetas, probes,
                     nbr_decay=None):
-    """Shapes per shard: nbr_row (k,M); z*_loc (k,n,C); thetas[l] (k,);
+    """Shapes per shard: z*_loc (k,n,C) blocked views; thetas[l] (k,);
     probes (1, len(probe_columns(L))), to which the round adds the
     ``probe_count`` of every line search it ran, summed and per search.
 
@@ -651,7 +503,7 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
     every site that aggregates the layer-1 input (the W_1 search, the
     layer-1 Z target, and for L = 1 FISTA's B and the dual) reads it, and
     no round aggregates or gathers Z_0.  The one exception is the fused
-    packed wire (``fused`` with a plan), whose Z sites keep their one-pass
+    wire (``fused`` with a plan), whose Z sites keep their one-pass
     Ã(Z_0 W) and so gather Z_0 once a round; its W_1 search reads
     ``ax_loc`` too.
 
@@ -669,54 +521,47 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
     ``l1`` … ``lL``, inside ``admm_z`` each hidden layer's under ``l1`` …
     ``l{L-1}`` (``admm_w/l2``, ``admm_z/l1`` in the op's path).
 
-    ``adj`` is the shard's adjacency rows — dense mode: a_row (k,M,n,n);
-    compressed mode: (ell_rows (k,max_deg,n,n), ell_idx (k,max_deg),
-    ell_msk (k,max_deg), ell_rcnt (k,), ell_ncnt (k,max_deg)) with the
-    ragged row counts feeding the ELL kernel's pad-row guards.  ``plan``
-    selects the transport: None means
-    all-gather (ell_idx holds *global* community ids into the gathered
-    (M,n,C) payload); a NeighborExchange means neighbour-only ppermute
-    rounds (ell_idx is pre-remapped to slots of the (r_pad,n,C) receive
-    buffer, and no (M,n,C) tensor exists in this body).
+    ``adj`` is the shard's ELL rows (ell_rows (k,max_deg,n,n), ell_idx
+    (k,max_deg), ell_msk (k,max_deg), ell_rcnt (k,), ell_ncnt
+    (k,max_deg)), the ragged row counts feeding the ELL kernel's pad-row
+    guards.  ``plan`` is the neighbour exchange: None on one shard, which
+    hosts every community (ell_idx holds global community ids into the
+    shard's own (M,n,C) rows, and nothing is exchanged); on many shards a
+    NeighborExchange of ppermute rounds (ell_idx is pre-remapped to slots
+    of the (r_pad,n,C) receive view, and no (M,n,C) tensor exists in this
+    body).
 
-    ``packed_aux`` (packed state mode) is a dict of *static* host tables:
-    z*_loc/u_loc arrive as this shard's Σ-bucket-rows planes, are
-    rebuilt into the blocked views above via take-with-fill (bitwise
-    lossless under the zero-outside-counts contract), and the updated
-    Z/U are re-packed on exit.  With a plan, the exchange itself runs on
-    the packed plane and the ELL aggregation reads the packed receive
-    plane through per-slot row offsets; ``overlap`` further splits the
-    aggregation by arrival round so each group's compute can overlap the
-    later ppermute rounds.
+    ``packed_aux`` is a dict of *static* host tables: z*_loc/u_loc arrive
+    as this shard's Σ-bucket-rows planes, are rebuilt into blocked views
+    via take-with-fill (bitwise lossless under the zero-outside-counts
+    contract), and the updated Z/U are re-packed on exit.  With a plan,
+    the exchange itself runs on the packed plane and the ELL aggregation
+    reads the packed receive plane through per-slot row offsets;
+    ``overlap`` further splits the aggregation by arrival round so each
+    group's compute can overlap the later ppermute rounds.
 
     Every ``gather`` returns an ``(agg, blk)`` pair: ``agg`` feeds
-    ``rowagg`` (the packed plane / its staged snapshots in packed mode)
-    and ``blk`` is the blocked row view every other consumer indexes.
-    Outside packed mode both elements are the same buffer.
+    ``rowagg`` (the receive plane / its staged snapshots on many shards)
+    and ``blk`` is the blocked row view every other consumer indexes.  On
+    one shard both elements are the shard's own blocked rows.
 
-    ``mb_aux`` (stochastic minibatching — requires packed + compressed)
-    carries the *static* per-shard sample mask table of this compiled
-    batch: ``smask[s, j]`` is 1.0 iff shard s's lane j is sampled this
-    round (shard-granular, so a shard's lanes agree).  ``nbr_decay`` is
-    the traced (k, max_deg) staleness weight d_r = stale_decay**age_r of
-    each lane's stored neighbours (subproblems.stale_weights).  The body
-    then (a) masks unsampled lanes' residuals out of the W-update psums,
-    (b) scales every Z-coupling penalty to neighbour r by d_r — √d_r is
-    folded into ``wt`` so the squared residuals carry the full weight,
-    and the last layer's dual term gets the second √d_r explicitly —
-    and (c) applies the Z/θ/U updates through a lane ``where`` so
-    unsampled lanes keep their iterates bit-for-bit.  Every knob is
-    exact-at-identity (mask 1.0, d 1.0 → multiplies by 1.0, selects of
-    the new value), so a full batch reproduces the unsampled program
-    bitwise.
+    ``mb_aux`` (stochastic minibatching) carries the *static* per-shard
+    sample mask table of this compiled batch: ``smask[s, j]`` is 1.0 iff
+    shard s's lane j is sampled this round (shard-granular, so a shard's
+    lanes agree).  ``nbr_decay`` is the traced (k, max_deg) staleness
+    weight d_r = stale_decay**age_r of each lane's stored neighbours
+    (subproblems.stale_weights).  The body then (a) masks unsampled
+    lanes' residuals out of the W-update psums, (b) scales every
+    Z-coupling penalty to neighbour r by d_r — √d_r is folded into ``wt``
+    so the squared residuals carry the full weight, and the last layer's
+    dual term gets the second √d_r explicitly — and (c) applies the
+    Z/θ/U updates through a lane ``where`` so unsampled lanes keep their
+    iterates bit-for-bit.  Every knob is exact-at-identity (mask 1.0,
+    d 1.0 → multiplies by 1.0, selects of the new value), so a full batch
+    reproduces the unsampled program bitwise.
     """
     f = gcn.activation_fn(cfg.activation)
     num_layers = cfg.num_layers
-    m_total = nbr_row.shape[1]
-    nbrf = nbr_row.astype(jnp.float32)           # (k, M) 1/0 neighbour rows
-    # union of this shard's lanes' neighbourhoods: the only communities
-    # whose payload rows any local subproblem reads
-    shard_nbr = jnp.max(nbrf, axis=0)            # (M,)
 
     if mb_aux is not None:
         smask = jnp.asarray(mb_aux["smask"])[jax.lax.axis_index(AXIS)]
@@ -726,35 +571,36 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
     else:
         smask_b = sm = sdr = None
 
-    packed_wire = packed_aux is not None and plan is not None
-    fused_wire = packed_wire and fused
-    if packed_aux is not None:
-        sid0 = jax.lax.axis_index(AXIS)
-        kk, npd = packed_aux["k"], packed_aux["n"]
-        unp_tbl = jnp.asarray(packed_aux["unpack"])[sid0]    # (k·n,)
-        pk_tbl = jnp.asarray(packed_aux["pack"])[sid0]       # (plane_rows,)
+    fused_wire = plan is not None and fused
+    sid0 = jax.lax.axis_index(AXIS)
+    kk, npd = packed_aux["k"], packed_aux["n"]
+    unp_tbl = jnp.asarray(packed_aux["unpack"])[sid0]    # (k·n,)
+    pk_tbl = jnp.asarray(packed_aux["pack"])[sid0]       # (plane_rows,)
 
-        def from_plane(p):
-            flat = jnp.take(p, unp_tbl, axis=0, mode="fill", fill_value=0)
-            return flat.reshape((kk, npd) + p.shape[1:])
+    def from_plane(p):
+        flat = jnp.take(p, unp_tbl, axis=0, mode="fill", fill_value=0)
+        return flat.reshape((kk, npd) + p.shape[1:])
 
-        def to_plane(blk):
-            flat = blk.reshape((kk * npd,) + blk.shape[2:])
-            return jnp.take(flat, pk_tbl, axis=0, mode="fill", fill_value=0)
+    def to_plane(blk):
+        flat = blk.reshape((kk * npd,) + blk.shape[2:])
+        return jnp.take(flat, pk_tbl, axis=0, mode="fill", fill_value=0)
 
-        if fused_wire:
-            z0_loc = from_plane(z0_loc)
-        labels_loc = from_plane(labels_loc)
-        mask_loc = from_plane(mask_loc)
-        zs_loc = tuple(from_plane(z) for z in zs_loc)
-        u_loc = from_plane(u_loc)
+    if fused_wire:
+        z0_loc = from_plane(z0_loc)
+    labels_loc = from_plane(labels_loc)
+    mask_loc = from_plane(mask_loc)
+    zs_loc = tuple(from_plane(z) for z in zs_loc)
+    u_loc = from_plane(u_loc)
 
-    if compressed:
-        ell_rows, ell_idx, ell_msk, ell_rcnt, ell_ncnt = adj
-        ell_f = ell_msk.astype(jnp.float32)      # (k, max_deg)
+    ell_rows, ell_idx, ell_msk, ell_rcnt, ell_ncnt = adj
+    ell_f = ell_msk.astype(jnp.float32)          # (k, max_deg)
+    if use_kernel:
+        from repro.kernels import ops as kops
+
+    if plan is None:
+        # one shard: every community is local, so the blocked rows are
+        # the whole payload and nothing crosses a wire
         if use_kernel:
-            from repro.kernels import ops as kops
-
             def agg_blocked(zh):
                 # scalar-prefetched indices steer the Z-block DMA; padding
                 # slots skip via @pl.when and the row-count guards drop pad
@@ -767,27 +613,16 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
                 return jnp.einsum("kdip,kdpc->kic",
                                   ell_rows.astype(jnp.float32),
                                   zg.astype(jnp.float32))
-    elif use_kernel:
-        a_row = adj
-        from repro.kernels import ops as kops
 
-        def agg_blocked(zh):
-            # per-lane neighbour rows engage the kernel's @pl.when block
-            # skipping: work ∝ nnz blocks, not M²
-            return kops.community_spmm(a_row, zh, nbr_row)
+        def rowagg(x):
+            return agg_blocked(x[0])
+
+        def gather(x_loc):
+            return (x_loc, x_loc)
     else:
-        a_row = adj
-
-        def agg_blocked(zh):             # Σ_{r∈N_m} Ã_{m,r} Z_r per lane
-            return jnp.einsum("kmip,mpc->kic",
-                              a_row * nbrf[:, :, None, None], zh)
-
-    if packed_wire:
         off_lanes = jnp.asarray(packed_aux["offsets"])[sid0]   # (k, D)
         lane_n = jnp.arange(npd)
         if use_kernel:
-            from repro.kernels import ops as kops
-
             def agg_plane(plane, msk):
                 # offset-indexed kernel: the Z DMA reads the packed
                 # receive plane at the scalar-prefetched slot offsets
@@ -823,21 +658,30 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         else:
             def rowagg(x):
                 return agg_plane(x[0], ell_f)
-    else:
-        def rowagg(x):
-            return agg_blocked(x[0])
+
+        ru_tbl = jnp.asarray(packed_aux["recv_unpack"])[sid0]  # (r_pad·n,)
+
+        def gather(x_loc):
+            """pack the blocked local rows onto this shard's plane, run
+            the ppermute schedule on packed row payloads, and rebuild the
+            (r_pad, n, C) blocked view for the row-indexed consumers."""
+            plane = to_plane(x_loc)
+            res = messages.exchange_neighbors_packed(
+                plan, plane, AXIS, comm_bf16=comm_bf16, staged=overlap)
+            buf = res[-1] if overlap else res
+            flat = jnp.take(buf, ru_tbl, axis=0, mode="fill", fill_value=0)
+            blk = flat.reshape((plan.r_pad, npd) + x_loc.shape[2:])
+            return (res, blk)
 
     # ``rowagg_mm(x, w)`` is the aggregation→GEMM composite the Z-update
-    # sites consume.  Unfused it is literally ``rowagg(x) @ w`` (bitwise
-    # the historic program); fused on the packed wire it runs the one-pass
-    # kernel / the reassociated A·(Z·W) oracle, so the aggregated
-    # (k, n, C_in) stack never exists outside VMEM scratch.  Overlap
-    # composes by linearity: (Σ_g agg_g) @ W == Σ_g (agg_g @ W), each
-    # arrival group's fused call depending only on its own stage buffer.
+    # sites consume.  Unfused it is literally ``rowagg(x) @ w``; fused on
+    # the exchange's receive plane it runs the one-pass kernel / the
+    # reassociated A·(Z·W) oracle, so the aggregated (k, n, C_in) stack
+    # never exists outside VMEM scratch.  Overlap composes by linearity:
+    # (Σ_g agg_g) @ W == Σ_g (agg_g @ W), each arrival group's fused call
+    # depending only on its own stage buffer.
     if fused_wire:
         if use_kernel:
-            from repro.kernels import ops as kops
-
             def agg_plane_mm(plane, msk, w):
                 return kops.community_spmm_ell_fused(
                     ell_rows, off_lanes, msk, plane, w, ell_rcnt, ell_ncnt)
@@ -874,50 +718,6 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         return run
 
     rowagg, rowagg_mm = tallied(rowagg), tallied(rowagg_mm)
-
-    if packed_wire:
-        ru_tbl = jnp.asarray(packed_aux["recv_unpack"])[sid0]  # (r_pad·n,)
-
-        def gather(x_loc):
-            """packed p2p: pack the blocked local rows onto this shard's
-            plane, run the ppermute schedule on packed row payloads
-            (byte-identical wire to the strided plan), and rebuild the
-            (r_pad, n, C) blocked view for the row-indexed consumers."""
-            plane = to_plane(x_loc)
-            res = messages.exchange_neighbors_packed(
-                plan, plane, AXIS, comm_bf16=comm_bf16, staged=overlap)
-            buf = res[-1] if overlap else res
-            flat = jnp.take(buf, ru_tbl, axis=0, mode="fill", fill_value=0)
-            blk = flat.reshape((plan.r_pad, npd) + x_loc.shape[2:])
-            return (res, blk)
-    elif plan is not None:
-        def gather(x_loc):
-            """p2p transport: (k, n, C) local -> (r_pad, n, C) neighbour
-            receive buffer via the static ppermute round schedule.  Only
-            the rows this shard's subproblems read ever hit the wire (plus
-            round padding); consumers index the buffer through the
-            pre-localized ELL slots."""
-            buf = messages.exchange_neighbors(plan, x_loc, AXIS,
-                                              comm_bf16=comm_bf16)
-            return (buf, buf)
-    else:
-        def gather(x_loc):
-            """allgather transport: (k, n, C) local -> (M, n, C) global
-            (community-major order), masked down to the rows
-            r ∈ ∪_lanes N_m that this shard's subproblems actually read —
-            the mask documents/verifies the needed volume the p2p transport
-            realizes (``ParallelADMMTrainer.comm_stats``).
-
-            With ``comm_bf16`` the paper's p/s message payloads travel in
-            bf16 (half the collective bytes; §Perf; messages.bf16_wire) and
-            are restored to f32 for the local subproblem math."""
-            dt = x_loc.dtype
-            gather_all = partial(jax.lax.all_gather, axis_name=AXIS)
-            g = messages.bf16_wire(gather_all, x_loc) if comm_bf16 \
-                else gather_all(x_loc)               # (n_shards, k, n, C)
-            g = g.reshape((m_total,) + x_loc.shape[1:])
-            g = g * shard_nbr[:, None, None].astype(dt)
-            return (g, g)
 
     # gathered k-th iterates — one communication round per ADMM iteration.
     # Z_0 is static input, aggregated once at construction (``ax_loc``):
@@ -983,44 +783,27 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
                 # Lane m's ψ only sums r ∈ N_m ∪ {m} — the r ∉ N_m
                 # residuals are constants in z (zero gradient) and drop
                 # from the objective.
-                if compressed:
-                    # neighbour-compressed form: enumerate the max_deg
-                    # stored neighbours only.  Ã_{r,m} = Ã_{m,r}ᵀ (Ã
-                    # symmetric), so the stored row blocks are consumed
-                    # transposed ("kdnp,knc->kdpc") — the gather-transpose
-                    # trick of second_order_from_relay.
-                    # O(max_deg·n_pad²·C) per lane instead of the dense
-                    # O(M·…).
-                    def pre_nbr(z, q_all=q_all, z_ref=z_ref,
-                                w_next=w_next):
-                        delta = (z - z_ref) @ w_next         # (k, n, C)
-                        own = jnp.einsum("kdnp,knc->kdpc",
-                                         ell_rows.astype(jnp.float32),
-                                         delta)
-                        return q_all[ell_idx] + own          # (k, D, n, C)
+                # Only the max_deg stored neighbours are enumerated.
+                # Ã_{r,m} = Ã_{m,r}ᵀ (Ã symmetric), so the stored row
+                # blocks are consumed transposed ("kdnp,knc->kdpc") — the
+                # gather-transpose trick of second_order_from_relay:
+                # O(max_deg·n_pad²·C) per lane.
+                def pre_nbr(z, q_all=q_all, z_ref=z_ref, w_next=w_next):
+                    delta = (z - z_ref) @ w_next             # (k, n, C)
+                    own = jnp.einsum("kdnp,knc->kdpc",
+                                     ell_rows.astype(jnp.float32), delta)
+                    return q_all[ell_idx] + own              # (k, D, n, C)
 
-                    # staleness damping: √d_r folded into the coupling
-                    # weight, so every squared residual carries the full
-                    # d_r (exact identity when all ages are 0: ell_f · 1.0
-                    # is bitwise ell_f)
-                    wt = (ell_f * sdr if sdr is not None
-                          else ell_f)[..., None, None]       # (k, D, 1, 1)
+                # staleness damping: √d_r folded into the coupling weight,
+                # so every squared residual carries the full d_r (exact
+                # identity when all ages are 0: ell_f · 1.0 is bitwise
+                # ell_f)
+                wt = (ell_f * sdr if sdr is not None
+                      else ell_f)[..., None, None]           # (k, D, 1, 1)
 
-                    def nbr_vals(x_all):
-                        """(M, n, C) gathered payload -> this lane's
-                        (k, D, n, C)."""
-                        return x_all[ell_idx]
-                else:
-                    def pre_nbr(z, q_all=q_all, z_ref=z_ref,
-                                w_next=w_next):
-                        delta = (z - z_ref) @ w_next         # (k, n, C)
-                        return q_all[None] + jnp.einsum("kmnp,knc->kmpc",
-                                                        a_row, delta)
-
-                    wt = nbrf[:, :, None, None]              # (k, M, 1, 1)
-
-                    def nbr_vals(x_all):
-                        return x_all[None]                   # (1, M, n, C)
+                def nbr_vals(x_all):
+                    """blocked payload rows -> this lane's (k, D, n, C)."""
+                    return x_all[ell_idx]
 
                 if l + 1 < num_layers:
                     zh_next = zh[l][1]
@@ -1089,11 +872,10 @@ def _iteration_body(cfg: gcn.GCNConfig, admm: ADMMConfig, use_kernel: bool,
         if smask_b is not None:
             new_u = jnp.where(smask_b[:, None, None], new_u, u_loc)
 
-    if packed_aux is not None:
-        # carry state between steps in the packed plane — the blocked
-        # (k, n, C) iterates never leave this body
-        new_zs = [to_plane(z) for z in new_zs]
-        new_u = to_plane(new_u)
+    # carry state between steps in the packed plane — the blocked
+    # (k, n, C) iterates never leave this body
+    new_zs = [to_plane(z) for z in new_zs]
+    new_u = to_plane(new_u)
 
     # the round's counts summed over its searches, then each search's own
     # (``probe_columns``)
@@ -1112,37 +894,14 @@ class ParallelADMMTrainer:
     def __init__(self, cfg: gcn.GCNConfig, admm: ADMMConfig, g: graph.Graph,
                  num_parts: int, mesh: Mesh | None = None, seed: int = 0,
                  config: TrainerConfig | None = None,
-                 part: np.ndarray | None = None,
-                 **legacy_flags):
-        if legacy_flags:
-            unknown = sorted(set(legacy_flags) - set(_LEGACY_FLAGS))
-            if unknown:
-                raise TypeError(
-                    f"ParallelADMMTrainer got unexpected keyword arguments "
-                    f"{unknown}; pass config=TrainerConfig(...)")
-            if config is not None:
-                raise ValueError(
-                    "pass either config=TrainerConfig(...) or the legacy "
-                    "flag kwargs, not both")
-            warnings.warn(
-                "ParallelADMMTrainer flag kwargs are deprecated; pass "
-                "config=TrainerConfig(...) instead",
-                DeprecationWarning, stacklevel=2)
-            config = TrainerConfig(**legacy_flags)
-        elif config is None:
-            config = TrainerConfig()
-        # all cross-flag validation lives in TrainerConfig.__post_init__
+                 part: np.ndarray | None = None):
+        config = TrainerConfig() if config is None else config
         self.config = config
         self.cfg, self.admm, self.graph = cfg, admm, g
-        self.compressed = compressed = config.compressed
-        self.transport = transport = config.transport
-        self.packed = packed = config.packed
-        self.overlap = overlap = config.overlap
-        self.fused = fused = config.fused
-        self.pad_mode = pad_mode = config.pad_mode
+        overlap, fused = config.overlap, config.fused
         use_kernel = config.use_kernel
         comm_bf16 = config.comm_bf16
-        adjacency_bf16 = config.adjacency_bf16
+        item = 2 if comm_bf16 else 4
         partitioner = config.partitioner
         if part is None:
             partitioner = partitioner or "bfs_kl"
@@ -1161,8 +920,8 @@ class ParallelADMMTrainer:
             self.partition_stats = graph.partition_quality(
                 g.num_nodes, g.edges, part, num_parts)
             self.layout = graph.build_community_layout(
-                g.num_nodes, g.edges, part, compressed=compressed,
-                pad_mode=pad_mode)
+                g.num_nodes, g.edges, part, compressed=True,
+                pad_mode="bucketed")
             m = int(np.asarray(self.layout.neighbor_mask).shape[0])
 
             if mesh is None:
@@ -1177,20 +936,17 @@ class ParallelADMMTrainer:
             # back at their bucket row counts on a flat plane — resident
             # bytes track true community size, not M·n_pad
             # (docs/layout.md)
-            self.packed_layout = self.layout.device_layout(n_shards) \
-                if packed else None
+            dl = self.packed_layout = self.layout.device_layout(n_shards)
             # every data array starts where the step reads it: lane-major
             # rows split over the comm axis, the scalar denominator
             # replicated
-            data = community_data(g, self.layout, compressed=compressed,
-                                  adjacency_bf16=adjacency_bf16,
-                                  device_layout=self.packed_layout)
+            data = community_data(g, self.layout, dl,
+                                  adjacency_bf16=config.adjacency_bf16)
             self.data = dataclasses.replace(data, **{
                 f.name: place_on_mesh(mesh, getattr(data, f.name),
                                       P() if f.name == "denom" else P(AXIS))
                 for f in dataclasses.fields(data)
-                if f.name != "packed_layout"
-                and getattr(data, f.name) is not None})
+                if f.name != "packed_layout"})
 
         with spans.span("construct.init_state"):
             # init from the same forward pass as the serial trainer
@@ -1198,12 +954,8 @@ class ParallelADMMTrainer:
             a_full = graph.normalized_adjacency(g.num_nodes, g.edges)
             zs_full = gcn.forward(cfg, jnp.asarray(a_full),
                                   jnp.asarray(g.features), ws)
-            if packed:
-                dl = self.packed_layout
-                zs = tuple(dl.pack_state(self.layout.pack(np.asarray(z)))
-                           for z in zs_full)
-            else:
-                zs = tuple(self.layout.pack(np.asarray(z)) for z in zs_full)
+            zs = tuple(dl.pack_state(self.layout.pack(np.asarray(z)))
+                       for z in zs_full)
             del zs_full
             u = np.zeros_like(zs[-1])
             taus = tuple(jnp.asarray(admm.tau_init) for _ in ws)
@@ -1220,138 +972,86 @@ class ParallelADMMTrainer:
             self.state = place_on_mesh(mesh, ParallelState(
                 tuple(ws), zs, u, taus, thetas, probes), self.state_spec)
 
-        self._plan = None
+        # the plan wires row-exact payloads — only each wired community's
+        # true rows ever cross the wire — and carries the plane routing
+        # tables (send/recv packed rows, offsets)
+        self._plan = messages.build_neighbor_exchange(
+            self.layout.neighbor_mask, n_shards, self.layout.n_pad,
+            sizes=self.layout.sizes, row_counts=self.layout.eff_row_counts())
+        csr = self.layout.compress()
+        # static host tables for the body — captured in the partial and
+        # indexed in-body by axis_index, so the shard_map specs never see
+        # them (same pattern as the plan's send/recv tables)
+        packed_aux = {
+            "k": int(dl.lanes_per_shard),
+            "n": int(dl.n_pad),
+            "unpack": np.asarray(dl.unpack_rows),
+            "pack": np.asarray(dl.pack_rows),
+        }
         ell_idx_dev = self.data.ell_indices
-        if self.transport == "p2p":
-            # bucketed layouts wire row-exact payloads: only each wired
-            # community's true rows ever cross the wire; the global scheme
-            # keeps the historic whole-n_pad-block messages.  Packed mode
-            # additionally threads bucket row_counts so the plan carries
-            # the plane routing tables (send/recv packed rows, offsets).
-            self._plan = messages.build_neighbor_exchange(
-                self.layout.neighbor_mask, n_shards, self.layout.n_pad,
-                sizes=self.layout.sizes if pad_mode == "bucketed" else None,
-                row_counts=self.layout.eff_row_counts() if packed else None)
-            if n_shards == 1:
-                # one shard hosts every community: nothing ever crosses the
-                # wire, the transports are the same program (the all-gather
-                # is a no-op collective), so keep the well-tested gather
-                # body and only the p2p byte accounting (wire_bytes == 0)
-                body_plan = None
-            else:
-                # ELL indices remapped host-side to receive-buffer slots —
-                # the body never sees an (M, ...) payload
-                body_plan = self._plan
-                csr = self.layout.compress()
-                ell_idx_dev = np.asarray(self._plan.localize_indices(
-                    csr.ell_indices, csr.ell_mask))
-        else:
+        if n_shards == 1:
+            # one shard hosts every community: nothing ever crosses the
+            # wire, so the body reads the shard's own rows (wire_bytes == 0)
             body_plan = None
-
-        # static host tables for the packed body — captured in the partial
-        # and indexed in-body by axis_index, so the shard_map specs never
-        # see them (same pattern as the plan's send/recv tables)
+        else:
+            # ELL indices remapped host-side to receive-buffer slots —
+            # the body never sees an (M, ...) payload
+            body_plan = self._plan
+            ell_idx_dev = np.asarray(self._plan.localize_indices(
+                csr.ell_indices, csr.ell_mask))
+            packed_aux["recv_unpack"] = np.asarray(
+                self._plan.recv_unpack_rows)
+            packed_aux["offsets"] = np.asarray(self._plan.localized_offsets(
+                csr.ell_indices, csr.ell_mask)).reshape(
+                n_shards, dl.lanes_per_shard, -1)
         overlap_on = bool(overlap and body_plan is not None)
         fused_on = bool(fused and body_plan is not None)
-        packed_aux = None
-        if packed:
-            dl = self.packed_layout
-            packed_aux = {
-                "k": int(dl.lanes_per_shard),
-                "n": int(dl.n_pad),
-                "unpack": np.asarray(dl.unpack_rows),
-                "pack": np.asarray(dl.pack_rows),
-            }
-            if body_plan is not None:
-                csr = self.layout.compress()
-                packed_aux["recv_unpack"] = \
-                    np.asarray(self._plan.recv_unpack_rows)
-                packed_aux["offsets"] = np.asarray(
-                    self._plan.localized_offsets(
-                        csr.ell_indices, csr.ell_mask)).reshape(
-                    n_shards, dl.lanes_per_shard, -1)
-                if overlap_on:
-                    # host tables the per-step arrival-group computation
-                    # needs: slot layout is plan-stable (restrict_exchange
-                    # never touches buffer geometry), so the localized
-                    # slots are computed once against the full plan
-                    ov_loc = np.asarray(self._plan.localize_indices(
-                        csr.ell_indices, csr.ell_mask)).reshape(
-                        n_shards, dl.lanes_per_shard, -1)
-                    ov_msk = np.asarray(csr.ell_mask).reshape(
-                        n_shards, dl.lanes_per_shard, -1)
+        if overlap_on:
+            # host tables the per-step arrival-group computation needs:
+            # slot layout is plan-stable (restrict_exchange never touches
+            # buffer geometry), so the localized slots are computed once
+            # against the full plan
+            ov_loc = ell_idx_dev.reshape(n_shards, dl.lanes_per_shard, -1)
+            ov_msk = np.asarray(csr.ell_mask).reshape(
+                n_shards, dl.lanes_per_shard, -1)
 
-        if compressed:
-            # each shard carries only its lanes' ELL rows — no dense
-            # (M, M, n_pad, n_pad) tensor exists on device — plus its
-            # lanes' ragged row counts for the kernel pad-row guards
-            adj_data = (self.data.ell_blocks, ell_idx_dev,
-                        self.data.ell_mask, self.data.row_counts,
-                        self.data.nbr_counts)
-            adj_spec = (sharded, sharded, sharded, sharded, sharded)
-        else:
-            adj_data = self.data.a_blocks
-            adj_spec = sharded
-        # full-M packed aggregation: ELL for Ã·Z_0 in every mode and for the
-        # metrics/Lagrangian programs in compressed mode (no dense
-        # adjacency is retained on device), the masked dense einsum for
-        # dense mode's.  The ELL aggregation runs per shard under the mesh
-        # (a Pallas kernel cannot be auto-partitioned across chips): each
-        # shard aggregates its own lanes against the replicated blocked Z.
-        # ``use_kernel`` picks the kernel or the einsum, as in the step
-        # body.
+        # each shard carries only its lanes' ELL rows — no dense
+        # (M, M, n_pad, n_pad) tensor exists on device — plus its lanes'
+        # ragged row counts for the kernel pad-row guards
+        data = self.data
+        adj_data = (data.ell_blocks, ell_idx_dev, data.ell_mask,
+                    data.row_counts, data.nbr_counts)
+        adj_full = (data.ell_blocks, data.ell_indices, data.ell_mask,
+                    data.row_counts, data.nbr_counts)
+        # full-M aggregation for Ã·Z_0 and the metrics/Lagrangian
+        # programs.  It runs per shard under the mesh (a Pallas kernel
+        # cannot be auto-partitioned across chips): each shard aggregates
+        # its own lanes against the replicated blocked Z.  ``use_kernel``
+        # picks the kernel or the einsum, as in the step body.
         from repro.kernels import ops as kops
         from repro.kernels import ref as kref
         agg_lanes = kops.community_spmm_ell if use_kernel \
             else kref.community_spmm_ell_einsum
-        agg_ell = jax.shard_map(
+        agg_full = jax.shard_map(
             lambda ell, z: agg_lanes(*ell[:3], z, *ell[3:]), mesh=mesh,
             in_specs=((sharded,) * 5, rep), out_specs=sharded,
             check_vma=False)
-        data = self.data
-        if compressed:
-            agg_full = agg_ell
-            adj_full = (data.ell_blocks, data.ell_indices, data.ell_mask,
-                        data.row_counts, data.nbr_counts)
-            ell_full = adj_full
-        else:
-            adj_full = (data.a_blocks, data.neighbor_mask)
-
-            def agg_full(adj, z_pack):
-                a_blocks, nbr = adj
-                nbr_f = nbr.astype(jnp.float32)
-                return jnp.einsum("mrip,rpc->mic",
-                                  a_blocks * nbr_f[:, :, None, None], z_pack)
-
-            # the ELL view of the dense blocks, for Ã·Z_0 alone
-            csr = self.layout.compress()
-            ell_full = place_on_mesh(mesh, (
-                csr.ell_blocks, csr.ell_indices, csr.ell_mask,
-                *csr.ell_row_counts()), sharded)
 
         # Ã·Z_0 and the eval programs run on the blocked (M, n_pad, ...)
-        # view; in packed mode the planes are rebuilt through the device
-        # layout's global row table (take-with-fill, bitwise lossless
-        # under the zero-outside-counts contract)
-        if packed:
-            gup = np.asarray(self.packed_layout.global_unpack_rows())
-            n_pad_loc = self.layout.n_pad
+        # view; the planes are rebuilt through the device layout's global
+        # row table (take-with-fill, bitwise lossless under the
+        # zero-outside-counts contract)
+        gup = np.asarray(dl.global_unpack_rows())
+        n_pad_loc = self.layout.n_pad
 
-            def unfold(p):
-                flat = jnp.take(p, gup, axis=0, mode="fill", fill_value=0)
-                return flat.reshape((m, n_pad_loc) + p.shape[1:])
-        else:
-            def unfold(p):
-                return p
+        def unfold(p):
+            flat = jnp.take(p, gup, axis=0, mode="fill", fill_value=0)
+            return flat.reshape((m, n_pad_loc) + p.shape[1:])
 
         # Z_0 = X never changes, so Ã·Z_0 is aggregated once, here, and
         # carried as device data: blocked (M, n_pad, C_0), split over the
         # comm axis like the step's lanes.  The step's first-layer sites
-        # and both eval programs read it.  Every mode aggregates it through
-        # the ELL view, so dense and compressed trainers start from the
-        # same bits: the first W search's objective is float32 rounding
-        # at construction (Z_1 is the forward pass's), and which way it
-        # goes follows those bits.  The width of every distinct
+        # and both eval programs read it.  The width of every distinct
         # aggregation each program lowers, filled as the program is traced
         # (``comm_stats["aggregations"]``), so C_0 is under "construct"
         agg_widths = {"construct": [int(data.z0.shape[-1])], "step": [],
@@ -1359,16 +1059,15 @@ class ParallelADMMTrainer:
         with spans.span("construct.input_agg"):
             @partial(jax.jit, out_shardings=NamedSharding(mesh, sharded))
             def input_agg(ell, z0):
-                return agg_ell(ell, unfold(z0))
+                return agg_full(ell, unfold(z0))
 
-            self.ax = jax.block_until_ready(input_agg(ell_full, data.z0))
+            self.ax = jax.block_until_ready(input_agg(adj_full, data.z0))
 
         k_lanes = m // n_shards
-        step_spec = (adj_spec, sharded, sharded, sharded, sharded, sharded,
-                     rep)
+        step_spec = ((sharded,) * 5, sharded, sharded, sharded, sharded, rep)
         step_data = place_on_mesh(mesh, (
-            adj_data, data.neighbor_mask, data.z0, self.ax, data.labels,
-            data.train_mask, data.denom), step_spec)
+            adj_data, data.z0, self.ax, data.labels, data.train_mask,
+            data.denom), step_spec)
 
         def make_step(sampled=None):
             """Compile one ADMM step.  ``sampled`` (an iterable of shard
@@ -1407,8 +1106,8 @@ class ParallelADMMTrainer:
                                       arr[s][ov_loc[s]] + 1, 0)
                 step_aux = dict(packed_aux, groups=grp)
             body = partial(_iteration_body, cfg, admm, use_kernel,
-                           comm_bf16, compressed, step_plan, overlap_on,
-                           fused, step_aux, mb_aux, agg_widths["step"])
+                           comm_bf16, step_plan, overlap_on, fused,
+                           step_aux, mb_aux, agg_widths["step"])
             in_specs = step_spec + tuple(self.state_spec)
             out_specs = tuple(self.state_spec)
             if mb_aux is not None:
@@ -1444,8 +1143,7 @@ class ParallelADMMTrainer:
             self._sampler = CommunityBatchSampler(
                 n_shards, config.batch_fraction,
                 seed=config.sample_seed, weights=rc_shard)
-            csr_mb = self.layout.compress()
-            self._mb_nbr = np.asarray(csr_mb.ell_indices)  # (M, D) global
+            self._mb_nbr = np.asarray(csr.ell_indices)   # (M, D) global
             self._mb_k = k_lanes
             self._ages = np.zeros(m, dtype=np.int64)
             self._mb_steps = {}
@@ -1461,73 +1159,55 @@ class ParallelADMMTrainer:
         gathered_cs = gathered_widths(dims, fused=fused_on)
         self.comm_stats = messages.gather_bytes(
             self.layout.neighbor_mask, self.layout.n_pad, gathered_cs,
-            itemsize=2 if comm_bf16 else 4)
-        self.comm_stats["transport"] = self.transport
+            itemsize=item)
         self.comm_stats["aggregations"] = agg_widths
         # residual-padding accounting: how many payload rows / aggregation
-        # FLOPs this trainer spends beyond the true community sizes.  The
-        # bucketed row_counts only shrink what a consumer actually
-        # exploits, so each axis is gated on its consumer being engaged —
-        # pad FLOPs drop only on the guarded-kernel path (use_kernel:
-        # tiles past the row counts skip the DMA+accumulate on TPU; the
+        # FLOPs this trainer spends beyond the true community sizes.  Pad
+        # FLOPs drop only on the guarded-kernel path (use_kernel: tiles
+        # past the row counts skip the DMA+accumulate on TPU; the
         # CPU/interpret fallbacks emulate the same masked semantics, so
         # off-TPU the number is the kernel-path bound rather than a
-        # measured skip, while the default einsum body processes every
-        # n_pad row and claims nothing), pad wire rows only under the
-        # row-exact p2p transport (an all-gather moves full-pad payloads
-        # regardless of layout) — the recorded numbers describe the
-        # configured program, not the layout's potential
-        self.comm_stats["pad_mode"] = pad_mode
-        kernel_ragged = compressed and use_kernel
-        wire_ragged = self.transport == "p2p"
-        item = 2 if comm_bf16 else 4
+        # measured skip, while the einsum body processes every n_pad row
+        # and claims nothing); the row-exact wire always drops pad rows
         ps_flops = messages.pad_stats(
             self.layout.neighbor_mask, self.layout.sizes,
-            self.layout.row_counts if kernel_ragged else None,
+            self.layout.row_counts if use_kernel else None,
             self.layout.n_pad, gathered_cs, itemsize=item)
         ps_wire = messages.pad_stats(
             self.layout.neighbor_mask, self.layout.sizes,
-            self.layout.row_counts if wire_ragged else None,
-            self.layout.n_pad, gathered_cs, itemsize=item)
+            self.layout.row_counts, self.layout.n_pad, gathered_cs,
+            itemsize=item)
         self.comm_stats.update(ps_wire)
         self.comm_stats.update({k: ps_flops[k] for k in
                                 ("pad_flops", "agg_flops", "pad_flop_frac")})
-        self.comm_stats["pad_guards"] = {"kernel": kernel_ragged,
-                                         "wire": wire_ragged}
         # the partition sets the communication: its edge cut is the p2p
         # wire volume's block count, its max_deg the ELL fan-in
         self.comm_stats["partitioner"] = self.partitioner
         self.comm_stats["partition"] = dict(self.partition_stats)
-        if self._plan is not None:
-            # scheduled p2p wire volume, tied to the mask-derived stats by
-            # the transport invariant: wire == true rows + round padding
-            # ≤ full, true rows ≤ needed (wire ≤ needed strictly at k=1)
-            self.comm_stats.update(messages.exchange_bytes(
-                self._plan, gathered_cs, itemsize=2 if comm_bf16 else 4))
-            messages.verify_transport_bytes(self.comm_stats)
-        else:
-            # an all-gather moves every row to every shard
-            self.comm_stats["wire_bytes"] = self.comm_stats["full_bytes"]
-        # device-resident adjacency accounting for this trainer's mode
-        # (itemsize-aware: the bf16 ELL block store halves the block term)
+        # scheduled p2p wire volume, tied to the mask-derived stats by the
+        # transport invariant: wire == true rows + round padding ≤ full,
+        # true rows ≤ needed (wire ≤ needed strictly at k=1)
+        self.comm_stats.update(messages.exchange_bytes(
+            self._plan, gathered_cs, itemsize=item))
+        messages.verify_transport_bytes(self.comm_stats)
+        # device-resident adjacency accounting (itemsize-aware: the bf16
+        # ELL block store halves the block term)
         self.comm_stats["adjacency"] = messages.adjacency_bytes(
             self.layout.neighbor_mask, self.layout.n_pad,
-            itemsize=2 if adjacency_bf16 else 4)
+            itemsize=2 if config.adjacency_bf16 else 4)
         self.comm_stats["adjacency"]["resident_bytes"] = \
             int(self.data.adjacency_nbytes)
 
         # device-resident iterate accounting: the packed plane prices
-        # Z/U/z0/labels/masks at Σ bucket rows (× the shard-max factor);
-        # the strided layout at M·n_pad rows regardless of skew.  All
+        # Z/U/z0/labels/masks at Σ bucket rows (× the shard-max factor),
+        # beside the M·n_pad rows a strided layout would hold.  All
         # resident iterates are f32 (comm_bf16 compresses the wire only).
         z_cols = sum(dims[1:])                    # Z_1..Z_L feature columns
         state_cols = dims[0] + z_cols + dims[-1]  # + z0 + U
         rc_eff = np.asarray(self.layout.eff_row_counts(), dtype=np.int64)
         strided_rows = m * self.layout.n_pad
-        resident_rows = self.packed_layout.total_rows if packed \
-            else strided_rows
+        resident_rows = dl.total_rows
         self.comm_stats["state"] = {
-            "packed": packed,
             "itemsize": 4,
             "rows": int(resident_rows),
             "strided_rows": int(strided_rows),
@@ -1538,19 +1218,19 @@ class ParallelADMMTrainer:
             "resident_bytes": int(resident_rows * (state_cols + 3) * 4),
             "strided_equiv_bytes": int(strided_rows * (state_cols + 3) * 4),
         }
-        if self._plan is not None:
-            # analytic overlap efficiency of the *active* round schedule —
-            # consumed by benchmarks.roofline's exposed-wire pricing.
-            # Under minibatching the compiled step runs the restricted
-            # sub-plan, so that is what gets priced (the full plan would
-            # overstate a sampled round's wire); ``step()`` re-prices when
-            # the active batch changes.
-            def _overlap_pricing(plan):
-                return messages.overlap_stats(
-                    plan, self.layout.neighbor_mask, gathered_cs,
-                    itemsize=2 if comm_bf16 else 4, enabled=overlap_on)
-            self._overlap_pricing = _overlap_pricing
-            self.comm_stats["overlap"] = _overlap_pricing(self._active_plan)
+
+        # analytic overlap efficiency of the *active* round schedule —
+        # consumed by benchmarks.roofline's exposed-wire pricing.  Under
+        # minibatching the compiled step runs the restricted sub-plan, so
+        # that is what gets priced (the full plan would overstate a
+        # sampled round's wire); ``step()`` re-prices when the active
+        # batch changes.
+        def _overlap_pricing(plan):
+            return messages.overlap_stats(
+                plan, self.layout.neighbor_mask, gathered_cs,
+                itemsize=item, enabled=overlap_on)
+        self._overlap_pricing = _overlap_pricing
+        self.comm_stats["overlap"] = _overlap_pricing(self._active_plan)
         if self._sampler is None:
             self.comm_stats["minibatch"] = {"enabled": False}
         else:
@@ -1683,10 +1363,8 @@ class ParallelADMMTrainer:
         step_fn, plan = self._step_for(shards)
         self._step = step_fn
         self._active_plan = plan if plan is not None else self._plan
-        if "overlap" in self.comm_stats:
-            # keep the overlap pricing tied to the plan this round runs
-            self.comm_stats["overlap"] = self._overlap_pricing(
-                self._active_plan)
+        # keep the overlap pricing tied to the plan this round runs
+        self.comm_stats["overlap"] = self._overlap_pricing(self._active_plan)
         self.state = step_fn(self.state, self._nbr_decay())
         # ages advance after the round: a community sampled this round
         # ends it fresh (age 0 — "reset on resample"), everyone else's

@@ -1,8 +1,7 @@
 """CLI: run the invariant linter over the benchmark trainer configs.
 
-Builds each benchmark trainer (transport x pad-mode on the compressed
-layout, plus the dense baseline in full mode), compiles its step on a
-4-shard host mesh, runs the ``repro.analysis`` rule registry against the
+Builds the trainer in each config (its one path, and each option of it),
+compiles its step on a 4-shard host mesh, runs the ``repro.analysis`` rule registry against the
 trainer's own host-side expectations, and writes a JSON report.  Exit
 status 1 if any error-severity finding survives its waivers — CI fails
 the build on that.
@@ -11,8 +10,7 @@ the build on that.
     PYTHONPATH=src python src/repro/launch/analyze.py --out report.json
 
 The device-count flag must be set before jax initialises (a 1-shard mesh
-compiles no real collectives, which would make every transport rule
-vacuous), so jax/repro imports happen inside ``main`` after the env is
+exchanges nothing, which would make every wire rule vacuous), so jax/repro imports happen inside ``main`` after the env is
 prepared.
 """
 from __future__ import annotations
@@ -24,39 +22,24 @@ import sys
 
 N_SHARDS = 4
 
-# the four benchmark transport x pad-mode configs (--quick and CI);
-# full mode adds the dense baseline (the dense-adjacency rule is waived
-# there — that config IS the dense layout) and the bf16 wire/store path
+# the trainer's one path and its options; --quick (CI) runs the first two
 QUICK_CONFIGS = [
-    {"name": "p2p_global", "transport": "p2p", "pad_mode": "global"},
-    {"name": "p2p_bucketed", "transport": "p2p", "pad_mode": "bucketed"},
-    {"name": "allgather_global", "transport": "allgather",
-     "pad_mode": "global"},
-    {"name": "allgather_bucketed", "transport": "allgather",
-     "pad_mode": "bucketed"},
     # packed resident state: memory/packed-resident-state proves the
     # compiled step holds no blocked row stack taller than r_pad
-    {"name": "p2p_packed", "transport": "p2p", "pad_mode": "bucketed",
-     "packed": True},
-    {"name": "p2p_packed_overlap", "transport": "p2p",
-     "pad_mode": "bucketed", "packed": True, "overlap": True},
-    # stochastic minibatching: the collective/permute-schedule rule proves
-    # the compiled sampled step's ppermute pairs are exactly the
-    # restricted sub-plan's — no collective touches an unsampled shard pair
-    {"name": "p2p_minibatch", "transport": "p2p", "pad_mode": "bucketed",
-     "packed": True, "batch_fraction": 0.5, "stale_decay": 0.5},
+    {"name": "p2p_packed"},
     # fused aggregation→Z-update: memory/fused-no-intermediate proves the
     # compiled step hands no aggregated (k, n_pad, C) stack to a GEMM
     # beyond the W-update line-search allowance, and the pallas VMEM rule
     # covers the fused spec's scratch-resident aggregate
-    {"name": "p2p_fused", "transport": "p2p", "pad_mode": "bucketed",
-     "packed": True, "fused": True},
+    {"name": "p2p_fused", "fused": True},
 ]
 FULL_CONFIGS = QUICK_CONFIGS + [
-    {"name": "dense_allgather", "transport": "allgather",
-     "pad_mode": "global", "compressed": False},
-    {"name": "p2p_bf16", "transport": "p2p", "pad_mode": "bucketed",
-     "comm_bf16": True, "adjacency_bf16": True},
+    {"name": "p2p_packed_overlap", "overlap": True},
+    # stochastic minibatching: the collective/permute-schedule rule proves
+    # the compiled sampled step's ppermute pairs are exactly the
+    # restricted sub-plan's — no collective touches an unsampled shard pair
+    {"name": "p2p_minibatch", "batch_fraction": 0.5, "stale_decay": 0.5},
+    {"name": "p2p_bf16", "comm_bf16": True, "adjacency_bf16": True},
 ]
 
 
@@ -91,10 +74,8 @@ def _build_trainer(spec: dict):
     admm = ADMMConfig(nu=1e-3, rho=1e-3)
     mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,),
                          devices=jax.devices()[:N_SHARDS])
-    # the spec dicts ARE TrainerConfig kwargs (single source of truth);
-    # only the compressed default differs from the dataclass default
+    # the spec dicts ARE TrainerConfig kwargs (single source of truth)
     kw = {k: v for k, v in spec.items() if k != "name"}
-    kw.setdefault("compressed", True)
     return ParallelADMMTrainer(cfg, admm, g, num_parts=8, seed=0,
                                part=part, mesh=mesh,
                                config=TrainerConfig(**kw))
@@ -103,18 +84,10 @@ def _build_trainer(spec: dict):
 def run_configs(configs: list[dict]) -> list:
     from repro import analysis
 
-    # the dense baseline legitimately holds the dense block tensor; the
-    # rule is already gated on dense_adjacency_allowed, the waiver here
-    # documents the intent in the report
-    waivers = (analysis.Waiver(
-        "memory/no-dense-adjacency",
-        "the dense baseline IS the dense layout",
-        when={"compressed": False}),)
     reports = []
     for spec in configs:
         tr = _build_trainer(spec)
-        reports.append(analysis.analyze_trainer(
-            tr, config=spec["name"], waivers=waivers))
+        reports.append(analysis.analyze_trainer(tr, config=spec["name"]))
     return reports
 
 
@@ -160,7 +133,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="invariant linter over the benchmark trainer configs")
     ap.add_argument("--quick", action="store_true",
-                    help="the four transport x pad-mode configs only")
+                    help="the plain and fused configs only")
     ap.add_argument("--config", action="append", default=None,
                     help="run only the named config(s)")
     ap.add_argument("--out", default="BENCH_analysis.json",

@@ -81,8 +81,7 @@ def main(argv=None) -> int:
     tr = ParallelADMMTrainer(
         cfg, ADMMConfig(nu=1e-3, rho=1e-3), g, num_parts=args.parts,
         seed=args.seed, part=part,
-        config=TrainerConfig(transport="p2p", compressed=True,
-                             pad_mode="bucketed", packed=True))
+        config=TrainerConfig.packed())
     print(f"[serve] training M={args.parts} model on N={g.num_nodes} "
           f"({args.epochs} epochs)...")
     tr.train(args.epochs)

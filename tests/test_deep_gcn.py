@@ -55,8 +55,8 @@ SEEDS = [2**31 + 5, 77]
 
 # id -> (TrainerConfig, shards, Pallas kernel bodies in interpret mode)
 MODES = {
-    "dense": (TrainerConfig(), 1, False),
     "packed-einsum": (TrainerConfig.packed(), 1, False),
+    "packed-einsum-4": (TrainerConfig.packed(), 4, False),
     "packed-kernel": (TrainerConfig.packed(use_kernel=True), 1, True),
     "packed-fused": (TrainerConfig.packed(use_kernel=True, fused=True), 1,
                      True),
@@ -100,10 +100,8 @@ def _host_state(trainer) -> dict:
     st, layout = trainer.state, trainer.layout
 
     def nodes(x):
-        x = np.asarray(x)
-        if trainer.packed:
-            x = trainer.packed_layout.unpack_state(x)
-        return layout.unpack(x)
+        return layout.unpack(trainer.packed_layout.unpack_state(
+            np.asarray(x)))
     return {"w": [np.asarray(w) for w in st.weights],
             "z": [nodes(z) for z in st.zs], "u": nodes(st.u),
             "tau": [float(t) for t in st.taus]}

@@ -141,15 +141,6 @@ def test_fused_spec_passes_pallas_checks():
 # TrainerConfig plumbing
 # ---------------------------------------------------------------------------
 
-def test_trainer_config_fused_requires_packed():
-    with pytest.raises(ValueError, match="fused=True requires packed"):
-        TrainerConfig(compressed=True, transport="p2p",
-                      pad_mode="bucketed", fused=True)
-    cfg = TrainerConfig.packed(fused=True)
-    assert cfg.fused and cfg.packed
-    assert TrainerConfig.packed().fused is False
-
-
 def _trainer(g, part, mesh, **kw):
     cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
     admm = ADMMConfig(nu=1e-3, rho=1e-3)

@@ -51,7 +51,8 @@ def test_parallel_matches_serial_one_community(tiny):
         np.testing.assert_allclose(np.asarray(ws), np.asarray(wp),
                                    rtol=2e-4, atol=2e-6)
     z_s = np.asarray(s.state.zs[-1])
-    z_p = p.layout.unpack(np.asarray(p.state.zs[-1]))
+    z_p = p.layout.unpack(
+        p.packed_layout.unpack_state(np.asarray(p.state.zs[-1])))
     np.testing.assert_allclose(z_s, z_p, rtol=2e-3, atol=2e-4)
 
 

@@ -6,10 +6,13 @@ comes from the trainer's constructor (``ax``, aggregated once since
 Z_0 = X never changes): the step and both eval programs multiply it, so
 no program aggregates at C_0 but the fused wire's one-pass Z sites.
 
-Parity: after one round from a shared state, the trainer's U and its
-metrics and Lagrangian match the wide-first (Ã Z) W of the serial
-trainer and ``subproblems`` on the same iterates, in every mode, at a
-tolerance set by float32 rounding of the reordered sums.
+Parity: the serial trainer is the oracle of the trainer's one path.
+After one round from a shared state, the trainer's U and its metrics and
+Lagrangian match the wide-first (Ã Z) W of the serial trainer and
+``subproblems`` on the same iterates, for every option of the path
+(``MODES_1`` on one shard, ``MODES_4`` on two and four), at a tolerance
+set by float32 rounding of the reordered sums, or by bf16 rounding where
+the option stores or wires bf16.
 
 Engagement: ``comm_stats["aggregations"]`` records the width of each
 distinct aggregation a program traces, C_0 under "construct" alone; the
@@ -21,7 +24,7 @@ gathers as the compiled step's permutes move them, Z_0 on the fused wire
 only.
 
 Run as a script (``python tests/test_narrow_first.py``) on a host with 4
-devices, it prints the 4-shard modes' readings as one JSON line.
+devices, it prints the multi-shard modes' readings as one JSON line.
 """
 import json
 import os
@@ -46,17 +49,37 @@ HIDDEN = 16
 # float32 rounding of sums reordered over a few dozen terms (they read
 # ~1.7e-7 here); the older mode-parity tests hold the iterates to 2e-4
 TOL = 2e-6
+# a bf16 adjacency store or wire rounds each block or payload to 8
+# mantissa bits: the tolerance the bf16 parity tests have always used
+BF16_TOL = 0.05
 
+# every option of the trainer's one path, each a case of both the serial
+# parity and the no-input-width tests, with the tolerance of its readings
 MODES_1 = {
-    "dense": TrainerConfig.dense(),
-    "compressed": TrainerConfig.p2p(),
-    "compressed-pallas-interpret": TrainerConfig.p2p(use_kernel=True),
-    "packed": TrainerConfig.packed(),
+    "packed": (TrainerConfig.packed(), TOL),
+    "packed-pallas-interpret": (TrainerConfig.packed(use_kernel=True), TOL),
+    "adjacency-bf16": (TrainerConfig.packed(adjacency_bf16=True), BF16_TOL),
+    "multilevel": (TrainerConfig.packed(partitioner="multilevel"), TOL),
+    "minibatch-1.0": (TrainerConfig.minibatch(batch_fraction=1.0), TOL),
+    # nothing crosses a wire on one shard: these options are inert there
+    "comm-bf16": (TrainerConfig.packed(comm_bf16=True), TOL),
+    "overlap-fused": (TrainerConfig.packed(overlap=True, fused=True), TOL),
 }
+# name -> (config, shards, tolerance); run in one 4-device subprocess
 MODES_4 = {
-    "packed-p2p-4": TrainerConfig.packed(),
-    "packed-overlap-4": TrainerConfig.packed(overlap=True),
-    "fused-4": TrainerConfig.packed(fused=True),
+    "packed-p2p-4": (TrainerConfig.packed(), 4, TOL),
+    "packed-p2p-2": (TrainerConfig.packed(), 2, TOL),
+    "packed-pallas-interpret-4": (TrainerConfig.packed(use_kernel=True), 4,
+                                  TOL),
+    "packed-overlap-4": (TrainerConfig.packed(overlap=True), 4, TOL),
+    "fused-4": (TrainerConfig.packed(fused=True), 4, TOL),
+    "overlap-fused-4": (TrainerConfig.packed(overlap=True, fused=True), 4,
+                        TOL),
+    "comm-bf16-4": (TrainerConfig.packed(comm_bf16=True), 4, BF16_TOL),
+    "adjacency-bf16-4": (TrainerConfig.packed(adjacency_bf16=True), 4,
+                         BF16_TOL),
+    "multilevel-4": (TrainerConfig.packed(partitioner="multilevel"), 4, TOL),
+    "minibatch-1.0-4": (TrainerConfig.minibatch(batch_fraction=1.0), 4, TOL),
 }
 
 
@@ -67,16 +90,17 @@ def _graph():
 
 
 def _trainer(g, part, dims, config, mesh=None):
+    """A trainer on ``part``, or on its own partition of the same number
+    of communities where ``config`` names a partitioner."""
     return ParallelADMMTrainer(gcn.GCNConfig(layer_dims=dims), ADMM, g,
                                num_parts=int(part.max()) + 1, seed=0,
-                               part=part, mesh=mesh, config=config)
+                               part=None if config.partitioner else part,
+                               mesh=mesh, config=config)
 
 
 def _nodes(tr, x):
-    """A strided or packed trainer array as (N, C) node rows."""
-    x = np.asarray(x)
-    if tr.packed:
-        x = tr.packed_layout.unpack_state(x)
+    """A packed trainer array as (N, C) node rows."""
+    x = tr.packed_layout.unpack_state(np.asarray(x))
     return jnp.asarray(tr.layout.unpack(x))
 
 
@@ -113,10 +137,10 @@ def round_readings(config, mesh=None) -> dict:
     }
 
 
-def _check(readings):
-    assert readings["u"] <= TOL, readings
-    assert readings["lagrangian"] <= TOL, readings
-    assert readings["residual"] <= TOL, readings
+def _check(readings, tol=TOL):
+    assert readings["u"] <= tol, readings
+    assert readings["lagrangian"] <= tol, readings
+    assert readings["residual"] <= tol, readings
     assert readings["accuracy"] == 0.0, readings
 
 
@@ -126,23 +150,30 @@ def _check(readings):
 
 @pytest.mark.parametrize("mode", sorted(MODES_1))
 def test_round_matches_wide_first_serial(mode):
+    config, tol = MODES_1[mode]
     kops.repro_force_interpret(mode.endswith("interpret"))
     try:
-        readings = round_readings(MODES_1[mode])
+        readings = round_readings(config)
     finally:
         kops.repro_force_interpret(False)
-    _check(readings)
+    _check(readings, tol)
 
 
 def _four_shard_main():
-    mesh = jax.make_mesh((4,), (AXIS,), (AxisType.Auto,),
-                         devices=jax.devices()[:4])
-    out = {name: round_readings(cfg, mesh) for name, cfg in MODES_4.items()}
+    meshes = {n: jax.make_mesh((n,), (AXIS,), (AxisType.Auto,),
+                               devices=jax.devices()[:n]) for n in (2, 4)}
     g, part = _graph()
     dims = (g.features.shape[1], HIDDEN, g.num_classes)
-    out["widths"] = {
-        name: _program_widths(_trainer(g, part, dims, cfg, mesh))
-        for name, cfg in MODES_4.items()}
+    out = {"widths": {}}
+    for name, (cfg, shards, _) in MODES_4.items():
+        kops.repro_force_interpret(name.startswith("packed-pallas"))
+        try:
+            out[name] = round_readings(cfg, meshes[shards])
+            out["widths"][name] = _program_widths(
+                _trainer(g, part, dims, cfg, meshes[shards]))
+        finally:
+            kops.repro_force_interpret(False)
+    mesh = meshes[4]
 
     # the wire: every round of this plan is a full permutation, so the
     # permutes of one shard's compiled step move 1/4 of the scheduled bytes
@@ -180,7 +211,7 @@ def four_shards():
 
 @pytest.mark.parametrize("mode", sorted(MODES_4))
 def test_round_matches_wide_first_serial_on_4_shards(four_shards, mode):
-    _check(four_shards[mode])
+    _check(four_shards[mode], MODES_4[mode][2])
 
 
 def test_wire_accounting_matches_the_compiled_permutes(four_shards):
@@ -208,7 +239,7 @@ def test_fused_wire_adds_z0_and_matches_the_compiled_permutes(four_shards):
 
 def _trained(dims, config=None):
     g, part = _graph()
-    tr = _trainer(g, part, dims, config or TrainerConfig.p2p())
+    tr = _trainer(g, part, dims, config or TrainerConfig.packed())
     tr.train(1)
     return tr
 
@@ -240,25 +271,27 @@ def test_aggregation_tally_reads_each_programs_widths(case):
 
 def _aggregation_widths(jaxpr, n_pad) -> list:
     """Output widths of the dot_generals that contract an (n_pad, n_pad)
-    adjacency block, in every sub-jaxpr."""
+    adjacency block, in every sub-jaxpr: a block stack (rank >= 4) or a
+    kernel's block tile (rank 2).  A rank-3 (rows, n_pad, C) operand is an
+    iterate whose width C happens to equal n_pad."""
     from repro.analysis.rules.precision import _sub_jaxprs
 
     out = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "dot_general" and any(
                 tuple(v.aval.shape[-2:]) == (n_pad, n_pad)
-                for v in eqn.invars):
+                and len(v.aval.shape) != 3 for v in eqn.invars):
             out.append(int(eqn.outvars[0].aval.shape[-1]))
         for sub in _sub_jaxprs(eqn.params):
             out += _aggregation_widths(sub, n_pad)
     return out
 
 
-@pytest.mark.parametrize("config", ["dense", "compressed"])
+@pytest.mark.parametrize("config", ["packed", "adjacency-bf16"])
 def test_eval_programs_aggregate_no_hidden_width(config):
     g, _ = _graph()
     c0, c2 = g.features.shape[1], g.num_classes
-    tr = _trained((c0, HIDDEN, c2), MODES_1[config])
+    tr = _trained((c0, HIDDEN, c2), MODES_1[config][0])
     for prog in (tr._metrics, tr._lagrangian):
         jx = jax.make_jaxpr(prog.fn)(prog.data, tr.state)
         widths = _aggregation_widths(jx.jaxpr, tr.layout.n_pad)
@@ -297,7 +330,7 @@ def test_programs_aggregate_no_input_width(mode):
     dims = (g.features.shape[1], HIDDEN, g.num_classes)
     kops.repro_force_interpret(mode.endswith("interpret"))
     try:
-        widths = _program_widths(_trainer(g, part, dims, MODES_1[mode]))
+        widths = _program_widths(_trainer(g, part, dims, MODES_1[mode][0]))
     finally:
         kops.repro_force_interpret(False)
     _check_no_input_width(widths)
@@ -306,31 +339,25 @@ def test_programs_aggregate_no_input_width(mode):
 @pytest.mark.parametrize("mode", sorted(MODES_4))
 def test_programs_aggregate_no_input_width_on_4_shards(four_shards, mode):
     _check_no_input_width(four_shards["widths"][mode],
-                          fused=mode.startswith("fused"))
+                          fused="fused" in mode)
 
 
 # how each one-shard trainer aggregates Z_0: "kernel" dispatches
 # kops.community_spmm_ell (the jnp oracle off the chip), "interpret" runs
-# the Pallas kernel body, "einsum" and "dense" the step's own einsums
+# the Pallas kernel body, "einsum" the step's own einsum
 INPUT_AGG_PATHS = {
     "kernel": TrainerConfig.packed(use_kernel=True),
     "interpret": TrainerConfig.packed(use_kernel=True),
-    "einsum": TrainerConfig.p2p(),
-    "dense": TrainerConfig.dense(),
+    "einsum": TrainerConfig.packed(),
 }
 
 
 def _step_input_agg(tr):
     """Ã Z_0 as one shard's step aggregated it every round before the
-    constructor cached it: the gathered blocked Z_0, masked to the shard's
-    neighbour rows, through the step's aggregation for the trainer's mode."""
+    constructor cached it: the blocked Z_0 through the step's aggregation
+    for the trainer's options."""
     d = tr.data
-    nbrf = d.neighbor_mask.astype(jnp.float32)
-    z0 = jnp.asarray(tr.layout.pack(tr.graph.features))
-    zh = z0 * jnp.max(nbrf, axis=0)[:, None, None]
-    if not tr.compressed:
-        return jnp.einsum("kmip,mpc->kic",
-                          d.a_blocks * nbrf[:, :, None, None], zh)
+    zh = jnp.asarray(tr.layout.pack(tr.graph.features))
     if tr.config.use_kernel:
         return kops.community_spmm_ell(d.ell_blocks, d.ell_indices,
                                        d.ell_mask, zh, d.row_counts,

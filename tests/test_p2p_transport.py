@@ -1,11 +1,11 @@
 """Neighbour-only ppermute transport: round-schedule correctness, wire-byte
-invariants, and p2p vs allgather trainer parity on a real 2-shard mesh.
+invariants, and the p2p trainer against the serial trainer on a real
+4-shard mesh.
 
-The schedule is host-side static (messages.NeighborExchange); the parity
-test runs in a subprocess so XLA can be launched with 2 host devices, and
-additionally proves from the compiled HLO that the p2p step contains no
-all-gather op (no (M, n_pad, C) payload is ever materialised) while moving
-fewer collective bytes than the allgather oracle.
+The schedule is host-side static (messages.NeighborExchange); the trainer
+tests run in a subprocess so XLA can be launched with 4 host devices, and
+additionally prove from the compiled HLO that the p2p step contains no
+all-gather op (no (M, n_pad, C) payload is ever materialised).
 """
 import os
 import subprocess
@@ -305,7 +305,7 @@ def test_verify_transport_multi_lane_padding_is_soft():
 
 def test_trainer_records_and_verifies_p2p_stats():
     from repro.core import gcn
-    from repro.core.parallel import ParallelADMMTrainer
+    from repro.core.parallel import ParallelADMMTrainer, TrainerConfig
     from repro.core.subproblems import ADMMConfig
 
     g, part = graph.synthetic_powerlaw_communities(
@@ -313,19 +313,44 @@ def test_trainer_records_and_verifies_p2p_stats():
     cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
     admm = ADMMConfig(nu=1e-3, rho=1e-3)
     tr = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                             compressed=True)
-    assert tr.transport == "p2p"
-    assert tr.comm_stats["transport"] == "p2p"
+                             config=TrainerConfig.packed())
+    # one shard hosts every community: the plan wires nothing
+    assert tr._plan.n_shards == 1
+    assert tr.comm_stats["wire_bytes"] == 0
     assert tr.comm_stats["wire_bytes"] <= tr.comm_stats["needed_bytes"]
-    ag = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                             compressed=True, transport="allgather")
-    assert ag.comm_stats["wire_bytes"] == ag.comm_stats["full_bytes"]
-    with pytest.raises(ValueError):
-        ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                            transport="p2p")            # dense + p2p
-    with pytest.raises(ValueError):
-        ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                            compressed=True, transport="carrier-pigeon")
+    assert tr.comm_stats["wire_bytes"] < tr.comm_stats["full_bytes"]
+    assert tr.comm_stats["wire_within_needed"]
+
+
+@pytest.mark.parametrize("options", [
+    {}, {"comm_bf16": True}, {"overlap": True, "fused": True},
+    {"batch_fraction": 1.0}, {"adjacency_bf16": True}],
+    ids=["plain", "comm-bf16", "overlap-fused", "minibatch-1.0",
+         "adjacency-bf16"])
+def test_one_shard_step_compiles_no_all_gather(options):
+    """On one shard nothing crosses a wire: the compiled step holds no
+    all-gather (nor any other gather-class collective), and the whole
+    rule registry is clean, for every option of the path."""
+    from repro import analysis
+    from repro.core import gcn
+    from repro.core.parallel import ParallelADMMTrainer, TrainerConfig
+    from repro.core.subproblems import ADMMConfig
+
+    g, part = graph.synthetic_powerlaw_communities(
+        num_parts=4, nodes_per_part=16, attach=1, seed=2, feat_dim=8,
+        size_skew=0.8)
+    cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
+    tr = ParallelADMMTrainer(cfg, ADMMConfig(nu=1e-3, rho=1e-3), g,
+                             num_parts=4, seed=0, part=part,
+                             config=TrainerConfig(**options))
+    assert tr.mesh.shape["comm"] == 1
+    rep = analysis.analyze_trainer(tr, config="one-shard")
+    assert analysis.no_findings(rep, rule="collective/no-allgather-under-p2p")
+    assert not rep.errors(), rep.summary()
+    census = analysis.hlo.hlo_census(
+        tr._step.lower(*tr._analysis_args).compile().as_text())
+    assert census.collectives["all-gather"]["count"] == 0
+    assert census.collectives["collective-permute"]["count"] == 0
 
 
 _P2P_WORKER = r"""
@@ -333,11 +358,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from repro.core import gcn, graph, messages
-from repro.core.parallel import AXIS, ParallelADMMTrainer
+from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
+from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
 from repro.launch import roofline
 from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
+from trajectory import (assert_matches_serial, assert_same_trajectory,
+                        one_shard_twin)
 
 N_SHARDS = 4
 assert len(jax.devices()) >= N_SHARDS, jax.devices()
@@ -349,78 +377,68 @@ mesh2 = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.device
 
 # --- raw exchange == the needed rows of an all-gather, on real devices ---
 layout = graph.build_community_layout(g.num_nodes, g.edges, part,
-                                      compressed=True)
-plan = messages.build_neighbor_exchange(layout.neighbor_mask, N_SHARDS,
-                                        layout.n_pad)
+                                      compressed=True, pad_mode="bucketed")
+dl = layout.device_layout(N_SHARDS)
+plan = messages.build_neighbor_exchange(
+    layout.neighbor_mask, N_SHARDS, layout.n_pad, sizes=layout.sizes,
+    row_counts=layout.eff_row_counts())
 rng = np.random.default_rng(0)
-x = jnp.asarray(rng.normal(size=(12, layout.n_pad, 8)).astype(np.float32))
-ex = jax.shard_map(lambda v: messages.exchange_neighbors(plan, v, AXIS),
-               mesh=mesh2, in_specs=(P(AXIS),), out_specs=P(AXIS),
-               check_vma=False)
-bufs = np.asarray(jax.jit(ex)(x)).reshape(N_SHARDS, plan.r_pad,
-                                          layout.n_pad, 8)
+x = rng.normal(size=(12, layout.n_pad, 8)).astype(np.float32)
+x = layout.pack(layout.unpack(x))          # zero outside the true rows
+ex = jax.shard_map(
+    lambda v: messages.exchange_neighbors_packed(plan, v, AXIS),
+    mesh=mesh2, in_specs=(P(AXIS),), out_specs=P(AXIS), check_vma=False)
+planes = np.asarray(jax.jit(ex)(jnp.asarray(dl.pack_state(x))))
+planes = planes.reshape(N_SHARDS, -1, 8)
+n = layout.n_pad
 for s in range(N_SHARDS):
+    recv = np.concatenate([planes[s], np.zeros((1, 8), np.float32)])
+    view = recv[np.minimum(plan.recv_unpack_rows[s], len(recv) - 1)]
+    view = view.reshape(plan.r_pad, n, 8)
     ids = plan.needed_ids[s]
     for slot, gid in enumerate(ids):
-        np.testing.assert_allclose(bufs[s, slot], np.asarray(x[gid]),
-                                   rtol=0, atol=0)
+        np.testing.assert_array_equal(view[slot], x[gid])
     # slots past the shard's needed set stay zero
     for slot in range(len(ids), plan.r_pad):
-        assert np.abs(bufs[s, slot]).max() == 0.0
+        assert np.abs(view[slot]).max() == 0.0
 print("EXCHANGE_OK")
 
-# --- trainer parity: p2p vs allgather, 3 iterations, W/Z/U + Lagrangian ---
+# --- the p2p trainer against the exchange-free one-shard trainer on the
+#     same partition, and against the serial trainer: 3 iterations ---
+serial = SerialADMMTrainer(cfg, admm, g, seed=0)
 p2p = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, part=part,
-                          mesh=mesh2, compressed=True, transport="p2p")
-ag = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, part=part,
-                         mesh=mesh2, compressed=True, transport="allgather")
-assert p2p.transport == "p2p" and ag.transport == "allgather"
+                          mesh=mesh2, config=TrainerConfig.packed())
+one = one_shard_twin(p2p, num_parts=12, seed=0, part=part)
+assert one.mesh.shape[AXIS] == 1
 for _ in range(3):
-    p2p.step(); ag.step()
-for za, zp in zip(ag.state.zs, p2p.state.zs):
-    np.testing.assert_allclose(np.asarray(za), np.asarray(zp),
-                               rtol=2e-4, atol=2e-5)
-for wa, wp in zip(ag.state.weights, p2p.state.weights):
-    np.testing.assert_allclose(np.asarray(wa), np.asarray(wp),
-                               rtol=2e-4, atol=2e-5)
-np.testing.assert_allclose(np.asarray(ag.state.u), np.asarray(p2p.state.u),
-                           rtol=2e-4, atol=2e-5)
-lag_p, lag_a = float(p2p._lagrangian(p2p.state)), float(ag._lagrangian(ag.state))
-assert abs(lag_p - lag_a) <= 1e-4 * max(1.0, abs(lag_a)), (lag_p, lag_a)
+    serial.step(); p2p.step(); one.step()
+assert_same_trajectory(p2p, one)
 print("PARITY_OK")
+assert_matches_serial(p2p, serial)
+print("SERIAL_PARITY_OK")
 
 # --- HLO proof via the analysis rules: the p2p step materialises no
 #     gathered payload, its permute schedule matches the host plan, and
 #     the full registry (memory, precision, donation) is clean ---
 from repro import analysis
 hlo_p2p = p2p._step.lower(p2p.state).compile().as_text()
-hlo_ag = ag._step.lower(ag.state).compile().as_text()
 rep = analysis.analyze_trainer(p2p, hlo_text=hlo_p2p, config="p2p-proof")
 assert analysis.no_findings(rep, rule="collective/no-allgather-under-p2p")
 assert analysis.no_findings(rep, rule="collective/permute-schedule")
 assert analysis.no_findings(rep, rule="memory/no-dense-adjacency")
 assert not rep.errors(), rep.summary()
-rep_ag = analysis.analyze_trainer(ag, hlo_text=hlo_ag, config="ag-oracle")
-assert not rep_ag.errors(), rep_ag.summary()
-# deliberate break: the allgather program under the p2p expectations must
-# trip exactly the rule that guards the transport contract
-bad = analysis.analyze_hlo(
-    hlo_ag, expectations=analysis.trainer_expectations(p2p))
-assert bad.findings_for("collective/no-allgather-under-p2p"), \
-    "linter missed the all-gather"
 c_p2p = roofline.hlo_census(hlo_p2p).collective_bytes
-c_ag = roofline.hlo_census(hlo_ag).collective_bytes
-assert 0 < c_p2p < c_ag, (c_p2p, c_ag)
-print(f"WIRE_OK p2p={c_p2p} allgather={c_ag}")
+assert 0 < c_p2p <= p2p.comm_stats["wire_bytes"], c_p2p
+print(f"WIRE_OK p2p={c_p2p}")
 
 # --- bf16 wire path stays close ---
 b16 = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, part=part,
-                          mesh=mesh2, compressed=True, transport="p2p",
-                          comm_bf16=True)
+                          mesh=mesh2,
+                          config=TrainerConfig.packed(comm_bf16=True))
 for _ in range(2):
     b16.step()
 ref = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, part=part,
-                          mesh=mesh2, compressed=True, transport="p2p")
+                          mesh=mesh2, config=TrainerConfig.packed())
 for _ in range(2):
     ref.step()
 for zb, zr in zip(b16.state.zs, ref.state.zs):
@@ -432,12 +450,13 @@ print("BF16_OK")
 
 _MULTILEVEL_WORKER = r"""
 import jax
-import numpy as np
 from repro.core import gcn, graph
-from repro.core.parallel import AXIS, ParallelADMMTrainer
+from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
 from jax.sharding import AxisType
+from trajectory import (assert_matches_serial, assert_same_trajectory,
+                        one_shard_twin)
 
 N_SHARDS = 4
 assert len(jax.devices()) >= N_SHARDS, jax.devices()
@@ -449,42 +468,19 @@ mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.devices
 
 serial = SerialADMMTrainer(cfg, admm, g, seed=0)
 ml = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, mesh=mesh,
-                         compressed=True, partitioner="multilevel")
-ag = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, mesh=mesh,
-                         compressed=True, partitioner="multilevel",
-                         transport="allgather")
-assert ml.partitioner == "multilevel" and ml.transport == "p2p"
+                         config=TrainerConfig(partitioner="multilevel"))
+assert ml.partitioner == "multilevel"
 assert ml.comm_stats["partitioner"] == "multilevel"
 assert ml.comm_stats["partition"]["edge_cut"] == ml.partition_stats["edge_cut"]
+one = one_shard_twin(ml, num_parts=12, seed=0)
+assert one.partition_stats == ml.partition_stats
 for _ in range(3):
-    serial.step(); ml.step(); ag.step()
+    serial.step(); ml.step(); one.step()
 
-# -- serial parity: the partitioner only reshapes communication; the math
-#    is the global Algorithm 1 either way --
-for zs_, zp in zip(serial.state.zs, ml.state.zs):
-    np.testing.assert_allclose(np.asarray(zs_),
-                               ml.layout.unpack(np.asarray(zp)),
-                               rtol=2e-3, atol=2e-4)
-for ws, wp in zip(serial.state.weights, ml.state.weights):
-    np.testing.assert_allclose(np.asarray(ws), np.asarray(wp),
-                               rtol=2e-3, atol=2e-4)
-np.testing.assert_allclose(np.asarray(serial.state.u),
-                           ml.layout.unpack(np.asarray(ml.state.u)),
-                           rtol=2e-3, atol=2e-4)
-lag_s = float(serial._lagr(serial.a_tilde, serial.z0, serial.labels,
-                           serial.train_mask, serial.state))
-lag_m = float(ml._lagrangian(ml.state))
-assert abs(lag_s - lag_m) <= 1e-4 * max(1.0, abs(lag_s)), (lag_s, lag_m)
-print("SERIAL_PARITY_OK")
-
-# -- transport parity under the multilevel partition: p2p vs allgather
-#    bit-compare on the same layout --
-for za, zp in zip(ag.state.zs, ml.state.zs):
-    np.testing.assert_allclose(np.asarray(za), np.asarray(zp),
-                               rtol=2e-4, atol=2e-5)
-np.testing.assert_allclose(np.asarray(ag.state.u), np.asarray(ml.state.u),
-                           rtol=2e-4, atol=2e-5)
-print("TRANSPORT_PARITY_OK")
+# -- the exchange-free one-shard trainer on the same partition: the p2p
+#    exchange changes only who sends which rows, never the math --
+assert_same_trajectory(ml, one)
+print("ONE_SHARD_PARITY_OK")
 
 # -- and the multilevel layout still compiles to a gather-free p2p step
 #    (the analysis rules prove it, plus schedule/memory/precision) --
@@ -494,38 +490,51 @@ assert analysis.no_findings(rep, rule="collective/no-allgather-under-p2p")
 assert analysis.no_findings(rep, rule="collective/permute-schedule")
 assert not rep.errors(), rep.summary()
 print("HLO_OK")
+
+# -- serial parity: the partitioner only reshapes communication; the math
+#    is the global Algorithm 1 either way --
+assert_matches_serial(ml, serial)
+print("SERIAL_PARITY_OK")
 """
 
 
 def test_multilevel_partition_trainer_invariance():
-    """ParallelADMMTrainer(partitioner='multilevel') on a real 4-shard mesh
-    matches the serial trainer's W/Z/U and Lagrangian to float tolerance
-    after 3 iterations, and its p2p step matches the allgather oracle on
-    the same layout — the partitioner choice changes only who talks to
-    whom, never the optimization semantics."""
+    """TrainerConfig(partitioner='multilevel') on a real 4-shard mesh
+    holds the W, Z, U and Lagrangian of the exchange-free one-shard
+    trainer on the same partition after 3 iterations, compiles a
+    gather-free p2p step, and matches the serial trainer's trajectory —
+    the partitioner choice changes only who talks to whom, never the
+    optimization semantics."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         os.path.dirname(__file__)])
     out = subprocess.run([sys.executable, "-c", _MULTILEVEL_WORKER],
                          capture_output=True, text=True, env=env,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    for tag in ("SERIAL_PARITY_OK", "TRANSPORT_PARITY_OK", "HLO_OK"):
+    for tag in ("ONE_SHARD_PARITY_OK", "HLO_OK", "SERIAL_PARITY_OK"):
         assert tag in out.stdout, out.stdout
 
 
 def test_p2p_parity_on_multi_shard_mesh():
-    """p2p vs allgather on a real 4-shard host mesh (subprocess: XLA locks
-    the device count at first init): identical W/Z/U and Lagrangian after 3
-    iterations, raw exchange delivers exactly the needed rows, and the
-    compiled p2p HLO contains collective-permutes but no all-gather while
-    moving fewer collective bytes."""
+    """The p2p trainer on a real 4-shard host mesh (subprocess: XLA locks
+    the device count at first init): the raw packed exchange delivers
+    exactly the needed rows, the trainer holds the W, Z, U and Lagrangian
+    of the exchange-free one-shard trainer on the same partition, and the
+    serial trainer's, after 3 iterations, the compiled HLO contains
+    collective-permutes but no all-gather and moves no more than the
+    scheduled wire, and the bf16 wire stays close."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         os.path.dirname(__file__)])
     out = subprocess.run([sys.executable, "-c", _P2P_WORKER],
                          capture_output=True, text=True, env=env,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    for tag in ("EXCHANGE_OK", "PARITY_OK", "WIRE_OK", "BF16_OK"):
+    for tag in ("EXCHANGE_OK", "PARITY_OK", "SERIAL_PARITY_OK", "WIRE_OK",
+                "BF16_OK"):
         assert tag in out.stdout, out.stdout

@@ -3,13 +3,13 @@ offset-indexed exchange/aggregation path, and the double-buffered
 exchange/aggregation overlap.
 
 The invariant under test everywhere: packing changes where rows LIVE,
-never the math — the packed trainer's iterates are *bitwise* equal to the
-strided (M, n_pad, ...) path's on CPU (the zero-outside-counts contract
-makes pack/unpack lossless and the einsum oracles see identical operands),
-while ``comm_stats['state']`` shows resident rows/bytes dropping.  The
-overlap mode re-associates the neighbour sum by arrival round, so its
-parity is tolerance- rather than bit-level; its wire schedule is
-byte-identical and ``comm_stats['overlap']`` prices what stays exposed.
+never the math — the zero-outside-counts contract makes pack/unpack
+lossless, while ``comm_stats['state']`` shows resident rows/bytes below
+the M·n_pad stride.  The serial trainer is the oracle of the packed
+trainer's rounds (tests/test_narrow_first.py).  The overlap mode
+re-associates the neighbour sum by arrival round, so its parity is
+tolerance- rather than bit-level; its wire schedule is byte-identical and
+``comm_stats['overlap']`` prices what stays exposed.
 """
 import os
 import subprocess
@@ -17,12 +17,11 @@ import sys
 
 import jax
 import numpy as np
-import pytest
 from jax.sharding import AxisType
 
 from repro import analysis
 from repro.core import gcn, graph
-from repro.core.parallel import AXIS, ParallelADMMTrainer
+from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig
 
 
@@ -36,10 +35,9 @@ def _trainer(g, part, mesh, **kw):
     cfg = gcn.GCNConfig(layer_dims=(8, 8, g.num_classes))
     admm = ADMMConfig(nu=1e-3, rho=1e-3)
     m = int(part.max()) + 1
-    kw.setdefault("compressed", True)
     return ParallelADMMTrainer(cfg, admm, g, num_parts=m, seed=0,
                                part=part, mesh=mesh,
-                               pad_mode="bucketed", **kw)
+                               config=TrainerConfig.packed(**kw))
 
 
 # ---------------------------------------------------------------------------
@@ -90,64 +88,20 @@ def test_global_unpack_rows_is_the_scatter_inverse():
 # trainer validation + comm_stats accounting
 # ---------------------------------------------------------------------------
 
-def test_packed_flag_validation():
-    g, part = _skewed()
-    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
-    with pytest.raises(ValueError, match="compressed"):
-        _trainer(g, part, mesh, packed=True, compressed=False)
-    with pytest.raises(ValueError, match="p2p"):
-        _trainer(g, part, mesh, packed=True, transport="allgather")
-    with pytest.raises(ValueError, match="packed"):
-        _trainer(g, part, mesh, overlap=True)
-
-
 def test_comm_stats_state_accounting():
     g, part = _skewed()
     mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
-    tr = _trainer(g, part, mesh, packed=True)
+    tr = _trainer(g, part, mesh)
     st = tr.comm_stats["state"]
-    assert st["packed"] is True
     assert st["node_rows"] <= st["bucket_rows"] <= st["rows"] \
         <= st["strided_rows"]
     assert st["rows"] < st["strided_rows"]          # the skew pays off
     assert st["z_bytes"] < st["z_strided_bytes"]
     assert st["resident_bytes"] < st["strided_equiv_bytes"]
-    # the strided trainer reports the same schema with packed=False and
-    # rows at the full M·n_pad stride
-    ref = _trainer(g, part, mesh).comm_stats["state"]
-    assert ref["packed"] is False
-    assert ref["rows"] == ref["strided_rows"] == st["strided_rows"]
-
-
-# ---------------------------------------------------------------------------
-# single-shard bitwise parity (the multi-shard run is the subprocess below)
-# ---------------------------------------------------------------------------
-
-def test_packed_trainer_bitwise_matches_strided_one_shard():
-    """On one shard the packed trainer stores Z/U as packed planes but
-    runs the identical blocked math — every iterate, the Lagrangian and
-    the metrics must match the strided trainer BITWISE."""
-    g, part = _skewed()
-    mesh = jax.make_mesh((1,), (AXIS,), (AxisType.Auto,))
-    ref = _trainer(g, part, mesh)
-    pk = _trainer(g, part, mesh, packed=True)
-    dl = pk.packed_layout
-    assert dl is not None
-    for _ in range(4):
-        ref.step()
-        pk.step()
-    for zr, zp in zip(ref.state.zs, pk.state.zs):
-        assert zp.shape[0] == dl.total_rows
-        np.testing.assert_array_equal(np.asarray(zr),
-                                      dl.unpack_state(np.asarray(zp)))
-    np.testing.assert_array_equal(np.asarray(ref.state.u),
-                                  dl.unpack_state(np.asarray(pk.state.u)))
-    for wr, wp in zip(ref.state.weights, pk.state.weights):
-        np.testing.assert_array_equal(np.asarray(wr), np.asarray(wp))
-    assert float(ref._lagrangian(ref.state)) == \
-        float(pk._lagrangian(pk.state))
-    for a, b in zip(ref._metrics(ref.state), pk._metrics(pk.state)):
-        assert float(a) == float(b)
+    # the stride a blocked layout would hold, and the rows the planes do
+    assert st["strided_rows"] == tr.data.num_parts * tr.layout.n_pad
+    assert st["rows"] == tr.packed_layout.total_rows \
+        == tr.state.zs[0].shape[0]
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +115,7 @@ def _hlo(body: str) -> str:
 
 
 def test_packed_resident_state_rule_fires_on_blocked_stacks():
-    exp = {"n_pad": 16, "state_packed": True, "packed_rows_bound": 4}
+    exp = {"n_pad": 16, "n_shards": 4, "packed_rows_bound": 4}
     # a computed (8, 16, 7) blocked row stack: 8 rows > r_pad = 4
     text = _hlo(
         "  %p0 = f32[8,16,7]{2,1,0} parameter(0)\n"
@@ -185,25 +139,27 @@ def test_packed_resident_state_rule_fires_on_blocked_stacks():
         "  ROOT %b = f32[8,16,16]{2,1,0} negate(f32[8,16,16]{2,1,0} %p0)")
     assert not analysis.analyze_hlo(adj, expectations=exp).findings_for(
         "memory/packed-resident-state")
-    # unpacked configs are out of scope
+    # one shard keeps the blocked body on its own rows: out of scope
     off = analysis.analyze_hlo(
-        text, expectations=dict(exp, state_packed=False))
+        text, expectations=dict(exp, n_shards=1))
     assert not off.findings_for("memory/packed-resident-state")
 
 
 # ---------------------------------------------------------------------------
-# 4-shard subprocess: packed p2p vs strided bitwise, overlap tolerance,
-# and the compiled-program proof (analysis rules over the real HLO)
+# 4-shard subprocess: resident-state accounting, overlap tolerance, serial
+# parity, and the compiled-program proof (analysis rules over the real HLO)
 # ---------------------------------------------------------------------------
 
 _PACKED_WORKER = r"""
 import jax
 import numpy as np
 from repro.core import gcn, graph
-from repro.core.parallel import AXIS, ParallelADMMTrainer
+from repro.core.parallel import AXIS, ParallelADMMTrainer, TrainerConfig
 from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
 from jax.sharding import AxisType
+from trajectory import (assert_matches_serial, assert_same_trajectory,
+                        one_shard_twin)
 
 N_SHARDS = 4
 assert len(jax.devices()) >= N_SHARDS, jax.devices()
@@ -216,41 +172,33 @@ mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.devices
 
 def build(**kw):
     return ParallelADMMTrainer(cfg, admm, g, num_parts=8, seed=0,
-                               part=part, mesh=mesh, compressed=True,
-                               pad_mode="bucketed", **kw)
+                               part=part, mesh=mesh,
+                               config=TrainerConfig.packed(**kw))
 
 serial = SerialADMMTrainer(cfg, admm, g, seed=0)
-ref = build()
-pk = build(packed=True)
-ov = build(packed=True, overlap=True)
-dl = pk.packed_layout
+pk = build()
+ov = build(overlap=True)
 
 # resident-state accounting: packed planes strictly undercut the stride
 st = pk.comm_stats["state"]
-assert st["packed"] and st["rows"] < st["strided_rows"], st
+assert st["rows"] < st["strided_rows"], st
 assert st["z_bytes"] < st["z_strided_bytes"], st
 # wire schedule identical either way; overlap prices the exposed share
-assert pk.comm_stats["wire_bytes"] == ref.comm_stats["wire_bytes"]
+assert pk.comm_stats["wire_bytes"] == ov.comm_stats["wire_bytes"]
 assert not pk.comm_stats["overlap"]["enabled"]
 ost = ov.comm_stats["overlap"]
 assert ost["enabled"] and ost["overlap_efficiency"] > 0, ost
 assert ost["exposed_wire_s"] < ost["total_wire_s"], ost
 print("STATS_OK")
 
+one = one_shard_twin(pk, num_parts=8, seed=0, part=part)
 for _ in range(3):
-    serial.step(); ref.step(); pk.step(); ov.step()
+    serial.step(); pk.step(); ov.step(); one.step()
 
-# packed p2p == strided p2p BITWISE (pack/unpack is lossless and the
-# math never sees the relocation)
-for zr, zp in zip(ref.state.zs, pk.state.zs):
-    np.testing.assert_array_equal(np.asarray(zr),
-                                  dl.unpack_state(np.asarray(zp)))
-np.testing.assert_array_equal(np.asarray(ref.state.u),
-                              dl.unpack_state(np.asarray(pk.state.u)))
-for wr, wp in zip(ref.state.weights, pk.state.weights):
-    np.testing.assert_array_equal(np.asarray(wr), np.asarray(wp))
-assert float(ref._lagrangian(ref.state)) == float(pk._lagrangian(pk.state))
-print("PACKED_BITWISE_OK")
+# the exchange-free one-shard trainer on the same partition: packing the
+# state into Σ-bucket-rows planes and wiring rows p2p never change the math
+assert_same_trajectory(pk, one)
+print("ONE_SHARD_PARITY_OK")
 
 # overlap re-associates the neighbour sum by arrival group: tolerance
 for zp, zo in zip(pk.state.zs, ov.state.zs):
@@ -261,26 +209,6 @@ np.testing.assert_allclose(np.asarray(pk.state.u), np.asarray(ov.state.u),
 lp, lo = float(pk._lagrangian(pk.state)), float(ov._lagrangian(ov.state))
 assert abs(lp - lo) <= 1e-4 * max(1.0, abs(lp)), (lp, lo)
 print("OVERLAP_OK")
-
-# both packed trainers reproduce the SERIAL trainer's W/Z/U + Lagrangian
-lag_s = float(serial._lagr(serial.a_tilde, serial.z0, serial.labels,
-                           serial.train_mask, serial.state))
-for tr in (pk, ov):
-    for zs_, zp in zip(serial.state.zs, tr.state.zs):
-        np.testing.assert_allclose(
-            np.asarray(zs_),
-            tr.layout.unpack(dl.unpack_state(np.asarray(zp))),
-            rtol=2e-3, atol=2e-4)
-    for ws, wp in zip(serial.state.weights, tr.state.weights):
-        np.testing.assert_allclose(np.asarray(ws), np.asarray(wp),
-                                   rtol=2e-3, atol=2e-4)
-    np.testing.assert_allclose(
-        np.asarray(serial.state.u),
-        tr.layout.unpack(dl.unpack_state(np.asarray(tr.state.u))),
-        rtol=2e-3, atol=2e-4)
-    lag_t = float(tr._lagrangian(tr.state))
-    assert abs(lag_s - lag_t) <= 1e-4 * max(1.0, abs(lag_s)), (lag_s, lag_t)
-print("SERIAL_PARITY_OK")
 
 # compiled-program proof: the packed step holds no blocked row stack
 # taller than r_pad, keeps the gather-free p2p schedule, and the 8-row
@@ -293,24 +221,32 @@ for tr, name in ((pk, "packed"), (ov, "packed-overlap")):
                                 rule="collective/no-allgather-under-p2p")
     assert not rep.errors(), rep.summary()
 print("HLO_OK")
+
+# both packed trainers reproduce the serial trainer's trajectory
+for tr in (pk, ov):
+    assert_matches_serial(tr, serial)
+print("SERIAL_PARITY_OK")
 """
 
 
-def test_packed_p2p_matches_strided_on_4_shards():
+def test_packed_p2p_matches_serial_on_4_shards():
     """The acceptance run: a 4-shard packed trainer on the size-skewed
-    graph matches the strided trainer's W/Z/U and Lagrangian BITWISE
-    after 3 iterations, the overlap trainer matches to tolerance, the
-    resident state strictly undercuts the stride, and the compiled step
-    passes the packed-resident-state rule."""
+    graph holds fewer resident rows than the stride, holds the W, Z, U and
+    Lagrangian of the exchange-free one-shard trainer on the same
+    partition after 3 iterations, the overlap trainer matches it to
+    tolerance, the compiled step passes the packed-resident-state rule,
+    and both match the serial trainer's trajectory."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         os.path.dirname(__file__)])
     out = subprocess.run([sys.executable, "-c", _PACKED_WORKER],
                          capture_output=True, text=True, env=env,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    for tag in ("STATS_OK", "PACKED_BITWISE_OK", "OVERLAP_OK",
-                "SERIAL_PARITY_OK", "HLO_OK"):
+    for tag in ("STATS_OK", "ONE_SHARD_PARITY_OK", "OVERLAP_OK", "HLO_OK",
+                "SERIAL_PARITY_OK"):
         assert tag in out.stdout, out.stdout
 
 

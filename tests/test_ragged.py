@@ -1,11 +1,11 @@
 """Ragged (size-aware) community padding: bucket scheme, blockify round
-trips, pad accounting, row-exact exchange, the ragged-vs-global trainer
-A/B, and the bf16 ELL block store.
+trips, pad accounting, row-exact exchange, the ragged trainer, and the
+bf16 ELL block store.
 
 The invariant under test everywhere: bucketed padding and row-exact wire
-change what is PROCESSED and TRANSMITTED, never the math — trainers under
-any pad scheme produce identical iterates, while ``comm_stats`` shows
-pad_bytes/pad_flops/wire_bytes dropping.
+change what is PROCESSED and TRANSMITTED, never the math — the layout and
+plan accounting show pad_bytes/pad_flops/wire_bytes below the global
+n_pad scheme's, and the trainer matches the serial trainer's math.
 """
 import os
 import subprocess
@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core import gcn, graph, messages
-from repro.core.parallel import ParallelADMMTrainer
+from repro.core.parallel import ParallelADMMTrainer, TrainerConfig
 from repro.core.subproblems import ADMMConfig
 
 
@@ -229,7 +229,7 @@ def test_row_exact_exchange_delivers_host_sim(skewed_layout):
 
 
 # ---------------------------------------------------------------------------
-# trainer A/B: ragged vs global padding
+# the ragged trainer
 # ---------------------------------------------------------------------------
 
 def _skewed_trainer_case():
@@ -241,56 +241,9 @@ def _skewed_trainer_case():
     return g, part, cfg, admm
 
 
-def test_trainer_pad_modes_bit_compatible_and_stats_drop():
-    """pad_mode only changes what is processed/wired: global and bucketed
-    trainers produce identical W/Z/U and Lagrangian, while the bucketed
-    comm_stats record strictly less padding — on the axes whose consumer
-    is actually engaged (row-exact p2p wire; guarded kernel with
-    use_kernel)."""
-    g, part, cfg, admm = _skewed_trainer_case()
-    glob = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                               compressed=True, pad_mode="global",
-                               use_kernel=True)
-    buck = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                               compressed=True, pad_mode="bucketed",
-                               use_kernel=True)
-    assert glob.comm_stats["pad_mode"] == "global"
-    assert buck.comm_stats["pad_mode"] == "bucketed"
-    assert buck.comm_stats["pad_guards"] == {"kernel": True, "wire": True}
-    assert buck.comm_stats["pad_bytes"] < glob.comm_stats["pad_bytes"]
-    assert buck.comm_stats["pad_flops"] < glob.comm_stats["pad_flops"]
-    # stats are gated on the consumer: without the guarded kernel the
-    # einsum aggregation processes every n_pad row, so bucketed pad_flops
-    # must NOT claim the skip; an allgather transport wires full-pad
-    # payloads, so bucketed pad_bytes must not claim the wire win either
-    nok = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                              compressed=True, pad_mode="bucketed")
-    assert nok.comm_stats["pad_guards"] == {"kernel": False, "wire": True}
-    assert nok.comm_stats["pad_flops"] == glob.comm_stats["pad_flops"]
-    assert nok.comm_stats["pad_bytes"] == buck.comm_stats["pad_bytes"]
-    nag = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                              compressed=True, pad_mode="bucketed",
-                              transport="allgather")
-    assert nag.comm_stats["pad_guards"]["wire"] is False
-    assert nag.comm_stats["pad_bytes"] == glob.comm_stats["pad_bytes"]
-    for _ in range(3):
-        glob.step()
-        buck.step()
-    for za, zb in zip(glob.state.zs, buck.state.zs):
-        np.testing.assert_allclose(np.asarray(za), np.asarray(zb),
-                                   rtol=2e-4, atol=2e-5)
-    for wa, wb in zip(glob.state.weights, buck.state.weights):
-        np.testing.assert_allclose(np.asarray(wa), np.asarray(wb),
-                                   rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(glob.state.u),
-                               np.asarray(buck.state.u),
-                               rtol=2e-4, atol=2e-5)
-    lg = float(glob._lagrangian(glob.state))
-    lb = float(buck._lagrangian(buck.state))
-    assert lb == pytest.approx(lg, rel=1e-5)
-    with pytest.raises(ValueError):
-        ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                            compressed=True, pad_mode="diagonal")
+def _trainer(g, part, cfg, admm, **kw):
+    return ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
+                               config=TrainerConfig.packed(**kw))
 
 
 def test_trainer_kernel_interpret_with_ragged_counts():
@@ -299,14 +252,11 @@ def test_trainer_kernel_interpret_with_ragged_counts():
     from repro.kernels import ops as kops
 
     g, part, cfg, admm = _skewed_trainer_case()
-    base = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                               compressed=True, pad_mode="bucketed")
+    base = _trainer(g, part, cfg, admm)
     base.step()
     kops.repro_force_interpret(True)
     try:
-        kern = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0,
-                                   part=part, compressed=True,
-                                   pad_mode="bucketed", use_kernel=True)
+        kern = _trainer(g, part, cfg, admm, use_kernel=True)
         kern.step()
     finally:
         kops.repro_force_interpret(False)
@@ -327,10 +277,8 @@ def test_adjacency_bf16_halves_blocks_and_stays_close():
     bytes, itemsize-aware accounting) with f32 accumulation — parity with
     the f32 store at loose tolerance over 3 iterations."""
     g, part, cfg, admm = _skewed_trainer_case()
-    f32 = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                              compressed=True)
-    b16 = ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                              compressed=True, adjacency_bf16=True)
+    f32 = _trainer(g, part, cfg, admm)
+    b16 = _trainer(g, part, cfg, admm, adjacency_bf16=True)
     assert b16.data.adjacency_bf16 and not f32.data.adjacency_bf16
     assert b16.data.ell_blocks.dtype == jnp.bfloat16
     # exactly the block plane halves; indices/mask stay full precision
@@ -349,9 +297,6 @@ def test_adjacency_bf16_halves_blocks_and_stays_close():
     for wf, wb in zip(f32.state.weights, b16.state.weights):
         np.testing.assert_allclose(np.asarray(wf), np.asarray(wb),
                                    rtol=0.05, atol=0.05)
-    with pytest.raises(ValueError):
-        ParallelADMMTrainer(cfg, admm, g, num_parts=4, seed=0, part=part,
-                            adjacency_bf16=True)      # dense + bf16 store
 
 
 # ---------------------------------------------------------------------------
@@ -361,11 +306,14 @@ def test_adjacency_bf16_halves_blocks_and_stays_close():
 _RAGGED_WORKER = r"""
 import jax
 import numpy as np
-from repro.core import gcn, graph
-from repro.core.parallel import AXIS, ParallelADMMTrainer
+from repro.core import gcn, graph, messages
+from repro.core.parallel import (AXIS, ParallelADMMTrainer, TrainerConfig,
+                                 gathered_widths)
 from repro.core.serial import SerialADMMTrainer
 from repro.core.subproblems import ADMMConfig
 from jax.sharding import AxisType
+from trajectory import (assert_matches_serial, assert_same_trajectory,
+                        one_shard_twin)
 
 N_SHARDS = 4
 assert len(jax.devices()) >= N_SHARDS, jax.devices()
@@ -380,39 +328,26 @@ mesh = jax.make_mesh((N_SHARDS,), (AXIS,), (AxisType.Auto,), devices=jax.devices
 
 serial = SerialADMMTrainer(cfg, admm, g, seed=0)
 rag = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, part=part,
-                          mesh=mesh, compressed=True, pad_mode="bucketed")
-glo = ParallelADMMTrainer(cfg, admm, g, num_parts=12, seed=0, part=part,
-                          mesh=mesh, compressed=True, pad_mode="global")
-assert rag.transport == "p2p" and rag.comm_stats["pad_mode"] == "bucketed"
-assert rag.comm_stats["wire_bytes"] < glo.comm_stats["wire_bytes"]
-assert rag.comm_stats["pad_bytes"] < glo.comm_stats["pad_bytes"]
+                          mesh=mesh, config=TrainerConfig.packed())
+# the row-exact wire and the bucketed padding undercut what the global
+# n_pad scheme would move and hold on the same layout
+lay = rag.layout
+whole = messages.build_neighbor_exchange(lay.neighbor_mask, N_SHARDS,
+                                         lay.n_pad)
+cs = gathered_widths(cfg.layer_dims)
+assert rag.comm_stats["wire_bytes"] < \
+    messages.exchange_bytes(whole, cs)["wire_bytes"]
+assert rag.comm_stats["pad_bytes"] < messages.pad_stats(
+    lay.neighbor_mask, lay.sizes, None, lay.n_pad, cs)["pad_bytes"]
+print("PAD_STATS_OK")
+
+# the exchange-free one-shard trainer on the same partition: bucketed
+# padding and the row-exact wire change what moves, never the math
+one = one_shard_twin(rag, num_parts=12, seed=0, part=part)
 for _ in range(3):
-    serial.step(); rag.step(); glo.step()
-
-# ragged == global bit-compatible on the same mesh
-for za, zb in zip(rag.state.zs, glo.state.zs):
-    np.testing.assert_allclose(np.asarray(za), np.asarray(zb),
-                               rtol=2e-4, atol=2e-5)
-np.testing.assert_allclose(np.asarray(rag.state.u), np.asarray(glo.state.u),
-                           rtol=2e-4, atol=2e-5)
-print("PAD_PARITY_OK")
-
-# ragged p2p == the serial trainer (W/Z/U + Lagrangian)
-for zs_, zp in zip(serial.state.zs, rag.state.zs):
-    np.testing.assert_allclose(np.asarray(zs_),
-                               rag.layout.unpack(np.asarray(zp)),
-                               rtol=2e-3, atol=2e-4)
-for ws, wp in zip(serial.state.weights, rag.state.weights):
-    np.testing.assert_allclose(np.asarray(ws), np.asarray(wp),
-                               rtol=2e-3, atol=2e-4)
-np.testing.assert_allclose(np.asarray(serial.state.u),
-                           rag.layout.unpack(np.asarray(rag.state.u)),
-                           rtol=2e-3, atol=2e-4)
-lag_s = float(serial._lagr(serial.a_tilde, serial.z0, serial.labels,
-                           serial.train_mask, serial.state))
-lag_r = float(rag._lagrangian(rag.state))
-assert abs(lag_s - lag_r) <= 1e-4 * max(1.0, abs(lag_s)), (lag_s, lag_r)
-print("SERIAL_PARITY_OK")
+    serial.step(); rag.step(); one.step()
+assert_same_trajectory(rag, one)
+print("ONE_SHARD_PARITY_OK")
 
 # the ragged p2p step still compiles gather-free (analysis rule proof)
 from repro import analysis
@@ -421,20 +356,29 @@ assert analysis.no_findings(rep, rule="collective/no-allgather-under-p2p")
 assert analysis.no_findings(rep, rule="collective/permute-schedule")
 assert not rep.errors(), rep.summary()
 print("HLO_OK")
+
+# the ragged p2p trainer reproduces the serial trainer's trajectory
+assert_matches_serial(rag, serial)
+print("SERIAL_PARITY_OK")
 """
 
 
 def test_ragged_p2p_matches_serial_on_4_shards():
     """The acceptance run: a 4-shard ragged (bucketed, row-exact p2p)
-    trainer on a size-skewed graph matches the serial trainer's W/Z/U and
-    Lagrangian after 3 iterations, wires strictly fewer bytes than the
-    global-pad trainer, and compiles without an all-gather."""
+    trainer on a size-skewed graph wires and pads strictly fewer bytes
+    than the global-pad scheme would, holds the W, Z, U and Lagrangian of
+    the exchange-free one-shard trainer on the same partition after 3
+    iterations, compiles without an all-gather, and matches the serial
+    trainer's trajectory."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         os.path.dirname(__file__)])
     out = subprocess.run([sys.executable, "-c", _RAGGED_WORKER],
                          capture_output=True, text=True, env=env,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    for tag in ("PAD_PARITY_OK", "SERIAL_PARITY_OK", "HLO_OK"):
+    for tag in ("PAD_STATS_OK", "ONE_SHARD_PARITY_OK", "HLO_OK",
+                "SERIAL_PARITY_OK"):
         assert tag in out.stdout, out.stdout
