@@ -20,6 +20,11 @@ Array = jax.Array
 
 @dataclasses.dataclass
 class TrainLog:
+    """One entry per logged round in each list.  ``epoch_time_s`` is the
+    round's own wall time for ``SerialADMMTrainer``; for
+    ``ParallelADMMTrainer.train``, which logs every round and reads the
+    device once per call, it is the call's mean per round, so a caller that
+    wants one round's time calls ``train(1)``."""
     epoch: list = dataclasses.field(default_factory=list)
     train_acc: list = dataclasses.field(default_factory=list)
     test_acc: list = dataclasses.field(default_factory=list)

@@ -393,15 +393,29 @@ def test_constructor_records_layout_then_initial_state():
 
 
 def test_train_records_each_round_under_its_caller():
+    """Each round dispatches its step and eval programs; the wait and the
+    one read of every round's metrics come once, at the end of the call."""
     t = _trainer()
     with spans.recording() as rec:
         with spans.span("caller"):
             t.train(2)
-    assert [s.name for s in rec] == list(TRAIN_SPANS) * 2 + ["caller"]
+    assert [s.name for s in rec] == ["train.step", "train.eval"] * 2 + \
+        ["train.wait", "train.sync", "caller"]
     assert all(s.parent == "caller" for s in rec[:-1])
     assert rec[-1].parent is None
     inner = rec[:-1]
     assert all(a.end_ns <= b.start_ns for a, b in zip(inner, inner[1:]))
+
+
+@pytest.mark.parametrize("rounds", (1, 4))
+def test_train_waits_and_reads_once_per_call(rounds):
+    t = _trainer()
+    with spans.recording() as rec:
+        t.train(rounds)
+    names = [s.name for s in rec]
+    assert names.count("train.step") == names.count("train.eval") == rounds
+    assert names.count("train.wait") == names.count("train.sync") == 1
+    assert names[-2:] == ["train.wait", "train.sync"]
 
 
 def test_nothing_is_recorded_outside_recording():
